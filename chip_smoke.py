@@ -1,0 +1,562 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, the normal entry points, no bench-only switch:
+
+1. device    — jax.devices() must be a TPU; versions and the native .so
+2. flagship  — fedml_tpu.init() + run_simulation() on the anchor config
+               (FedAvg, CIFAR-10 stand-in, ResNet-56 bf16, 100 clients,
+               10 a round, Dirichlet 0.5) for a few rounds with eval
+3. lm        — DistributedLMTrainer at the README's LM flagship width
+               (dim 1024, 12 layers, 16 heads, vocab 32000, bf16, AdamW)
+               at T=4096, where attention dispatches to the flash kernel
+4. kernels   — every other pallas_call against its in-repo reference:
+               fused_gram, fused_quantize_pack q8/q4, conv2d_pallas
+5. four chips (only when jax sees >= 4): stage 2 with backend="TPU" (the
+               client mesh over every device) and stage 3 with dp=2 x tp=2
+
+Any exception, NaN or failed check ends the run with a non-zero code; the
+last line of stdout is the result JSON only when every stage passed. There
+is no CPU fallback and no switch that lets this pass off the chip: without
+a TPU it exits 1 naming the platform it found.
+
+The stage functions take their sizes as arguments so tests/test_chip_smoke.py
+can shake them out at a tiny size on the CPU; ``main`` is the only caller
+that passes the full sizes, and the only place the chip-only facts (platform,
+compiled Mosaic calls) are required.
+"""
+
+from __future__ import annotations
+
+import json
+import logging
+import sys
+import time
+
+FLAGSHIP = dict(
+    dataset="cifar10", model="resnet56", partition_method="hetero",
+    partition_alpha=0.5, client_num_in_total=100, client_num_per_round=10,
+    epochs=1, batch_size=64, use_bf16=True, learning_rate=0.01,
+    random_seed=0,
+)
+FLAGSHIP_ROUNDS = 3           # eval runs at round 0 and at the last round
+LM_MODEL = dict(vocab_size=32000, dim=1024, num_heads=16, num_layers=12)
+LM_SEQ = 4096                 # auto_attention_impl -> "flash" from here
+LM_BATCH = 4                  # ~5.5 GB of the v5e's 16 GB (XLA's own estimate)
+LM_STEPS = 4
+GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
+QUANT_SHAPE = (1000, 65536)
+# ResNet-56's three stages at the flagship batch: (batch, height/width, chans)
+CONV_STAGES = ((64, 32, 16), (64, 16, 32), (64, 8, 64))
+
+
+class CheckFailed(AssertionError):
+    """A stage's result is wrong (python -O does not strip this check)."""
+
+
+def require(cond, message: str) -> None:
+    if not cond:
+        raise CheckFailed(message)
+
+
+def rel_err(got, want) -> float:
+    """||got - want|| / ||want|| in float64 on the host."""
+    import numpy as np
+
+    got = np.asarray(got, np.float64)
+    want = np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want)
+                 / max(np.linalg.norm(want), 1e-30))
+
+
+def mosaic_calls(fn, *args) -> int:
+    """How many compiled Mosaic kernels the lowered program holds. Zero
+    means the Pallas call ran interpreted or took a jnp reference."""
+    import jax
+
+    return jax.jit(fn).lower(*args).as_text().count("tpu_custom_call")
+
+
+def memory_stats() -> list:
+    """Per-device allocator counters, where the backend reports them."""
+    import jax
+
+    out = []
+    for d in jax.devices():
+        ms = d.memory_stats() or {}
+        out.append({k: int(ms[k]) for k in
+                    ("bytes_in_use", "peak_bytes_in_use") if k in ms})
+    return out
+
+
+class CompileMeter:
+    """What compiling cost, from jax's own monitoring events: programs
+    requested, persistent-cache hits, seconds in XLA (``xla_s``, with the
+    count of compiles that took a second or more — the ones jax persists)
+    and seconds loading cached executables. A warm run shows
+    ``xla_over_1s == 0``: nothing the cache could hold was compiled again;
+    what remains are programs under jax's one-second persistence threshold.
+    Compiles happen on the calling thread, so a hit event is followed by
+    its own duration event."""
+
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
+    _BACKEND = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax
+
+        self.totals = {"requests": 0, "cache_hits": 0, "xla_s": 0.0,
+                       "xla_over_1s": 0, "cache_load_s": 0.0}
+        self._hit_pending = False
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_event(self, event: str, **_):
+        if event == self._REQUEST:
+            self.totals["requests"] += 1
+        elif event == self._HIT:
+            self.totals["cache_hits"] += 1
+            self._hit_pending = True
+
+    def _on_duration(self, event: str, secs: float, **_):
+        if event != self._BACKEND:
+            return
+        if self._hit_pending:
+            self._hit_pending = False
+            self.totals["cache_load_s"] += secs
+        else:
+            self.totals["xla_s"] += secs
+            self.totals["xla_over_1s"] += secs >= 1.0
+
+    def since(self, seen: dict) -> dict:
+        return {k: round(v - seen.get(k, 0), 2)
+                for k, v in self.totals.items()}
+
+
+class LogTap(logging.Handler):
+    """Collects the engine's own 'which path engaged' log lines."""
+
+    def __init__(self, prefixes):
+        super().__init__(level=logging.INFO)
+        self.prefixes = tuple(prefixes)
+        self.lines: list = []
+
+    def emit(self, record):
+        msg = record.getMessage()
+        if msg.startswith(self.prefixes):
+            self.lines.append(msg)
+
+
+# --------------------------------------------------------------- stage 1
+
+def stage_device() -> dict:
+    import importlib.metadata as md
+
+    import jax
+    import jaxlib
+
+    from fedml_tpu import native
+
+    devices = jax.devices()
+    try:
+        libtpu = md.version("libtpu")
+    except md.PackageNotFoundError:
+        libtpu = None
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu,
+        "native": "so" if native.get_lib() is not None else "numpy-fallback",
+    }
+
+
+# --------------------------------------------------------------- stage 2
+
+def stage_flagship(config: dict, rounds: int, backend: str = "sp") -> dict:
+    """init() + run_simulation(): finite falling train loss, finite test_acc."""
+    import numpy as np
+
+    import fedml_tpu
+
+    tap = LogTap(("FedSimulator:", "SimulatorTPU:"))
+    root = logging.getLogger()
+    root.addHandler(tap)
+    old_level = root.level
+    root.setLevel(min(old_level or logging.INFO, logging.INFO))
+    try:
+        fedml_tpu.init(config=dict(
+            config, comm_round=rounds, backend=backend,
+            # evaluates at round 0 and at the last round only
+            frequency_of_the_test=max(rounds, 1) * 1000))
+        t0 = time.perf_counter()
+        history = fedml_tpu.run_simulation()
+        wall = time.perf_counter() - t0
+    finally:
+        root.removeHandler(tap)
+        root.setLevel(old_level)
+    losses = [float(r["train_loss"]) for r in history]
+    accs = [float(r["test_acc"]) for r in history if "test_acc" in r]
+    require(len(history) == rounds, f"ran {len(history)} of {rounds} rounds")
+    require(bool(np.all(np.isfinite(losses))), f"train_loss not finite: {losses}")
+    require(losses[-1] < losses[0], f"train_loss did not fall: {losses}")
+    require(accs and bool(np.all(np.isfinite(accs))),
+            f"no finite test_acc in {rounds} rounds: {accs}")
+    return {
+        "engaged": tap.lines, "train_loss": losses, "test_acc": accs,
+        "run_s": round(wall, 2),
+        # host time to dispatch each round. A round whose lane length is
+        # new compiles a new program, and in a run this short every round
+        # can: cold, these are compile times, not round times
+        "dispatch_s": [round(float(r["dispatch_time"]), 2) for r in history],
+        "memory": memory_stats(),
+    }
+
+
+# --------------------------------------------------------------- stage 3
+
+def stage_lm(model: dict, seq: int, batch: int, steps: int,
+             dp: int = 1, tp: int = 1) -> dict:
+    """A few AdamW steps on one seeded batch: finite falling loss; plus how
+    many Mosaic calls the step lowers to and where the params live."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.attention import auto_attention_impl
+    from fedml_tpu.parallel.trainer import (
+        DistributedLMTrainer,
+        DistTrainConfig,
+    )
+
+    heads, dh = model["num_heads"], model["dim"] // model["num_heads"]
+    impl = auto_attention_impl(batch // dp, heads // tp, seq, dh, itemsize=2)
+    t0 = time.perf_counter()
+    trainer = DistributedLMTrainer(
+        DistTrainConfig(dp=dp, tp=tp), max_len=seq, dtype=jnp.bfloat16,
+        seed=0, **model)
+    build_s = time.perf_counter() - t0
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, model["vocab_size"], (batch, seq + 1),
+                          dtype=np.int32)
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    n_mosaic = trainer._train_step.lower(
+        trainer.params, trainer.opt_state,
+        jax.ShapeDtypeStruct(x.shape, jnp.int32, sharding=trainer.batch_sharding),
+        jax.ShapeDtypeStruct(y.shape, jnp.int32, sharding=trainer.batch_sharding),
+    ).as_text().count("tpu_custom_call")
+    losses, step_s = [], []
+    for _ in range(steps):
+        t = time.perf_counter()
+        losses.append(trainer.step(x, y))   # float(loss): waits on the device
+        step_s.append(time.perf_counter() - t)
+    require(bool(np.all(np.isfinite(losses))), f"LM loss not finite: {losses}")
+    require(losses[-1] < losses[0], f"LM loss did not fall: {losses}")
+    leaves = jax.tree_util.tree_leaves(trainer.params)
+    holders = set().union(*(leaf.sharding.device_set for leaf in leaves))
+    biggest = max(leaves, key=lambda a: a.size)
+    return {
+        "attention_impl": impl, "mosaic_calls_lowered": n_mosaic,
+        "mesh": dict(trainer.mesh.shape), "batch": batch, "seq": seq,
+        "loss": [round(v, 4) for v in losses],
+        "build_s": round(build_s, 2),
+        "first_step_s": round(step_s[0], 2),      # compile + one step
+        "steady_step_s": round(min(step_s[1:]), 3) if steps > 1 else None,
+        "param_devices": len(holders),
+        "largest_param_shard": list(
+            biggest.addressable_shards[0].data.shape),
+        "largest_param": list(biggest.shape),
+        "memory": memory_stats(),
+    }
+
+
+def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
+    """flash forward and backward against multihead_attention(impl='dense')
+    on seeded bf16 inputs, at bf16 tolerance."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.attention import multihead_attention
+
+    rng = np.random.default_rng(1)
+    q, k, v, w = (jnp.asarray(rng.standard_normal((batch, seq, heads, dh)),
+                              jnp.bfloat16) for _ in range(4))
+
+    def loss(impl):
+        def f(q, k, v):
+            out = multihead_attention(q, k, v, causal=True, impl=impl)
+            return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum(), out
+        return f
+
+    fwd_bwd = lambda impl: jax.jit(  # noqa: E731
+        jax.value_and_grad(loss(impl), argnums=(0, 1, 2), has_aux=True))
+    (_, out_f), g_f = fwd_bwd("flash")(q, k, v)
+    (_, out_d), g_d = fwd_bwd("dense")(q, k, v)
+    errs = {"out": rel_err(out_f, out_d)}
+    for name, a, b in zip(("dq", "dk", "dv"), g_f, g_d):
+        errs[name] = rel_err(a, b)
+    for name, e in errs.items():
+        require(np.isfinite(e) and e <= 2e-2,
+                f"flash vs dense {name}: relative error {e:.3e} > 2e-2")
+    return {
+        "rel_err": {k_: float(f"{e:.3e}") for k_, e in errs.items()},
+        "mosaic_calls_lowered": mosaic_calls(
+            jax.grad(lambda q, k, v: loss("flash")(q, k, v)[0], (0, 1, 2)),
+            q, k, v),
+    }
+
+
+# --------------------------------------------------------------- stage 4
+
+def check_gram(shape, interpret=None) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.pallas import fused_gram
+    from fedml_tpu.ops.pallas.agg_robust import _reference_gram
+
+    flat = jnp.asarray(
+        np.random.default_rng(2).standard_normal(shape), jnp.float32)
+    kern = lambda x: fused_gram(x, interpret=interpret)  # noqa: E731
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(jax.jit(kern)(flat))
+    first_s = time.perf_counter() - t0
+    want = jax.jit(_reference_gram)(flat)
+    with jax.default_matmul_precision("highest"):
+        want_hi = jax.jit(_reference_gram)(flat)
+    err, err_hi = rel_err(got, want), rel_err(got, want_hi)
+    require(got.shape == (shape[0], shape[0]), f"gram shape {got.shape}")
+    # the MXU's default pass rounds f32 operands to bf16, in the kernel and
+    # in XLA's reference alike; against the full-f32 reference that is the
+    # error to expect, not a kernel defect
+    require(np.isfinite(err_hi) and err_hi <= 1e-2,
+            f"fused_gram vs f32 reference: relative error {err_hi:.3e}")
+    return {"shape": list(shape), "rel_err_vs_reference": float(f"{err:.3e}"),
+            "rel_err_vs_f32_reference": float(f"{err_hi:.3e}"),
+            "mosaic_calls_lowered": mosaic_calls(kern, flat),
+            "first_call_s": round(first_s, 2)}
+
+
+def check_quant(shape, bits: int, interpret=None, wire_rows: int = 3) -> dict:
+    """Kernel == jnp reference bit for bit on the device, and == the numpy
+    wire codec byte for byte on a few rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.comm.codec import pack_int4, stochastic_quantize
+    from fedml_tpu.ops.pallas import fused_quantize_pack
+
+    C, m = shape
+    vals = np.random.default_rng(3 + bits).standard_normal(shape).astype(
+        np.float32)
+    vals[0, :300] = 0.0           # an all-zero chunk: the amax == 0 scale
+    cids = np.arange(7, 7 + C, dtype=np.uint32)
+    seed, rnd, leaf = 13, 2, 99
+
+    def run(use_kernel):
+        return lambda v, r, c: fused_quantize_pack(  # noqa: E731
+            v, bits, seed, r, c, leaf, use_kernel=use_kernel,
+            interpret=interpret if use_kernel else None)
+
+    args = (jnp.asarray(vals), jnp.uint32(rnd), jnp.asarray(cids))
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(jax.jit(run(True))(*args))
+    first_s = time.perf_counter() - t0
+    want = jax.jit(run(False))(*args)
+    mismatched = {
+        name: int(np.count_nonzero(np.asarray(a) != np.asarray(b)))
+        for name, a, b in zip(("packed", "scales", "dec"), got, want)}
+    require(not any(mismatched.values()),
+            f"fused_quantize_pack q{bits} differs from its reference "
+            f"(elements): {mismatched}")
+    packed, scales, dec = (np.asarray(a) for a in got)
+    for row in np.linspace(0, C - 1, wire_rows).astype(int):
+        q, s, d = stochastic_quantize(vals[row], bits, seed, rnd,
+                                      int(cids[row]), leaf)
+        wire = pack_int4(q) if bits == 4 else q
+        require(np.array_equal(packed[row], wire)
+                and np.array_equal(scales[row], s)
+                and np.array_equal(dec[row], d),
+                f"fused_quantize_pack q{bits} row {row} differs from the "
+                "numpy wire codec")
+    return {"shape": list(shape), "bits": bits, "bit_identical": True,
+            "mosaic_calls_lowered": mosaic_calls(run(True), *args),
+            "first_call_s": round(first_s, 2)}
+
+
+def check_conv(stage, dtype_name: str = "bfloat16") -> dict:
+    """conv2d_pallas forward and both gradients against XLA's conv."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.conv import conv2d_pallas
+
+    b, hw, c = stage
+    dtype = jnp.dtype(dtype_name)
+    rng = np.random.default_rng(4 + hw)
+    x = jnp.asarray(rng.standard_normal((b, hw, hw, c)), dtype)
+    w = jnp.asarray(rng.standard_normal((3, 3, c, c)) * 0.1, dtype)
+    cot = jnp.asarray(rng.standard_normal((b, hw, hw, c)), jnp.float32)
+
+    def xla_conv(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
+
+    def loss_of(conv):
+        def loss(x, w):
+            out = conv(x, w)
+            return (out.astype(jnp.float32) * cot).sum(), out
+        return loss
+
+    def fwd_bwd(conv):
+        return jax.jit(jax.value_and_grad(loss_of(conv), (0, 1), has_aux=True))
+
+    pallas = lambda x, w: conv2d_pallas(x, w, 1, "SAME")  # noqa: E731
+    t0 = time.perf_counter()
+    (_, out_p), g_p = jax.block_until_ready(fwd_bwd(pallas)(x, w))
+    first_s = time.perf_counter() - t0
+    (_, out_x), g_x = fwd_bwd(xla_conv)(x, w)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    errs = {"out": rel_err(out_p, out_x), "dx": rel_err(g_p[0], g_x[0]),
+            "dw": rel_err(g_p[1], g_x[1])}
+    for name, e in errs.items():
+        require(np.isfinite(e) and e <= tol,
+                f"conv2d_pallas {stage} {name}: relative error {e:.3e} > {tol}")
+    return {"stage": list(stage), "dtype": dtype_name,
+            "rel_err": {k: float(f"{e:.3e}") for k, e in errs.items()},
+            # forward, dx (the forward kernel again) and dw
+            "mosaic_calls_lowered": mosaic_calls(
+                jax.grad(loss_of(pallas), (0, 1), has_aux=True), x, w),
+            "first_call_s": round(first_s, 2)}
+
+
+# --------------------------------------------------------------- stage 5
+
+def check_four_chips(before: list, fl: dict, lm: dict,
+                     lm_one_chip=None) -> dict:
+    """The flagship over the client mesh (``fl``: stage 2 with
+    backend="TPU") and the LM step with dp=2 x tp=2 (``lm``) must each have
+    put every device to work. ``before`` is memory_stats() from before
+    the two ran; each stage's own ``memory`` was read while its arrays
+    were still alive."""
+    n_dev = len(before)
+    mesh_lines = [ln for ln in fl["engaged"] if ln.startswith("SimulatorTPU:")]
+    require(mesh_lines and f"over {n_dev} of {n_dev} devices" in mesh_lines[0],
+            f"flagship mesh leaves devices idle: {fl['engaged']}")
+    # a device nothing ran on reports a zero peak; the replicated train set
+    # and the round's temporaries raise it on every device of the mesh
+    unused = [i for i, m in enumerate(fl["memory"])
+              if m.get("peak_bytes_in_use", 1) <= 0]
+    require(not unused, f"flagship: devices {unused} were never used")
+    require(lm["param_devices"] == 4,
+            f"LM params live on {lm['param_devices']} devices, not 4")
+    require(lm["largest_param_shard"] != lm["largest_param"],
+            "LM params are replicated, not tensor-sharded")
+    empty = [i for i, m in enumerate(lm["memory"][:4])
+             if m.get("bytes_in_use", 1) <= 0]
+    require(not empty, f"lm: devices {empty} hold no shard of the trainer")
+    if lm_one_chip is not None and lm_one_chip["batch"] == lm["batch"]:
+        gap = abs(lm["loss"][0] - lm_one_chip["loss"][0])
+        require(gap <= 2e-2, "dp=2 x tp=2 first-step loss differs from the "
+                f"one-chip step by {gap:.4f}")
+    return {
+        "flagship_mesh": mesh_lines[0],
+        "lm_mesh": lm["mesh"],
+        "peak_bytes_in_use": {
+            "before": [m.get("peak_bytes_in_use") for m in before],
+            "after_flagship": [m.get("peak_bytes_in_use")
+                               for m in fl["memory"]],
+            "after_lm": [m.get("peak_bytes_in_use") for m in lm["memory"]]},
+        "lm_bytes_in_use": [m.get("bytes_in_use") for m in lm["memory"]],
+    }
+
+
+# ------------------------------------------------------------------ main
+
+def main() -> int:
+    try:
+        import fedml_tpu  # noqa: F401
+        from fedml_tpu.utils.compile_cache import configure_compile_cache
+    except ImportError as e:
+        print(f"chip_smoke: the fedml_tpu package is not importable from "
+              f"here ({e}); run from the root of a checkout", file=sys.stderr)
+        return 2
+    cache_dir = configure_compile_cache()   # before the first compile
+    meter = CompileMeter()
+    logging.basicConfig(level=logging.WARNING, stream=sys.stderr)
+    t_start = time.perf_counter()
+
+    def run(name, fn, *args, **kwargs):
+        seen = dict(meter.totals)
+        t0 = time.perf_counter()
+        out = fn(*args, **kwargs)
+        out = {**out, "wall_s": round(time.perf_counter() - t0, 2),
+               "compile": meter.since(seen)}
+        print(f"[chip_smoke] {name}: {json.dumps(out)}", flush=True)
+        return out
+
+    dev = stage_device()
+    if dev["platform"] != "tpu":
+        # nothing goes to stdout off the chip: no result was produced
+        print(f"chip_smoke: platform is {dev['platform']!r} "
+              f"({dev['device_kind']}), not 'tpu' — this check only passes "
+              "on the chip", file=sys.stderr)
+        return 1
+    print(f"[chip_smoke] device: {json.dumps(dev)}", flush=True)
+    print(f"[chip_smoke] compile cache: "
+          f"{cache_dir or 'JAX_COMPILATION_CACHE_DIR (placed from outside)'}",
+          flush=True)
+
+    fl = run("flagship", stage_flagship, FLAGSHIP, FLAGSHIP_ROUNDS)
+    require(any("schedule=packed" in ln for ln in fl["engaged"]),
+            f"flagship did not take the packed schedule: {fl['engaged']}")
+
+    lm = run("lm", stage_lm, LM_MODEL, LM_SEQ, LM_BATCH, LM_STEPS)
+    require(lm["attention_impl"] == "flash",
+            f"attention dispatched to {lm['attention_impl']} at T={LM_SEQ}")
+    # forward, dq and dk/dv kernels in every layer (one more forward each
+    # where the block is rematerialised)
+    require(lm["mosaic_calls_lowered"] >= 3 * LM_MODEL["num_layers"],
+            f"LM step lowers to {lm['mosaic_calls_lowered']} Mosaic calls: "
+            "the flash kernels did not engage compiled")
+    fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
+              LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
+    require(fvd["mosaic_calls_lowered"] >= 3, "flash check ran interpreted")
+
+    kernels = [run("fused_gram", check_gram, GRAM_SHAPE),
+               run("fused_quantize_pack_q8", check_quant, QUANT_SHAPE, 8),
+               run("fused_quantize_pack_q4", check_quant, QUANT_SHAPE, 4)]
+    kernels += [run(f"conv2d_pallas_{hw}x{hw}x{c}", check_conv, (b, hw, c))
+                for b, hw, c in CONV_STAGES]
+    for res in kernels:
+        require(res["mosaic_calls_lowered"] >= 1,
+                f"kernel did not run as a compiled Mosaic call: {res}")
+
+    if dev["device_count"] >= 4:
+        # the anchor cohort of 10 does not divide a client axis of 4: the
+        # engine pads it (packed lanes), the facade no longer shrinks the
+        # mesh to 2 — so this is the anchor config, not a cohort of 12
+        before = memory_stats()
+        fl4 = run("four_chips_flagship", stage_flagship, FLAGSHIP,
+                  FLAGSHIP_ROUNDS, backend="TPU")
+        lm4 = run("four_chips_lm", stage_lm, LM_MODEL, LM_SEQ, LM_BATCH,
+                  LM_STEPS, dp=2, tp=2)
+        run("four_chips", check_four_chips, before, fl4, lm4, lm_one_chip=lm)
+
+    print(f"[chip_smoke] total: wall {time.perf_counter() - t_start:.1f}s, "
+          f"compile {json.dumps(meter.since({}))}", flush=True)
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev["platform"], "kind": dev["device_kind"],
+        "count": dev["device_count"]}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -31,8 +31,8 @@ if __name__ == "__main__":
     p.add_argument("--layers", type=int, default=8)
     p.add_argument("--seq_len", type=int, default=2048)
     p.add_argument("--batch", type=int, default=8)
-    # memory levers (see results/lm_mfu_bench.json for their measured
-    # effect): per-block remat and chunked cross-entropy
+    # memory levers (their effect is not measured on this chip): per-block
+    # remat and chunked cross-entropy
     p.add_argument("--no_remat", action="store_true")
     p.add_argument("--ce_chunk", type=int, default=256,
                    help="0 = full-logit CE; else sequence-chunk size "

@@ -31,9 +31,13 @@ _global_args: Optional[Arguments] = None
 
 
 def init(args: Optional[Arguments] = None, config: Optional[Dict[str, Any]] = None) -> Arguments:
-    """Global init (reference ``fedml.init()``, __init__.py:27): load args,
-    seed, initialize multi-host JAX if env says so."""
+    """Global init (reference ``fedml.init()``, __init__.py:27): place the
+    compile cache, load args, seed, initialize multi-host JAX if env says
+    so."""
     global _global_args
+    from .utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()  # must precede the first compile
     if args is None:
         args = load_arguments(override=config)
     set_seeds(int(getattr(args, "random_seed", 0)))

@@ -11,9 +11,7 @@ This started life as the standalone 78-line ``scripts/check_no_print.py``
 lint; that script is now a thin shim over this checker (same allowlist,
 same exit semantics), and ``tests/test_no_print.py`` keeps both honest.
 
-Allowlist: ``fedml_tpu/utils/chip_probe.py`` (child-process probe protocol
-speaks over stdout by design) and ``fedml_tpu/cli/`` (a CLI's job is to
-print).
+Allowlist: ``fedml_tpu/cli/`` (a CLI's job is to print).
 """
 
 from __future__ import annotations
@@ -23,7 +21,6 @@ from typing import Iterable, List, Tuple
 
 from .core import Checker, Finding, Module
 
-ALLOWLIST_FILES = {"fedml_tpu/utils/chip_probe.py"}
 ALLOWLIST_DIRS = ("fedml_tpu/cli/",)
 
 
@@ -53,8 +50,6 @@ class NoPrintChecker(Checker):
     description = "bare print() calls in library code (use logging/telemetry)"
 
     def interested(self, relpath: str) -> bool:
-        if relpath in ALLOWLIST_FILES:
-            return False
         return not relpath.startswith(ALLOWLIST_DIRS)
 
     def visit_module(self, module: Module) -> Iterable[Finding]:

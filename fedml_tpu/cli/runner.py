@@ -238,6 +238,10 @@ class FedMLEdgeRunner:
             # fork/exec outside the lock — callbacks run on one dispatcher
             # thread, so only the self._proc handoff below needs the lock
             # (the watcher thread compares identity before acting)
+            # the training child is the process that claims the chip(s): this
+            # daemon never initialises a JAX backend (importing fedml_tpu
+            # does not — tests/test_process_start.py pins it) and runs one
+            # child at a time (a superseded run was killed above)
             with open(log_path, "w") as log:
                 # the child duplicates the log fd; close the parent's copy
                 proc = subprocess.Popen(

@@ -2,7 +2,7 @@
 
 These read the exact on-disk formats the reference consumes, with their
 *natural* per-user client partitions (the whole point of femnist/shakespeare —
-VERDICT r1 #4):
+round-1 review #4):
 
 - LEAF JSON dirs (``train/*.json`` + ``test/*.json`` with keys ``users``,
   ``num_samples``, ``user_data``): reference ``data/MNIST/data_loader.py:32

@@ -11,6 +11,7 @@ string; on one host with one chip everything collapses to jax.devices()[0].
 
 from __future__ import annotations
 
+import logging
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -27,6 +28,14 @@ def get_device(args=None):
         )
         idxs = mapping_for_rank(mapping, rank)
         return [devices[i] for i in idxs if i < len(devices)]
+    if rank >= len(devices):
+        # a TPU chip belongs to one process: ranks that wrap onto a device
+        # another rank already maps to cannot both open it
+        logging.warning(
+            "get_device: rank %d wraps onto device %d of %d (%s) — more "
+            "ranks than devices share a device, which a TPU refuses; give "
+            "each process its own chip or a gpu_mapping_file",
+            rank, rank % len(devices), len(devices), devices[0].platform)
     return devices[rank % len(devices)]
 
 

@@ -89,8 +89,8 @@ def create(args, output_dim: int):
         norm = getattr(args, "norm", "group")
         # conv_impl: "xla" (default) | "im2col" | "pallas" — the multi-weight
         # conv paths (ops/conv.py) for per-lane-weight execution experiments;
-        # measured on the v5e the XLA path wins at ResNet-56's shapes
-        # (results/lane_sweep_r4.json), so it stays the default
+        # XLA's conv is the default (the three are not compared on this
+        # chip)
         conv_impl = getattr(args, "conv_impl", None) or "xla"
         return CifarResNet(depth=depth, num_classes=output_dim,
                            norm_kind=norm, dtype=dtype, conv_impl=conv_impl)

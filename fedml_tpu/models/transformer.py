@@ -39,8 +39,9 @@ class SelfAttention(nn.Module):
     num_heads: int
     causal: bool = True
     dtype: jnp.dtype = jnp.float32
-    # sequence parallelism: when set (with ``mesh``), attention runs
-    # sequence-sharded inside shard_map over this mesh axis.
+    # ``mesh``: the mesh the enclosing step is partitioned over (None =
+    # single device). sequence parallelism: when ``seq_axis`` is set,
+    # attention runs sequence-sharded inside shard_map over that mesh axis.
     # ``sp_impl`` picks the collective pattern (ops/attention.py):
     #   "ring"    — K/V blocks rotate via ppermute, online softmax;
     #               O(T/n) memory per device (extreme context lengths).
@@ -54,11 +55,7 @@ class SelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from ..ops.attention import (
-            multihead_attention,
-            ring_attention,
-            ulysses_attention,
-        )
+        from ..ops.attention import ring_attention, ulysses_attention
 
         B, T, D = x.shape
         H = self.num_heads
@@ -87,10 +84,42 @@ class SelfAttention(nn.Module):
                 check_vma=False,
             )(q, k, v)
         else:
-            out = multihead_attention(q, k, v, causal=self.causal,
-                                      impl=self.attn_impl)
+            out = self._local_attention(q, k, v)
         out = out.reshape(B, T, D)
         return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="proj")(out)
+
+    def _local_attention(self, q, k, v):
+        """Attention with no sequence axis. GSPMD cannot partition a Mosaic
+        kernel (jax refuses to lower one inside a sharded jit), so when the
+        flash path engages under a data x model mesh the call is wrapped in
+        shard_map: batch and heads are independent, each device runs the
+        kernel on its own (B/dp, T, H/tp, Dh) shard. The dense path stays
+        plain XLA, which GSPMD partitions itself."""
+        from ..ops.attention import auto_attention_impl, multihead_attention
+
+        B, T, H, Dh = q.shape
+        impl, mesh = self.attn_impl, self.mesh
+        if mesh is not None and mesh.size > 1:
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            from ..parallel.mesh import AXIS_DATA, AXIS_MODEL
+
+            b_ax = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
+            h_ax = AXIS_MODEL if mesh.shape.get(AXIS_MODEL, 1) > 1 else None
+            dp = mesh.shape[b_ax] if b_ax else 1
+            tp = mesh.shape[h_ax] if h_ax else 1
+            if B % dp == 0 and H % tp == 0 and (impl or auto_attention_impl(
+                    B // dp, H // tp, T, Dh,
+                    jnp.dtype(q.dtype).itemsize)) == "flash":
+                spec = P(b_ax, None, h_ax, None)
+                return shard_map(
+                    lambda q, k, v: multihead_attention(
+                        q, k, v, causal=self.causal, impl="flash"),
+                    mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                    check_vma=False,
+                )(q, k, v)
+        return multihead_attention(q, k, v, causal=self.causal, impl=impl)
 
 
 class Block(nn.Module):
@@ -133,7 +162,7 @@ class TransformerLM(nn.Module):
     # matmul OUTPUTS are saved and only cheap elementwise/norm ops
     # recompute, trading some of full-remat's memory win to reclaim most
     # of its recompute FLOPs (the classic middle point on the
-    # memory/compute curve; A/B'd by scripts/bench_lm_attribution_r5.py)
+    # memory/compute curve; not measured on this chip)
     remat: Union[bool, str] = False
 
     @nn.compact

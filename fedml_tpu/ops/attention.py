@@ -1,9 +1,8 @@
 """Attention ops: dense multihead attention + ring attention over a seq axis.
 
-Single-chip path is plain XLA (it fuses QK^T -> softmax -> V well on the MXU
-for moderate T; a pallas flash kernel is the planned upgrade — see
-ops/pallas/). The ring path implements blockwise ring attention
-(Liu et al.) with ``lax.ppermute`` over the ``seq`` mesh axis: each shard
+Single-chip path is plain XLA for moderate T and the pallas flash kernel
+(ops/pallas/flash_attention.py) past the crossover. The ring path
+implements blockwise ring attention (Liu et al.) with ``lax.ppermute`` over the ``seq`` mesh axis: each shard
 holds a query block, K/V blocks rotate around the ring, and softmax is
 accumulated online (running max + normalizer), so memory stays O(T/n per
 device) and comms ride ICI. This is the long-context capability the task
@@ -23,10 +22,9 @@ def auto_attention_impl(B: int, H: int, T: int, Dh: int,
                         itemsize: int = 2) -> str:
     """Pick 'flash' vs 'dense' for (B, T, H, Dh) attention.
 
-    Speed: measured crossover (results/flash_attention_bench.json) — XLA's
-    fused dense attention holds a slight edge below T=4096 on the v5e
-    (0.88-0.99x); from 4096 the K-blocked kernel wins 2x+ and is the only
-    option once (T, T) logits stop fitting in HBM.
+    Speed: the T >= 4096 crossover is a constant carried over from earlier
+    rounds and is not measured on this chip; past it the K-blocked kernel
+    is in any case the only option once (T, T) logits stop fitting in HBM.
 
     Memory: BELOW the speed crossover, dense training saves the
     (B, H, T, T) probabilities for the backward pass PER LAYER — a
@@ -35,9 +33,9 @@ def auto_attention_impl(B: int, H: int, T: int, Dh: int,
     HBM once multiplied by typical depths).
 
     A BLOCK_TABLE entry for T (ops/pallas/flash_attention.py — populated
-    only from confirmed on-chip sweeps, scripts/bench_flash_blocks_r5.py)
-    means flash measured at-or-faster than dense at that length with the
-    tabled blocks, so it lowers the crossover for exactly that T — but
+    only from a sweep on the chip; empty today) means flash measured
+    at-or-faster than dense at that length with the tabled blocks, so it
+    lowers the crossover for exactly that T — but
     only at the SWEPT shape family (Dh=64 bf16): at other Dh/itemsize the
     kernel's guards would reject the tabled blocks and run unmeasured
     auto squares, a config the table says nothing about.

@@ -3,9 +3,8 @@
 Why this exists: the packed-lane cohort executor (``simulation/fed_sim.py``)
 vmaps the whole local-update over the lane axis, so every conv sees
 *per-lane weights*. XLA lowers a weight-batched conv to a grouped
-convolution, whose thin per-group channels starve the 128-wide MXU — the
-measured penalty on the v5e is ~1.5x at the 32x32x16 stage and ~4.7x at
-16x16x64 (``results/lane_sweep_r3.json``). The reference has no analogue
+convolution, whose thin per-group channels starve the 128-wide MXU (how
+much is not measured on this chip). The reference has no analogue
 (its clients train sequentially in Python — ``simulation/sp/fedavg/
 my_model_trainer_classification.py:15``); this is a TPU-native problem and
 gets a TPU-native fix:
@@ -39,6 +38,8 @@ from typing import Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # --- pure-JAX im2col ------------------------------------------------------
 
@@ -90,13 +91,6 @@ def conv2d_im2col(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
 
 
 # --- pallas fused kernel --------------------------------------------------
-
-try:  # pallas import kept lazy-tolerant: CPU test envs lack Mosaic only at trace
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
 
 
 def _pick_block_b(b: int, h: int, w: int, ci: int, kk: int, co: int,
@@ -170,7 +164,7 @@ def _pad_same(x, kh, kw, stride):
 
 
 def _supported(x_shape, w_shape, stride, padding) -> bool:
-    if not _HAS_PALLAS or len(w_shape) != 4:
+    if len(w_shape) != 4:
         return False
     kh, kw, _, _ = w_shape
     return (padding == "SAME" and stride == 1 and kh == kw == 3
@@ -189,8 +183,6 @@ def conv2d_pallas(x: jnp.ndarray, w: jnp.ndarray, stride: int = 1,
 
 
 def _conv2d_pallas_impl(x, w, stride, padding):
-    if not _HAS_PALLAS:
-        raise RuntimeError("conv2d_pallas requires jax.experimental.pallas")
     if not _supported(x.shape, w.shape, stride, padding):
         raise ValueError(
             "conv2d_pallas supports only 3x3 kernels, stride 1, SAME padding "

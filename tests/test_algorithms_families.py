@@ -170,7 +170,7 @@ def test_fedseg_transunet_learns():
 @pytest.mark.slow
 def test_fedseg_deeplab_learns_and_beats_unet_control():
     """DeepLabV3+ (reference app/fedcv/image_segmentation/model/
-    deeplabV3_plus.py) trains federated, learns, and — VERDICT r3 #4 —
+    deeplabV3_plus.py) trains federated, learns, and — round-3 review #4 —
     earns its ASPP/decoder depth: same federated budget on the 4-class
     medical segmentation task, at least UNetLite's per-pixel accuracy.
     (slow: ~20 distinct conv shapes to compile on one CPU core; one

@@ -1,4 +1,4 @@
-"""Workload-aware cohort scheduling (VERDICT #10): the DP bucket scheduler
+"""Workload-aware cohort scheduling (review #10): the DP bucket scheduler
 wired into FedSimulator cuts padded compute for skewed cohorts while
 matching the even path's aggregation numerics."""
 

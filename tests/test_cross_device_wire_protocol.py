@@ -1,7 +1,7 @@
 """Cross-device protocol conformance: a stand-in "phone" that speaks ONLY
 the public wire format — raw-socket MQTT 3.1.1 + the documented msgpack
 message encoding — against the real cross-device server over the real-wire
-broker (VERDICT r4 #8; reference test/android_protocol_test/test_protocol.py
+broker (round-4 review #8; reference test/android_protocol_test/test_protocol.py
 keeps the same kind of Python stand-in for its Android client).
 
 The stand-in deliberately imports NOTHING from fedml_tpu.comm or

@@ -43,7 +43,7 @@ def test_flash_gradients_match_dense(causal):
 
 
 def test_flash_gradients_long_context_T1024():
-    """VERDICT #8 done-criterion: grad-vs-dense allclose at T=1024 and the
+    """review #8 done-criterion: grad-vs-dense allclose at T=1024 and the
     (T, T) buffer absent from the compiled flash backward."""
     q, k, v = _qkv(B=1, T=1024, H=1, Dh=64, seed=3)
 

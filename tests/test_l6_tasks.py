@@ -148,7 +148,7 @@ def test_fedgraphnn_recsys_rating_completion_learns():
 
 def test_all_reference_fedgraphnn_dirs_have_dataset_aliases():
     """Every task directory under the reference app/fedgraphnn tree must
-    resolve through data.load (capability-parity check, VERDICT r3 #5)."""
+    resolve through data.load (capability-parity check, round-3 review #5)."""
     from fedml_tpu import data as data_mod
 
     for name in ("moleculenet", "moleculenet_reg", "ego_networks_node_clf",

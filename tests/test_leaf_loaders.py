@@ -2,7 +2,7 @@
 
 Fixtures are generated by scripts/make_leaf_fixtures.py in the exact on-disk
 formats the reference consumes; these tests exercise the real parse paths and
-the natural per-user client partitions (VERDICT r1 #4).
+the natural per-user client partitions (round-1 review #4).
 """
 
 import os

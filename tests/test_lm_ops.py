@@ -1,7 +1,7 @@
 """LM training ops: chunked cross-entropy and block rematerialization.
 
-These are the memory levers of the MFU flagship (scripts/bench_lm_mfu.py):
-both must be pure memory/time tradeoffs — numerics identical to the naive
+These are the memory levers of the LM trainer (DistTrainConfig): both must
+be pure memory/time tradeoffs — numerics identical to the naive
 formulations.
 """
 

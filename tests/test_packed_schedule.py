@@ -140,7 +140,7 @@ def test_packed_on_mesh_matches_sp():
 
 @pytest.mark.slow
 def test_packed_mesh_size_sweep_matches_sp():
-    """VERDICT r3 #6: the packed path must compose at EVERY mesh size, with
+    """round-3 review #6: the packed path must compose at EVERY mesh size, with
     per-device lane shards scaling as devices grow — 2/4/8-device meshes
     all reproduce the SP result, and the lane grid G divides by the axis
     size (so each device owns G/axis lanes)."""
