@@ -11,9 +11,13 @@ One process, the normal entry points, no bench-only switch:
                (dim 1024, 12 layers, 16 heads, vocab 32000, bf16, AdamW)
                at T=4096, where attention dispatches to the flash kernel
 4. kernels   — every other pallas_call against its in-repo reference:
-               fused_gram, fused_quantize_pack q8/q4, conv2d_pallas
-5. four chips (only when jax sees >= 4): stage 2 with backend="TPU" (the
-               client mesh over every device) and stage 3 with dp=2 x tp=2
+               fused_gram (alone at a 1000-row cohort, and inside
+               fused_sanitize_krum on a flagship cohort of ResNet-56
+               updates), fused_quantize_pack q8/q4, conv2d_pallas
+5. four chips (only when jax sees >= 4, and then FIRST, while every
+               device's peak-memory counter is still untouched): stage 2
+               with backend="TPU" (the client mesh over every device) and
+               stage 3 with dp=2 x tp=2
 
 Any exception, NaN or failed check ends the run with a non-zero code; the
 last line of stdout is the result JSON only when every stage passed. There
@@ -45,6 +49,7 @@ LM_SEQ = 4096                 # auto_attention_impl -> "flash" from here
 LM_BATCH = 4                  # ~5.5 GB of the v5e's 16 GB (XLA's own estimate)
 LM_STEPS = 4
 GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
+GRAM_REFUSED = (10, 1_000_000)  # wider than full-row tiles fit in VMEM
 QUANT_SHAPE = (1000, 65536)
 # ResNet-56's three stages at the flagship batch: (batch, height/width, chans)
 CONV_STAGES = ((64, 32, 16), (64, 16, 32), (64, 8, 64))
@@ -78,14 +83,15 @@ def mosaic_calls(fn, *args) -> int:
 
 
 def memory_stats() -> list:
-    """Per-device allocator counters, where the backend reports them."""
+    """Per-device allocator counters. A backend that keeps none (the CPU)
+    gives empty dicts; one that does must have both."""
     import jax
 
     out = []
     for d in jax.devices():
-        ms = d.memory_stats() or {}
-        out.append({k: int(ms[k]) for k in
-                    ("bytes_in_use", "peak_bytes_in_use") if k in ms})
+        ms = d.memory_stats()
+        out.append({} if not ms else {
+            k: int(ms[k]) for k in ("bytes_in_use", "peak_bytes_in_use")})
     return out
 
 
@@ -342,14 +348,116 @@ def check_gram(shape, interpret=None) -> dict:
             "first_call_s": round(first_s, 2)}
 
 
-def check_quant(shape, bits: int, interpret=None, wire_rows: int = 3) -> dict:
-    """Kernel == jnp reference bit for bit on the device, and == the numpy
-    wire codec byte for byte on a few rows."""
+def check_gram_refuses(shape, interpret=None) -> dict:
+    """Past the width its full-row tiles hold, the compiled dispatch must
+    refuse by type — not hand back the reference under the kernel's name."""
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu.ops.pallas import GramKernelShapeError, fused_gram
+
+    try:
+        jax.eval_shape(lambda x: fused_gram(x, interpret=interpret),
+                       jax.ShapeDtypeStruct(shape, jnp.float32))
+    except GramKernelShapeError as e:
+        return {"shape": list(shape), "refused": str(e)}
+    raise CheckFailed(f"fused_gram took a {shape} stack without the kernel "
+                      "and without an error")
+
+
+def model_param_shapes(config: dict, input_shape, classes: int):
+    """The config's model's parameter pytree, shapes only."""
+    from types import SimpleNamespace
+
+    import jax
+    import jax.numpy as jnp
+
+    from fedml_tpu import models
+
+    model = models.create(SimpleNamespace(**config), classes)
+    return jax.eval_shape(lambda: models.init_params(
+        model, jax.random.PRNGKey(0),
+        jnp.zeros((1,) + tuple(input_shape), jnp.float32)))["params"]
+
+
+def check_sanitize_krum(template, cohort: int, interpret=None) -> dict:
+    """The agg_kernels defense path at a model's real width:
+    fused_sanitize_krum (its Gram plane in the Pallas kernel) against the
+    sanitize_stacked -> krum_aggregate pair the simulator runs without it,
+    on a seeded cohort of updates shaped like ``template`` in which client
+    1 uploads a NaN and client 2 a boosted update."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from fedml_tpu.comm.codec import pack_int4, stochastic_quantize
+    from fedml_tpu.core.robust import (
+        fused_sanitize_krum,
+        krum_aggregate,
+        sanitize_stacked,
+    )
+
+    leaves, treedef = jax.tree_util.tree_flatten(template)
+    rng = np.random.default_rng(5)
+    stack = [(0.01 * rng.standard_normal((cohort,) + tuple(leaf.shape))
+              ).astype(np.float32) for leaf in leaves]
+    stack[0][1].flat[0] = np.nan
+    for rows in stack:
+        rows[2] *= 1e3
+    updates = jax.tree_util.tree_unflatten(
+        treedef, [jnp.asarray(rows) for rows in stack])
+    weights = jnp.full((cohort,), 8.0, jnp.float32)
+    n_byz, m = 2, 3
+
+    def fused(u, w):
+        return fused_sanitize_krum(u, w, n_byz=n_byz, m=m, use_kernel=True,
+                                   interpret=interpret)
+
+    def unfused(u, w):
+        clean, cw, quar, z = sanitize_stacked(u, w)
+        agg, sel = krum_aggregate(clean, cw, n_byz=n_byz, m=m)
+        return agg, cw, quar, z, sel
+
+    t0 = time.perf_counter()
+    agg_f, _, quar_f, _, sel_f = jax.block_until_ready(
+        jax.jit(fused)(updates, weights))
+    first_s = time.perf_counter() - t0
+    agg_u, _, quar_u, _, sel_u = jax.jit(unfused)(updates, weights)
+    quarantined = np.flatnonzero(np.asarray(quar_f)).tolist()
+    require(quarantined == [1, 2] and np.array_equal(quar_f, quar_u),
+            f"quarantine: fused {quarantined}, unfused "
+            f"{np.flatnonzero(np.asarray(quar_u)).tolist()}, planted [1, 2]")
+    selected = np.flatnonzero(np.asarray(sel_f)).tolist()
+    require(len(selected) == m and np.array_equal(sel_f, sel_u),
+            f"Krum selection: fused {selected}, unfused "
+            f"{np.flatnonzero(np.asarray(sel_u)).tolist()}")
+    flatten = lambda t: np.concatenate(  # noqa: E731
+        [np.asarray(x, np.float64).ravel()
+         for x in jax.tree_util.tree_leaves(t)])
+    err = rel_err(flatten(agg_f), flatten(agg_u))
+    require(np.isfinite(err) and err <= 1e-6,
+            f"fused vs unfused Krum aggregate: relative error {err:.3e}")
+    return {"cohort": cohort,
+            "width": int(sum(np.prod(leaf.shape) for leaf in leaves)),
+            "quarantined": quarantined, "selected": selected,
+            "agg_rel_err_vs_unfused": float(f"{err:.3e}"),
+            "mosaic_calls_lowered": mosaic_calls(fused, updates, weights),
+            "first_call_s": round(first_s, 2)}
+
+
+def check_quant(shape, bits: int, interpret=None) -> dict:
+    """Kernel == its jnp reference bit for bit on the device; and, since
+    those two share their arithmetic, also == the two codecs written
+    independently of it: the decode of the unfused XLA path over the whole
+    stack, and the numpy wire codec byte for byte on every row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.comm.codec import (
+        _quant_roundtrip_jnp,
+        pack_int4,
+        stochastic_quantize,
+    )
     from fedml_tpu.ops.pallas import fused_quantize_pack
 
     C, m = shape
@@ -376,7 +484,14 @@ def check_quant(shape, bits: int, interpret=None, wire_rows: int = 3) -> dict:
             f"fused_quantize_pack q{bits} differs from its reference "
             f"(elements): {mismatched}")
     packed, scales, dec = (np.asarray(a) for a in got)
-    for row in np.linspace(0, C - 1, wire_rows).astype(int):
+    unfused = np.asarray(jax.jit(
+        lambda v, r, c: _quant_roundtrip_jnp(v, bits, seed, r, c, leaf, jnp)
+    )(*args))
+    n_off = int(np.count_nonzero(dec.view(np.uint32)
+                                 != unfused.view(np.uint32)))
+    require(n_off == 0, f"fused_quantize_pack q{bits}: {n_off} decoded "
+            "elements differ from the unfused codec._quant_roundtrip_jnp")
+    for row in range(C):
         q, s, d = stochastic_quantize(vals[row], bits, seed, rnd,
                                       int(cids[row]), leaf)
         wire = pack_int4(q) if bits == 4 else q
@@ -386,6 +501,7 @@ def check_quant(shape, bits: int, interpret=None, wire_rows: int = 3) -> dict:
                 f"fused_quantize_pack q{bits} row {row} differs from the "
                 "numpy wire codec")
     return {"shape": list(shape), "bits": bits, "bit_identical": True,
+            "wire_rows_checked": C,
             "mosaic_calls_lowered": mosaic_calls(run(True), *args),
             "first_call_s": round(first_s, 2)}
 
@@ -439,42 +555,44 @@ def check_conv(stage, dtype_name: str = "bfloat16") -> dict:
 
 # --------------------------------------------------------------- stage 5
 
-def check_four_chips(before: list, fl: dict, lm: dict,
-                     lm_one_chip=None) -> dict:
+# every device of a stage's mesh must raise its peak-memory counter by at
+# least this much while the stage runs (the flagship replicates ~0.6 GB of
+# training data per device; the LM shards ~1 GB of params + AdamW state)
+MIN_PEAK_GROWTH = 128 << 20
+
+
+def check_four_chips(before: list, fl: dict, lm: dict) -> dict:
     """The flagship over the client mesh (``fl``: stage 2 with
-    backend="TPU") and the LM step with dp=2 x tp=2 (``lm``) must each have
-    put every device to work. ``before`` is memory_stats() from before
-    the two ran; each stage's own ``memory`` was read while its arrays
-    were still alive."""
+    backend="TPU") and the LM step with dp=2 x tp=2 (``lm``), run in that
+    order, must each have put every device to work: each device's
+    ``peak_bytes_in_use`` grows by ``MIN_PEAK_GROWTH`` or more from
+    ``before`` (memory_stats() read before the two) to the flagship's
+    reading, and again to the LM's. The counter only ever rises, so the
+    two run before any one-chip stage has raised device 0's. A backend
+    that keeps no such counter cannot pass (KeyError)."""
     n_dev = len(before)
     mesh_lines = [ln for ln in fl["engaged"] if ln.startswith("SimulatorTPU:")]
     require(mesh_lines and f"over {n_dev} of {n_dev} devices" in mesh_lines[0],
             f"flagship mesh leaves devices idle: {fl['engaged']}")
-    # a device nothing ran on reports a zero peak; the replicated train set
-    # and the round's temporaries raise it on every device of the mesh
-    unused = [i for i, m in enumerate(fl["memory"])
-              if m.get("peak_bytes_in_use", 1) <= 0]
-    require(not unused, f"flagship: devices {unused} were never used")
-    require(lm["param_devices"] == 4,
-            f"LM params live on {lm['param_devices']} devices, not 4")
+    peaks = [[m["peak_bytes_in_use"] for m in snap]
+             for snap in (before, fl["memory"], lm["memory"])]
+    for name, prev, cur in (("flagship", peaks[0], peaks[1]),
+                            ("lm", peaks[1], peaks[2])):
+        require(len(cur) == n_dev, f"{name}: {len(cur)} devices reported")
+        idle = [i for i in range(n_dev)
+                if cur[i] - prev[i] < MIN_PEAK_GROWTH]
+        require(not idle, f"{name}: devices {idle} did no work — peak "
+                f"bytes {prev} -> {cur}")
+    require(lm["param_devices"] == n_dev,
+            f"LM params live on {lm['param_devices']} devices, not {n_dev}")
     require(lm["largest_param_shard"] != lm["largest_param"],
             "LM params are replicated, not tensor-sharded")
-    empty = [i for i, m in enumerate(lm["memory"][:4])
-             if m.get("bytes_in_use", 1) <= 0]
-    require(not empty, f"lm: devices {empty} hold no shard of the trainer")
-    if lm_one_chip is not None and lm_one_chip["batch"] == lm["batch"]:
-        gap = abs(lm["loss"][0] - lm_one_chip["loss"][0])
-        require(gap <= 2e-2, "dp=2 x tp=2 first-step loss differs from the "
-                f"one-chip step by {gap:.4f}")
     return {
         "flagship_mesh": mesh_lines[0],
         "lm_mesh": lm["mesh"],
-        "peak_bytes_in_use": {
-            "before": [m.get("peak_bytes_in_use") for m in before],
-            "after_flagship": [m.get("peak_bytes_in_use")
-                               for m in fl["memory"]],
-            "after_lm": [m.get("peak_bytes_in_use") for m in lm["memory"]]},
-        "lm_bytes_in_use": [m.get("bytes_in_use") for m in lm["memory"]],
+        "peak_bytes_in_use": dict(zip(
+            ("before", "after_flagship", "after_lm"), peaks)),
+        "lm_bytes_in_use": [m["bytes_in_use"] for m in lm["memory"]],
     }
 
 
@@ -514,6 +632,18 @@ def main() -> int:
           f"{cache_dir or 'JAX_COMPILATION_CACHE_DIR (placed from outside)'}",
           flush=True)
 
+    lm4 = None
+    if dev["device_count"] >= 4:
+        # the anchor cohort of 10 does not divide a client axis of 4: the
+        # engine pads it (packed lanes) and the facade builds the mesh over
+        # all four — so this is the anchor config, not a cohort of 12
+        before = memory_stats()
+        fl4 = run("four_chips_flagship", stage_flagship, FLAGSHIP,
+                  FLAGSHIP_ROUNDS, backend="TPU")
+        lm4 = run("four_chips_lm", stage_lm, LM_MODEL, LM_SEQ, LM_BATCH,
+                  LM_STEPS, dp=2, tp=2)
+        run("four_chips", check_four_chips, before, fl4, lm4)
+
     fl = run("flagship", stage_flagship, FLAGSHIP, FLAGSHIP_ROUNDS)
     require(any("schedule=packed" in ln for ln in fl["engaged"]),
             f"flagship did not take the packed schedule: {fl['engaged']}")
@@ -526,11 +656,19 @@ def main() -> int:
     require(lm["mosaic_calls_lowered"] >= 3 * LM_MODEL["num_layers"],
             f"LM step lowers to {lm['mosaic_calls_lowered']} Mosaic calls: "
             "the flash kernels did not engage compiled")
+    if lm4 is not None:
+        gap = abs(lm4["loss"][0] - lm["loss"][0])
+        require(gap <= 2e-2, "dp=2 x tp=2 first-step loss differs from the "
+                f"one-chip step by {gap:.4f}")
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
     require(fvd["mosaic_calls_lowered"] >= 3, "flash check ran interpreted")
 
+    run("fused_gram_refuses", check_gram_refuses, GRAM_REFUSED)
     kernels = [run("fused_gram", check_gram, GRAM_SHAPE),
+               run("fused_sanitize_krum", check_sanitize_krum,
+                   model_param_shapes(FLAGSHIP, (32, 32, 3), 10),
+                   FLAGSHIP["client_num_per_round"]),
                run("fused_quantize_pack_q8", check_quant, QUANT_SHAPE, 8),
                run("fused_quantize_pack_q4", check_quant, QUANT_SHAPE, 4)]
     kernels += [run(f"conv2d_pallas_{hw}x{hw}x{c}", check_conv, (b, hw, c))
@@ -538,17 +676,6 @@ def main() -> int:
     for res in kernels:
         require(res["mosaic_calls_lowered"] >= 1,
                 f"kernel did not run as a compiled Mosaic call: {res}")
-
-    if dev["device_count"] >= 4:
-        # the anchor cohort of 10 does not divide a client axis of 4: the
-        # engine pads it (packed lanes), the facade no longer shrinks the
-        # mesh to 2 — so this is the anchor config, not a cohort of 12
-        before = memory_stats()
-        fl4 = run("four_chips_flagship", stage_flagship, FLAGSHIP,
-                  FLAGSHIP_ROUNDS, backend="TPU")
-        lm4 = run("four_chips_lm", stage_lm, LM_MODEL, LM_SEQ, LM_BATCH,
-                  LM_STEPS, dp=2, tp=2)
-        run("four_chips", check_four_chips, before, fl4, lm4, lm_one_chip=lm)
 
     print(f"[chip_smoke] total: wall {time.perf_counter() - t_start:.1f}s, "
           f"compile {json.dumps(meter.since({}))}", flush=True)

@@ -9,6 +9,7 @@ reference names MPI/NCCL so reference configs run unchanged).
 
 from __future__ import annotations
 
+import functools
 import logging
 from typing import Optional
 
@@ -18,10 +19,14 @@ from .. import data as data_mod
 from .. import models as models_mod
 from ..algorithms import LocalTrainConfig, get_algorithm
 from ..algorithms.local_sgd import infer_loss_kind as _infer_loss_kind
-from ..constants import FEDML_FEDERATED_OPTIMIZER_FEDAVG_ROBUST
 from ..parallel.mesh import AXIS_CLIENT, AXIS_MODEL, MeshConfig, create_mesh
 from .async_engine import AsyncFedSimulator
-from .fed_sim import FedSimulator, SimConfig, reference_client_sampling
+from .fed_sim import (
+    FedSimulator,
+    SimConfig,
+    pads_cohort,
+    reference_client_sampling,
+)
 from .hierarchical import HierarchicalFedSimulator
 from .decentralized import DecentralizedSimulator
 from .multi_run import MultiTenantSimDriver, TenantJob, TenantRunResult
@@ -45,8 +50,16 @@ __all__ = [
 def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
     """Shared assembly: data + model + algorithm + FedSimulator.
 
+    ``mesh`` is a mesh, None, or a function ``pads -> mesh`` called once
+    the engine is known: ``pads`` says whether that engine pads a cohort
+    the client axis does not divide (:func:`fed_sim.pads_cohort`; the
+    two-level and serverless engines never do).
+
     Returns (simulator, apply_fn).
     """
+    mesh_for = ((lambda pads: mesh)
+                if mesh is None or isinstance(mesh, jax.sharding.Mesh)
+                else mesh)
     if fed_data is None:
         fed_data, output_dim = data_mod.load(args)
     else:
@@ -178,7 +191,7 @@ def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
             sim_cfg,
             group_num=int(getattr(args, "group_num", 2)),
             group_comm_round=int(getattr(args, "group_comm_round", 2)),
-            mesh=mesh,
+            mesh=mesh_for(False),
         )
         return sim, apply_fn
     if optimizer_name.lower() == "tieredfl":
@@ -191,7 +204,7 @@ def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
             variables,
             sim_cfg,
             tier=TierConfig.from_args(args),
-            mesh=mesh,
+            mesh=mesh_for(False),
         )
         return sim, apply_fn
     if optimizer_name.lower() == "decentralized":
@@ -210,7 +223,7 @@ def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
             variables,
             sim_cfg, mixing_matrix=tm.topology,
             mode=str(getattr(args, "decentralized_mode", "dsgd")),
-            mesh=mesh,
+            mesh=mesh_for(False),
         )
         return sim, apply_fn
 
@@ -239,7 +252,8 @@ def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
     update_transform = _make_attack_transform(alg, args) if attack_type else None
     sim_cls = AsyncFedSimulator if sim_cfg.async_mode else FedSimulator
     sim = sim_cls(
-        fed_data, alg, variables, sim_cfg, mesh=mesh,
+        fed_data, alg, variables, sim_cfg,
+        mesh=mesh_for(pads_cohort(alg, update_transform)),
         # raw pieces for the packed cohort schedule's in-scan batch step
         packed_ctx=(apply_fn, cfg, needs_dropout, has_batch_stats),
         # reference test_on_the_server hook: an object with that method
@@ -298,46 +312,46 @@ class SimulatorSingleProcess:
         return self.sim.run(self.apply_fn)
 
 
+def _client_mesh(args, pads: bool):
+    """The client-axis mesh over this process's devices
+    (``args.model_axis_size > 1`` adds the ``model`` axis; the client axis
+    takes the remaining devices). Where the engine cannot pad (``pads``
+    False) the axis shrinks to a divisor of the cohort, and the devices
+    that leaves out are named in a warning."""
+    n_dev = len(jax.devices())
+    model_axis = int(getattr(args, "model_axis_size", 1) or 1)
+    if n_dev % model_axis != 0:
+        raise ValueError(
+            f"model_axis_size={model_axis} must divide the device "
+            f"count ({n_dev})")
+    n_cli = n_dev // model_axis
+    per_round = int(getattr(args, "client_num_per_round", 10))
+    # client axis can't exceed cohort size
+    axis = min(n_cli, per_round) if per_round > 0 else n_cli
+    while not pads and per_round % axis != 0:
+        axis -= 1
+    devices = jax.devices()[: axis * model_axis]
+    axes = ((AXIS_CLIENT, axis),) + (
+        ((AXIS_MODEL, model_axis),) if model_axis > 1 else ())
+    mesh = create_mesh(MeshConfig(axes=axes), devices=devices)
+    idle = jax.devices()[len(devices):]
+    (logging.warning if idle else logging.info)(
+        "SimulatorTPU: mesh %s over %d of %d devices, cohort of %d%s",
+        dict(mesh.shape), len(devices), n_dev, per_round,
+        f"; left idle: {idle}" if idle else "")
+    return mesh
+
+
 class SimulatorTPU:
     """Parrot-TPU: clients sharded over the ICI mesh (replaces SimulatorMPI /
-    SimulatorNCCL, simulator.py:54,206). ``args.model_axis_size > 1`` builds
-    the 2-D ``client`` × ``model`` mesh: the client axis takes the remaining
-    devices and the global model state shards over the model axis."""
+    SimulatorNCCL, simulator.py:54,206). Without a ``mesh`` of the caller's
+    it builds :func:`_client_mesh` once the engine is chosen."""
 
     def __init__(self, args, device=None, dataset=None, model=None, mesh=None):
-        if mesh is None:
-            n_dev = len(jax.devices())
-            model_axis = int(getattr(args, "model_axis_size", 1) or 1)
-            if n_dev % model_axis != 0:
-                raise ValueError(
-                    f"model_axis_size={model_axis} must divide the device "
-                    f"count ({n_dev})")
-            n_cli = n_dev // model_axis
-            per_round = int(getattr(args, "client_num_per_round", 10))
-            # client axis can't exceed cohort size
-            axis = min(n_cli, per_round) if per_round > 0 else n_cli
-            # FedSimulator pads a cohort the axis does not divide (zero-
-            # weight rows / lanes / bucket slots). The axis shrinks to a
-            # divisor only where that padding does not exist or would be
-            # seen: the two-level and serverless engines, a robust
-            # aggregate, an injected attack
-            opt = str(getattr(args, "federated_optimizer", "FedAvg")).lower()
-            pads = (opt not in ("hierarchicalfl", "tieredfl", "decentralized",
-                                FEDML_FEDERATED_OPTIMIZER_FEDAVG_ROBUST.lower())
-                    and not getattr(args, "attack_type", None))
-            while not pads and per_round % axis != 0:
-                axis -= 1
-            devices = jax.devices()[: axis * model_axis]
-            axes = ((AXIS_CLIENT, axis),) + (
-                ((AXIS_MODEL, model_axis),) if model_axis > 1 else ())
-            mesh = create_mesh(MeshConfig(axes=axes), devices=devices)
-            idle = jax.devices()[len(devices):]
-            (logging.warning if idle else logging.info)(
-                "SimulatorTPU: mesh %s over %d of %d devices, cohort of %d%s",
-                dict(mesh.shape), len(devices), n_dev, per_round,
-                f"; left idle: {idle}" if idle else "")
-        self.mesh = mesh
-        self.sim, self.apply_fn = build_simulator(args, dataset, model, mesh=mesh)
+        self.sim, self.apply_fn = build_simulator(
+            args, dataset, model,
+            mesh=mesh or functools.partial(_client_mesh, args))
+        self.mesh = self.sim.mesh
 
     def run(self):
         return self.sim.run(self.apply_fn)

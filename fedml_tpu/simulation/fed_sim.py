@@ -332,6 +332,15 @@ def _cohort_outputs(alg: FedAlgorithm, params, cohort, client_states, rng):
                                data, rngs)
 
 
+def pads_cohort(algorithm, update_transform) -> bool:
+    """Whether the engine may pad a cohort its mesh's client axis does not
+    divide. Padded rows are zero-weight: invisible to the plain weighted
+    mean (and to the packed / bucketed schedules' lane and slot padding),
+    seen by a custom aggregate or an injected attack. The one rule both
+    the engine's own check and the facade's choice of mesh go by."""
+    return algorithm.aggregate is None and update_transform is None
+
+
 class FedSimulator:
     """Generic over FedAlgorithm; placement decided by ``mesh``."""
 
@@ -586,8 +595,7 @@ class FedSimulator:
         self._cohort_pad = 0
         if mesh is not None and not self._packed and not self._bucketed:
             self._cohort_pad = (-cfg.client_num_per_round) % self._axis_size
-        if self._cohort_pad and (self.alg.aggregate is not None
-                                 or update_transform is not None):
+        if self._cohort_pad and not pads_cohort(self.alg, update_transform):
             raise ValueError(
                 f"client_num_per_round={cfg.client_num_per_round} is not a "
                 f"multiple of the '{cfg.cohort_shard_axis}' mesh axis size "

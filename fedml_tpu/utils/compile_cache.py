@@ -10,6 +10,18 @@ from a temp dir, a pid or the clock.
 A process pinned to the CPU platform gets no cache: the cache exists for the
 chip's minutes-long compiles, and the CPU test tier must leave nothing in
 the checkout.
+
+What a warm run still compiles: only the directory is chosen here. jax
+persists a program only when it took a second or more to compile
+(``jax_persistent_cache_min_compile_time_secs``), so a warm run loads
+those and compiles every sub-second program again — most of the programs
+by count, little of the time. Whoever places the cache from outside can
+lower that threshold the same way
+(``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0``).
+
+The in-checkout default is the package's parent directory, which is the
+checkout because the package runs from a source tree (there is no install
+step); an installed copy must be given ``JAX_COMPILATION_CACHE_DIR``.
 """
 
 from __future__ import annotations

@@ -127,6 +127,24 @@ def test_gram_kernel_matches_reference():
     _eq(g_r, jax.vmap(lambda r: flat @ r)(flat))
 
 
+@pytest.mark.parametrize("C,D,vmem_cap", [
+    (100, 300, None),   # one 104-row j block
+    (130, 257, None),   # two 72-row j blocks, cohort padded to 144
+    (100, 300, 8 * (24 * 384 + 16 * 128)),   # VMEM for a 16-row j tile only
+])
+def test_gram_kernel_block_shapes_match_reference(C, D, vmem_cap, monkeypatch):
+    """The j tile's height follows the cohort and the VMEM left by the
+    width; every height gives the reference's bits."""
+    from fedml_tpu.ops.pallas import agg_robust
+
+    if vmem_cap is not None:
+        monkeypatch.setattr(agg_robust, "_VMEM_CAP", vmem_cap)
+        assert agg_robust._block_j(C, D) == 16
+    flat = jnp.asarray(
+        np.random.default_rng(C).standard_normal((C, D)), jnp.float32)
+    _eq(fused_gram(flat, interpret=True), _reference_gram(flat), "gram")
+
+
 @pytest.mark.parametrize("use_kernel", [True, False])
 @pytest.mark.parametrize("m,sample_weighted", [(1, False), (3, True)])
 def test_fused_sanitize_krum_bit_identical(use_kernel, m, sample_weighted):

@@ -59,26 +59,84 @@ def test_kernel_checks_tiny_interpret(monkeypatch):
 
     assert chip_smoke.check_gram((10, 64), interpret=True)[
         "rel_err_vs_reference"] == 0.0
+    assert "agg_kernels off" in chip_smoke.check_gram_refuses(
+        chip_smoke.GRAM_REFUSED, interpret=False)["refused"]
+    with pytest.raises(chip_smoke.CheckFailed, match="without an error"):
+        # the CPU's default dispatch is the reference: not a refusal
+        chip_smoke.check_gram_refuses(chip_smoke.GRAM_REFUSED)
+    krum = chip_smoke.check_sanitize_krum(
+        chip_smoke.model_param_shapes(TINY_FL, (28, 28, 1), 10), cohort=10,
+        interpret=True)
+    assert krum["width"] == 7850 and krum["quarantined"] == [1, 2]
     for bits in (8, 4):
         assert chip_smoke.check_quant((5, 700), bits, interpret=True)[
-            "bit_identical"]
+            "wire_rows_checked"] == 5
     # conv2d_pallas has no interpret argument of its own
     monkeypatch.setattr(
         pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True))
     assert chip_smoke.check_conv((2, 8, 8))["rel_err"]["dw"] <= 2e-2
 
 
-def test_four_chip_checks_tiny_on_virtual_devices():
-    assert len(jax.devices()) >= 4
-    before = chip_smoke.memory_stats()
+def _snapshot(peaks, in_use=None):
+    return [{"peak_bytes_in_use": p, "bytes_in_use": b}
+            for p, b in zip(peaks, in_use or peaks)]
+
+
+# per-device counters of the four-chip run of stage 5 (chip tool, PR 21)
+CHIP_BEFORE = _snapshot([27136] * 4)
+CHIP_FL = _snapshot([1056694784, 925604352, 925604352, 925604352])
+CHIP_LM = _snapshot([3094960128, 2347167744, 2347167744, 2347167744],
+                    [1971065856, 1958284288, 1958284288, 1958284288])
+
+
+def test_four_chip_checks_tiny_on_virtual_devices(monkeypatch):
+    """Both stage-5 runs at a tiny size on four virtual devices; the CPU
+    keeps no allocator counters, so the check gets the chip's own."""
+    monkeypatch.setattr(jax, "devices", lambda *_, four=jax.devices()[:4]: four)
+    assert chip_smoke.memory_stats() == [{}] * 4
     fl = chip_smoke.stage_flagship(
         dict(TINY_FL, client_num_per_round=10), 2, backend="TPU")
     lm = chip_smoke.stage_lm(TINY_LM, seq=16, batch=4, steps=2, dp=2, tp=2)
-    out = chip_smoke.check_four_chips(before, fl, lm)
+    with pytest.raises(KeyError, match="peak_bytes_in_use"):
+        chip_smoke.check_four_chips(fl["memory"], fl, lm)
+    fl, lm = dict(fl, memory=CHIP_FL), dict(lm, memory=CHIP_LM)
+    out = chip_smoke.check_four_chips(CHIP_BEFORE, fl, lm)
     assert out["lm_mesh"] == {"data": 2, "seq": 1, "model": 2}
-    assert f"over {len(before)} of {len(before)} devices" in out["flagship_mesh"]
-    # a mesh that leaves devices idle is refused
-    idle = dict(fl, engaged=["SimulatorTPU: mesh {'client': 2} over 2 of 8 "
+    assert "over 4 of 4 devices" in out["flagship_mesh"]
+    # a mesh that leaves devices idle is refused, whatever the log says of it
+    idle = dict(fl, engaged=["SimulatorTPU: mesh {'client': 2} over 2 of 4 "
                              "devices, cohort of 10; left idle: [...]"])
     with pytest.raises(chip_smoke.CheckFailed, match="idle"):
-        chip_smoke.check_four_chips(before, idle, lm)
+        chip_smoke.check_four_chips(CHIP_BEFORE, idle, lm)
+    two_chips = dict(fl, memory=_snapshot(
+        [1056694784, 925604352, 27136, 27136]))
+    with pytest.raises(chip_smoke.CheckFailed,
+                       match=r"flagship: devices \[2, 3\] did no work"):
+        chip_smoke.check_four_chips(CHIP_BEFORE, two_chips, lm)
+    # a counter an earlier stage already raised cannot vouch for this one
+    with pytest.raises(chip_smoke.CheckFailed,
+                       match=r"lm: devices \[0\] did no work"):
+        chip_smoke.check_four_chips(
+            CHIP_BEFORE, fl, dict(lm, memory=_snapshot(
+                [1056694784] + [2347167744] * 3)))
+
+
+@pytest.mark.parametrize("extra,axis", [
+    ({}, 4),                                               # padded
+    (dict(federated_optimizer="FedAvg_robust", defense_type="krum"), 2),
+    (dict(attack_type="sign_flip"), 2),
+    (dict(federated_optimizer="HierarchicalFL"), 2),
+])
+def test_facade_shrinks_the_client_mesh_only_where_the_engine_cannot_pad(
+        extra, axis, monkeypatch):
+    """A cohort of 10 on four devices: the facade asks the engine's own
+    rule (fed_sim.pads_cohort) and never builds a mesh the engine then
+    refuses."""
+    import fedml_tpu
+    from fedml_tpu.simulation import SimulatorTPU
+
+    monkeypatch.setattr(jax, "devices", lambda *_, four=jax.devices()[:4]: four)
+    args = fedml_tpu.init(config=dict(
+        TINY_FL, client_num_per_round=10, comm_round=1, backend="TPU",
+        **extra))
+    assert dict(SimulatorTPU(args).mesh.shape) == {"client": axis}

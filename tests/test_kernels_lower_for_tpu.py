@@ -14,9 +14,11 @@ import pytest
 
 from fedml_tpu.ops.conv import conv2d_pallas
 from fedml_tpu.ops.pallas import (
+    GramKernelShapeError,
     flash_attention,
     fused_gram,
     fused_quantize_pack,
+    robust_shapes_ok,
 )
 
 
@@ -29,10 +31,24 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-@pytest.mark.parametrize("C", [16, 1000])
-def test_fused_gram_lowers(C):
+# a wide cohort; MNIST-LR's flattened update; the flagship's cohort at
+# ResNet-56's — the widest the full-row tiles hold (8-row j tile)
+@pytest.mark.parametrize("C,D", [(16, 4096), (1000, 4096), (1000, 7850),
+                                 (10, 855770)])
+def test_fused_gram_lowers(C, D):
     assert _mosaic_calls(lambda f: fused_gram(f, interpret=False),
-                         _sds((C, 4096), jnp.float32)) == 1
+                         _sds((C, D), jnp.float32)) == 1
+
+
+def test_fused_gram_past_its_width_is_an_error_not_the_reference():
+    """No kernel exists past the VMEM bound: the compiled dispatch says so
+    by type; only interpret mode (the parity suite's) takes the reference."""
+    wide = _sds((10, 1_000_000), jnp.float32)
+    assert not robust_shapes_ok(*wide.shape)
+    with pytest.raises(GramKernelShapeError, match="agg_kernels off"):
+        jax.eval_shape(lambda f: fused_gram(f, interpret=False), wide)
+    assert jax.eval_shape(lambda f: fused_gram(f, interpret=True),
+                          wide).shape == (10, 10)
 
 
 @pytest.mark.parametrize("bits", [8, 4])
