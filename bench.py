@@ -335,74 +335,6 @@ def host_pack_bench(rounds: int = 20) -> int:
     return 0 if ok else 1
 
 
-def telemetry_overhead_bench(rounds: int = 20, trials: int = 3,
-                             threshold: float = 0.01) -> int:
-    """``--telemetry-overhead``: CPU-only guard for the telemetry cost
-    budget (ISSUE: enabled-vs-disabled delta < 1% of round wall-clock).
-    One simulator, interleaved enabled/disabled 20-round blocks (interleaving
-    cancels thermal/allocator drift), compared on MIN wall per arm — min is
-    the noise-robust estimator for a lower-bounded cost. Also asserts the
-    per-round phase breakdown covers round_time within 5%."""
-    import fedml_tpu
-    from fedml_tpu.core import telemetry, trace_plane
-    from fedml_tpu.simulation import build_simulator
-
-    args = fedml_tpu.init(config=dict(
-        dataset="mnist", model="lr", debug_small_data=True,
-        client_num_in_total=20, client_num_per_round=10, comm_round=rounds,
-        learning_rate=0.1, epochs=1, batch_size=8,
-        frequency_of_the_test=10_000, random_seed=0,
-    ))
-    sim, _ = build_simulator(args)
-    sim.run(apply_fn=None, log_fn=None)  # compile warm-up (discarded)
-
-    def _block(enabled: bool) -> float:
-        telemetry.configure(enabled=enabled)
-        # the <1% budget must hold with the full trace plane armed, not
-        # just the PR 2 metrics layer (ISSUE 10 acceptance)
-        trace_plane.configure(ship_spans=enabled, anomaly_detection=enabled,
-                              flight_recorder=enabled)
-        sim.history.clear()
-        t0 = time.perf_counter()
-        sim.run(apply_fn=None, log_fn=None)
-        return time.perf_counter() - t0
-
-    walls = {True: [], False: []}
-    for _ in range(trials):
-        for enabled in (True, False):
-            walls[enabled].append(_block(enabled))
-    on, off = min(walls[True]), min(walls[False])
-    overhead = (on - off) / off if off > 0 else 0.0
-    # phase coverage from the last ENABLED block's history
-    telemetry.configure(enabled=True)
-    trace_plane.configure(ship_spans=True, anomaly_detection=True,
-                          flight_recorder=True)
-    sim.history.clear()
-    sim.run(apply_fn=None, log_fn=None)
-    trace_plane.reset()
-    phases = _phase_stats(sim.history)
-    cov = phases.get("coverage_frac") or 0.0
-    cov_ok = abs(cov - 1.0) <= 0.05
-    ok = overhead < threshold and cov_ok
-    line = {
-        "metric": "telemetry_overhead_frac",
-        "unit": (f"(min wall enabled - disabled)/disabled over {trials}x"
-                 f"{rounds}-round interleaved CPU blocks; budget <"
-                 f" {threshold}"),
-        "value": round(overhead, 5),
-        "wall_enabled_s": round(on, 4),
-        "wall_disabled_s": round(off, 4),
-        "phase_coverage_frac": cov,
-        "telemetry": phases,
-    }
-    print(json.dumps(line), flush=True)
-    print(f"telemetry-overhead: {overhead * 100:.3f}% (budget "
-          f"{threshold * 100:.0f}%) phase_coverage={cov} "
-          f"{'OK' if ok else 'OVER BUDGET' if cov_ok else 'COVERAGE GAP'}",
-          file=sys.stderr, flush=True)
-    return 0 if ok else 1
-
-
 def cohort_sweep_bench(sizes=(10, 100, 1000, 10000), pool: int = 20000,
                        warmup_rounds: int = 2, measured_rounds: int = 3) -> int:
     """``--cohort-sweep``: CPU-only scaling sweep of the arena-backed round
@@ -1498,10 +1430,6 @@ if __name__ == "__main__":
         # host-side measurement only — never wait on (or measure) the chip
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         sys.exit(host_pack_bench())
-    if "--telemetry-overhead" in sys.argv:
-        # host-side guard only — never wait on (or measure) the chip
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        sys.exit(telemetry_overhead_bench())
     if "--cohort-sweep" in sys.argv:
         # cohort-axis scaling measurement — host + CPU backend only
         os.environ.setdefault("JAX_PLATFORMS", "cpu")

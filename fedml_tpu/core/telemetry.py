@@ -19,12 +19,19 @@ fields into a subsystem all layers report through:
 - **Collectors** — JAX compilation-event listeners (``jax.monitoring``)
   and a daemon-thread sampler for ``SysStats`` + ``device.memory_stats()``.
 
-The defining constraint is overhead (<1% of round wall-clock, guarded by
-``bench.py --telemetry-overhead``): when disabled, every accessor returns a
-shared null metric whose methods are empty, ``inject``/``extract`` are
-no-ops, and spans neither allocate ids nor record. Enabled-path costs are a
-few dict lookups and ``perf_counter`` calls per round — microseconds
-against rounds that take milliseconds to seconds.
+The defining constraint is overhead (<1% of round wall-clock): when
+disabled, every accessor returns a shared null metric whose methods are
+empty, ``inject``/``extract`` are no-ops, and spans neither allocate ids,
+record, nor annotate. Enabled-path costs are a few dict lookups and
+``perf_counter`` calls per round — microseconds against rounds that take
+milliseconds to seconds (what a span costs on the LM step is measured on the
+chip in PERF.md and bounded in ``tests/test_lm_tracing.py``).
+
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``fedml:<span name>``: under a profiler session (``MLOps.device_trace``, a
+benchmark's traced slice) the program's spans land on the profile's host
+lines, on the device trace's clock; with no session the annotation is a
+flag test.
 """
 
 from __future__ import annotations
@@ -37,7 +44,6 @@ import logging
 import os
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -390,7 +396,9 @@ ROUND_IDX_KEY = "telemetry_round_idx"
 
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    # 64 random bits as 16 hex digits; a fifth of uuid4's cost, which a
+    # span pays once or twice
+    return os.urandom(8).hex()
 
 
 def current_context() -> Optional[TraceContext]:
@@ -447,10 +455,30 @@ def extract_trace(msg) -> Optional[TraceContext]:
                         round_idx=int(rnd) if rnd is not None else None)
 
 
+PROFILE_PREFIX = "fedml:"  # a span's name on a jax profile's host lines
+
+# jax.profiler.TraceAnnotation, looked up at the first span (importing jax
+# initialises no backend); False where jax is absent
+_annotation_cls = None
+
+
+def _trace_annotation():
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # jax absent/old — telemetry must not require it
+            _annotation_cls = False
+        else:
+            _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
 class Tracer:
     """Span recorder. Finished spans land in a bounded ring (inspection /
     tests), the JSONL sink when configured, and the
-    ``fedml_span_seconds{name=...}`` histogram."""
+    ``fedml_span_seconds{name=...}`` histogram; for its life a span is also
+    a profiler annotation ``fedml:<name>``, so it shows on a device trace."""
 
     def __init__(self, registry: MetricsRegistry, buffer: int = 4096):
         self.registry = registry
@@ -473,6 +501,11 @@ class Tracer:
                        else (parent.round_idx if parent else None)),
         )
         token = _current.set(ctx)
+        annotation_cls = _trace_annotation()
+        annotation = (annotation_cls(PROFILE_PREFIX + name)
+                      if annotation_cls else None)
+        if annotation is not None:
+            annotation.__enter__()
         wall0 = time.time()
         t0 = time.perf_counter()
         status = "ok"
@@ -482,6 +515,9 @@ class Tracer:
             status = "error"
             raise
         finally:
+            duration = time.perf_counter() - t0
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             _current.reset(token)
             rec = {
                 "kind": "span",
@@ -491,7 +527,7 @@ class Tracer:
                 "parent_span_id": parent.span_id if parent else None,
                 "round_idx": ctx.round_idx,
                 "start": wall0,
-                "duration": time.perf_counter() - t0,
+                "duration": duration,
                 "status": status,
             }
             if attrs:

@@ -28,9 +28,8 @@ This module is the forensic layer on top:
   that triggered them.
 
 Everything is OFF by default: with the plane disabled every hook is a
-single attribute check, no message grows a byte (the disabled wire format
-stays byte-identical), and ``bench.py --telemetry-overhead`` holds the <1%
-budget with the plane on.
+single attribute check, and no message grows a byte (the disabled wire
+format stays byte-identical).
 """
 
 from __future__ import annotations

@@ -32,6 +32,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..core.telemetry import get_registry, get_tracer
 from ..models.transformer import TransformerLM
 from .mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ, MeshConfig, create_mesh
 from .sharding import transformer_param_specs, tree_shardings
@@ -100,35 +101,43 @@ class DistributedLMTrainer:
         mesh: Optional[Mesh] = None,
         seed: int = 0,
     ):
-        self.cfg = cfg
-        self.mesh = mesh or make_lm_mesh(cfg)
-        self.model = TransformerLM(
-            vocab_size=vocab_size, dim=dim, num_heads=num_heads,
-            num_layers=num_layers, max_len=max_len, dtype=dtype,
-            seq_axis=AXIS_SEQ if cfg.sp > 1 else None,
-            mesh=self.mesh,
-            sp_impl=cfg.sp_impl,
-            # per-block remat: O(1) layers of activations alive in bwd —
-            # strictly better than checkpointing the whole apply (which
-            # still holds every layer alive during the recompute)
-            remat=(cfg.remat_policy if cfg.remat_policy != "full" else True)
-            if cfg.use_remat else False,
-        )
-        # init on host with a tiny batch, then place with TP shardings; the
-        # init token length must divide by sp (ring attention shards T)
-        variables = self.model.init(
-            jax.random.PRNGKey(seed), jnp.zeros((1, 8 * max(1, cfg.sp)), jnp.int32)
-        )
-        self.param_specs = transformer_param_specs(variables)
-        self.param_shardings = tree_shardings(self.mesh, self.param_specs)
-        self.params = jax.device_put(variables, self.param_shardings)
-        self.opt = optax.adamw(
-            cfg.lr, weight_decay=cfg.weight_decay,
-            mu_dtype=jnp.dtype(cfg.mu_dtype) if cfg.mu_dtype else None)
-        # moments inherit the params' shardings (init maps over sharded params)
-        self.opt_state = self.opt.init(self.params)
-        self.batch_sharding = NamedSharding(self.mesh, P(AXIS_DATA, AXIS_SEQ))
-        self._train_step = self._build_train_step()
+        tracer = get_tracer()
+        with tracer.span("lm.trainer_init"):
+            self.cfg = cfg
+            self.mesh = mesh or make_lm_mesh(cfg)
+            self.model = TransformerLM(
+                vocab_size=vocab_size, dim=dim, num_heads=num_heads,
+                num_layers=num_layers, max_len=max_len, dtype=dtype,
+                seq_axis=AXIS_SEQ if cfg.sp > 1 else None,
+                mesh=self.mesh,
+                sp_impl=cfg.sp_impl,
+                # per-block remat: O(1) layers of activations alive in bwd —
+                # strictly better than checkpointing the whole apply (which
+                # still holds every layer alive during the recompute)
+                remat=(cfg.remat_policy if cfg.remat_policy != "full" else True)
+                if cfg.use_remat else False,
+            )
+            with tracer.span("lm.init_params"):
+                # init on host with a tiny batch, then place with TP
+                # shardings; the init token length must divide by sp (ring
+                # attention shards T)
+                variables = self.model.init(
+                    jax.random.PRNGKey(seed),
+                    jnp.zeros((1, 8 * max(1, cfg.sp)), jnp.int32)
+                )
+                self.param_specs = transformer_param_specs(variables)
+                self.param_shardings = tree_shardings(self.mesh, self.param_specs)
+                self.params = jax.device_put(variables, self.param_shardings)
+            with tracer.span("lm.opt_init"):
+                self.opt = optax.adamw(
+                    cfg.lr, weight_decay=cfg.weight_decay,
+                    mu_dtype=jnp.dtype(cfg.mu_dtype) if cfg.mu_dtype else None)
+                # moments inherit the params' shardings (init maps over
+                # sharded params)
+                self.opt_state = self.opt.init(self.params)
+            self.batch_sharding = NamedSharding(self.mesh, P(AXIS_DATA, AXIS_SEQ))
+            with tracer.span("lm.build_step"):
+                self._train_step = self._build_train_step()
 
     def _build_train_step(self) -> Callable:
         model = self.model
@@ -137,22 +146,27 @@ class DistributedLMTrainer:
 
         def loss_fn(params, tokens, targets):
             # block-level remat is baked into the model (cfg.use_remat)
+            # the scopes are metadata: they name the ops of the loss and of
+            # the optimizer in a device trace, and change no arithmetic
             if ce_chunk:
                 from ..ops.losses import chunked_lm_cross_entropy
 
                 hid = model.apply(params, tokens, return_hidden=True)
-                head = params["params"]["head"]["kernel"].astype(hid.dtype)
-                return chunked_lm_cross_entropy(hid, head, targets,
-                                                chunk=ce_chunk)
+                with jax.named_scope("lm.loss"):
+                    head = params["params"]["head"]["kernel"].astype(hid.dtype)
+                    return chunked_lm_cross_entropy(hid, head, targets,
+                                                    chunk=ce_chunk)
             logits = model.apply(params, tokens)
-            logz = jax.nn.log_softmax(logits.astype(jnp.float32))
-            ll = jnp.take_along_axis(logz, targets[..., None], -1)[..., 0]
-            return -ll.mean()
+            with jax.named_scope("lm.loss"):
+                logz = jax.nn.log_softmax(logits.astype(jnp.float32))
+                ll = jnp.take_along_axis(logz, targets[..., None], -1)[..., 0]
+                return -ll.mean()
 
         def train_step(params, opt_state, tokens, targets):
             loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("lm.optimizer"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         rep = NamedSharding(self.mesh, P())
@@ -164,12 +178,25 @@ class DistributedLMTrainer:
         )
 
     def step(self, tokens: np.ndarray, targets: np.ndarray) -> float:
-        tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), self.batch_sharding)
-        targets = jax.device_put(jnp.asarray(targets, jnp.int32), self.batch_sharding)
-        self.params, self.opt_state, loss = self._train_step(
-            self.params, self.opt_state, tokens, targets
-        )
-        return float(loss)
+        """One optimizer step; ends in the host's read of the loss. Its host
+        work is three spans under ``lm.step``: the uploads, the dispatch of
+        the jitted step, and the wait for the loss (a wait on the device,
+        never a busy time)."""
+        tracer = get_tracer()
+        with tracer.span("lm.step"):
+            with tracer.span("lm.input_put"):
+                tokens = jax.device_put(jnp.asarray(tokens, jnp.int32), self.batch_sharding)
+                targets = jax.device_put(jnp.asarray(targets, jnp.int32), self.batch_sharding)
+            with tracer.span("lm.dispatch"):
+                self.params, self.opt_state, loss = self._train_step(
+                    self.params, self.opt_state, tokens, targets
+                )
+            with tracer.span("lm.loss_wait"):
+                loss = float(loss)
+        registry = get_registry()
+        registry.counter("fedml_lm_steps_total").inc()
+        registry.counter("fedml_lm_tokens_total").inc(tokens.size)
+        return loss
 
     def train(self, data_iter, steps: int, log_every: int = 10, log_fn=print) -> list:
         losses = []

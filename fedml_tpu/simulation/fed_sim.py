@@ -813,8 +813,12 @@ class FedSimulator:
             else:
                 train_params = params
                 train_client_states = client_states
-            outs = _cohort_outputs(alg, train_params, cohort,
-                                   train_client_states, rng)
+            # the stages' scopes are metadata: they name the round's ops in
+            # a device trace (fed.local_update, fed.codec, fed.sanitize,
+            # fed.aggregate, fed.server_update) and change no arithmetic
+            with jax.named_scope("fed.local_update"):
+                outs = _cohort_outputs(alg, train_params, cohort,
+                                       train_client_states, rng)
             update = outs.update
             w = outs.weight.astype(jnp.float32)
             upd_sh = None
@@ -849,8 +853,9 @@ class FedSimulator:
                 # lossy wire roundtrip FIRST: the attacker corrupts what the
                 # server decodes (cross-silo decompress-then-corrupt order)
                 # and the sanitizer sees what the attacker produced
-                update, codec_res = codec_rt(
-                    update, codec_res, cids_u32, round_u32)
+                with jax.named_scope("fed.codec"):
+                    update, codec_res = codec_rt(
+                        update, codec_res, cids_u32, round_u32)
             # adversarial corruption first, sanitizer second — the defense
             # must see exactly what a byzantine client would upload
             if transform is not None:
@@ -865,18 +870,20 @@ class FedSimulator:
 
                 ra = alg.robust
                 f_byz, m_krum = ra._krum_fm(c_real + pad)
-                agg, w, quar, z, _sel = fused_sanitize_krum(
-                    update, w, z_thresh=z_thresh, n_byz=f_byz, m=m_krum,
-                    sample_weighted=ra.defense_type == "krum_fedavg",
-                    valid=valid_np, out_shardings=upd_sh)
+                with jax.named_scope("fed.sanitize"):
+                    agg, w, quar, z, _sel = fused_sanitize_krum(
+                        update, w, z_thresh=z_thresh, n_byz=f_byz, m=m_krum,
+                        sample_weighted=ra.defense_type == "krum_fedavg",
+                        valid=valid_np, out_shardings=upd_sh)
                 qz = jnp.stack([quar.astype(jnp.float32),
                                 jnp.nan_to_num(z, posinf=1e30)])
             elif detect:
                 from ..core.robust import sanitize_stacked
 
-                update, w, quar, z = sanitize_stacked(
-                    update, w, z_thresh, valid=valid_np,
-                    out_shardings=upd_sh)
+                with jax.named_scope("fed.sanitize"):
+                    update, w, quar, z = sanitize_stacked(
+                        update, w, z_thresh, valid=valid_np,
+                        out_shardings=upd_sh)
                 # one (2, C) row pair [quarantine flag, robust z] rides back
                 # with the metrics — a single extra host transfer per round
                 qz = jnp.stack([quar.astype(jnp.float32),
@@ -889,12 +896,13 @@ class FedSimulator:
                     # no layout promise — re-pin before the reduction
                     update = _pin(update, upd_sh)
                 _probe("update", update)
-                if alg.aggregate is not None:
-                    agg = alg.aggregate(update, w)
-                else:
-                    from ..core.algframe import weighted_mean
+                with jax.named_scope("fed.aggregate"):
+                    if alg.aggregate is not None:
+                        agg = alg.aggregate(update, w)
+                    else:
+                        from ..core.algframe import weighted_mean
 
-                    agg = weighted_mean(update, w)
+                        agg = weighted_mean(update, w)
             if mdl:
                 # the client-axis reduction leaves each aggregate leaf on
                 # its model layout — pin it so the optimizer apply below
@@ -902,7 +910,9 @@ class FedSimulator:
                 # comes back to the model axis here)
                 agg = _pin(agg, _infer_sh(agg, leading_cohort=False))
             _probe("agg", agg)
-            new_params, new_server_state = alg.server_update(params, agg, server_state)
+            with jax.named_scope("fed.server_update"):
+                new_params, new_server_state = alg.server_update(
+                    params, agg, server_state)
             if mdl:
                 _probe("params_out", new_params)
                 _probe("opt_state_out", new_server_state)
@@ -1688,9 +1698,14 @@ class FedSimulator:
         self._phase_acc.append(("publish", time.perf_counter() - t_pub))
 
     def _span(self, name: str, value: Optional[str] = None):
+        """A host phase of the round loop or of the prefetch thread: a span
+        of the telemetry tracer (so ``fedml:<name>`` on a device trace) and,
+        with an MLOps profiler, its started / ended events."""
+        stack = contextlib.ExitStack()
+        stack.enter_context(telemetry.get_tracer().span(name, value=value))
         if self._profiler is not None:
-            return self._profiler.span(name, event_value=value)
-        return contextlib.nullcontext()
+            stack.enter_context(self._profiler.span(name, event_value=value))
+        return stack
 
     def _paused_prefetch(self):
         """Sync point: guarantees the prefetch worker is quiescent for the
