@@ -9,7 +9,8 @@ One process, the normal entry points, no bench-only switch:
                10 a round, Dirichlet 0.5) for a few rounds with eval
 3. lm        — DistributedLMTrainer at the README's LM flagship width
                (dim 1024, 12 layers, 16 heads, vocab 32000, bf16, AdamW)
-               at T=4096, where attention dispatches to the flash kernel
+               at T=4096 (attention dispatches to the flash kernel on a
+               TPU from T=1024 up: PR 27's sweep, PERF.md section 6)
 4. kernels   — every other pallas_call against its in-repo reference:
                fused_gram (alone at a 1000-row cohort, and inside
                fused_sanitize_krum on a flagship cohort of ResNet-56
@@ -45,7 +46,8 @@ FLAGSHIP = dict(
 )
 FLAGSHIP_ROUNDS = 3           # eval runs at round 0 and at the last round
 LM_MODEL = dict(vocab_size=32000, dim=1024, num_heads=16, num_layers=12)
-LM_SEQ = 4096                 # auto_attention_impl -> "flash" from here
+LM_SEQ = 4096                 # long context; "flash" on a TPU from T=1024
+FLASH_SPLIT_SEQ = 12288       # past what one backward kernel holds of dq in VMEM
 LM_BATCH = 4                  # ~5.5 GB of the v5e's 16 GB (XLA's own estimate)
 LM_STEPS = 4
 GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
@@ -651,9 +653,9 @@ def main() -> int:
     lm = run("lm", stage_lm, LM_MODEL, LM_SEQ, LM_BATCH, LM_STEPS)
     require(lm["attention_impl"] == "flash",
             f"attention dispatched to {lm['attention_impl']} at T={LM_SEQ}")
-    # forward, dq and dk/dv kernels in every layer (one more forward each
-    # where the block is rematerialised)
-    require(lm["mosaic_calls_lowered"] >= 3 * LM_MODEL["num_layers"],
+    # the forward kernel, remat's recompute of it and the one backward kernel:
+    # the layers share one lowering of each
+    require(lm["mosaic_calls_lowered"] >= 3,
             f"LM step lowers to {lm['mosaic_calls_lowered']} Mosaic calls: "
             "the flash kernels did not engage compiled")
     if lm4 is not None:
@@ -662,7 +664,12 @@ def main() -> int:
                 f"one-chip step by {gap:.4f}")
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
-    require(fvd["mosaic_calls_lowered"] >= 3, "flash check ran interpreted")
+    require(fvd["mosaic_calls_lowered"] >= 2, "flash check ran interpreted")
+    # the dk/dv + dq pair, which no model here is long enough to take
+    split = run("flash_vs_dense_split", check_flash_vs_dense,
+                FLASH_SPLIT_SEQ, 2, 64)
+    require(split["mosaic_calls_lowered"] >= 3,
+            "the split backward did not engage compiled")
 
     run("fused_gram_refuses", check_gram_refuses, GRAM_REFUSED)
     kernels = [run("fused_gram", check_gram, GRAM_SHAPE),

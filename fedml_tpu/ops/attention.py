@@ -1,7 +1,8 @@
 """Attention ops: dense multihead attention + ring attention over a seq axis.
 
-Single-chip path is plain XLA for moderate T and the pallas flash kernel
-(ops/pallas/flash_attention.py) past the crossover. The ring path
+Single-chip path is plain XLA for short T and the pallas flash kernel
+(ops/pallas/flash_attention.py) from the measured crossover up
+(``auto_attention_impl``). The ring path
 implements blockwise ring attention (Liu et al.) with ``lax.ppermute`` over the ``seq`` mesh axis: each shard
 holds a query block, K/V blocks rotate around the ring, and softmax is
 accumulated online (running max + normalizer), so memory stays O(T/n per
@@ -17,37 +18,47 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..core.telemetry import get_registry
+
+
+# The lowest length at which the kernel beats XLA's dense attention on the
+# chip: PR 27's sweep on the v5e at (B, T, H, Dh) = (8, T, 16, 64) bf16 causal
+# (PERF.md section 6) and the LM cell at T = 1024. Nothing shorter was
+# measured, so nothing shorter is routed.
+FLASH_MIN_T_ON_TPU = 1024
+# Off the chip the kernel runs in Pallas interpret mode, where it is slower
+# than dense at any length: there it is taken only where the (T, T) logits
+# stop being an option.
+FLASH_MIN_T_INTERPRETED = 4096
+DENSE_SAVED_BYTES_MAX = 512 * 1024**2
+
 
 def auto_attention_impl(B: int, H: int, T: int, Dh: int,
                         itemsize: int = 2) -> str:
-    """Pick 'flash' vs 'dense' for (B, T, H, Dh) attention.
+    """Pick 'flash' vs 'dense' for (B, T, H, Dh) attention, from the
+    platform and the shape alone.
 
-    Speed: the T >= 4096 crossover is a constant carried over from earlier
-    rounds and is not measured on this chip; past it the K-blocked kernel
-    is in any case the only option once (T, T) logits stop fitting in HBM.
+    Speed: on a TPU backend the K-blocked kernel takes every length from
+    ``FLASH_MIN_T_ON_TPU`` up; elsewhere it is interpreted, and dense keeps
+    everything under ``FLASH_MIN_T_INTERPRETED``.
 
-    Memory: BELOW the speed crossover, dense training saves the
-    (B, H, T, T) probabilities for the backward pass PER LAYER — a
-    12-layer stack at B=16 H=16 T=2048 pins 26 GB. Prefer flash whenever
-    one layer's saved tensor crosses 512 MB (a meaningful slice of 16 GB
-    HBM once multiplied by typical depths).
+    Memory, on every platform: dense training saves the (B, H, T, T)
+    probabilities for the backward pass PER LAYER — a 12-layer stack at
+    B=16 H=16 T=2048 pins 26 GB. Prefer flash whenever one layer's saved
+    tensor crosses 512 MB (a meaningful slice of 16 GB HBM once multiplied
+    by typical depths).
 
-    A BLOCK_TABLE entry for T (ops/pallas/flash_attention.py — populated
-    only from a sweep on the chip; empty today) means flash measured
-    at-or-faster than dense at that length with the tabled blocks, so it
-    lowers the crossover for exactly that T — but
-    only at the SWEPT shape family (Dh=64 bf16): at other Dh/itemsize the
-    kernel's guards would reject the tabled blocks and run unmeasured
-    auto squares, a config the table says nothing about.
+    Either way the shape has to tile and the kernels' blocks have to fit
+    VMEM (``flash_shapes_ok``): ViT's 65 and 197 tokens, a lane-hostile Dh
+    and 25 heads of 64 (one 1600-lane tile) stay dense.
     """
     from .pallas import flash_shapes_ok
-    from .pallas.flash_attention import BLOCK_TABLE, BLOCK_TABLE_SWEPT_SHAPE
 
-    dense_saved_bytes = B * H * T * T * itemsize
-    want_flash = (T >= 4096 or dense_saved_bytes > 512 * 1024**2
-                  or (T in BLOCK_TABLE
-                      and (Dh, itemsize) == BLOCK_TABLE_SWEPT_SHAPE))
-    if want_flash and flash_shapes_ok(T, Dh, itemsize=itemsize):
+    min_t = (FLASH_MIN_T_ON_TPU if jax.default_backend() == "tpu"
+             else FLASH_MIN_T_INTERPRETED)
+    want_flash = (T >= min_t
+                  or B * H * T * T * itemsize > DENSE_SAVED_BYTES_MAX)
+    if want_flash and flash_shapes_ok(T, Dh, itemsize=itemsize, heads=H):
         return "flash"
     return "dense"
 
@@ -65,8 +76,9 @@ def multihead_attention(
     if impl is None:
         itemsize = jnp.dtype(q.dtype).itemsize
         impl = auto_attention_impl(q.shape[0], q.shape[2], T, Dh, itemsize)
-        saved_gb = q.shape[0] * q.shape[2] * T * T * itemsize / 2**30
-        if impl == "dense" and (T >= 8192 or saved_gb > 0.5):
+        saved_bytes = q.shape[0] * q.shape[2] * T * T * itemsize
+        if impl == "dense" and (T >= 8192
+                                or saved_bytes > DENSE_SAVED_BYTES_MAX):
             # loud, not silent: dense wanted flash (long T, or the
             # per-layer saved probabilities alone cross the memory
             # threshold) but flash was refused (untileable T or
@@ -80,7 +92,13 @@ def multihead_attention(
                 "and Dh in {64, k*128}; got Dh=%d) — expect ~%.1f GB of "
                 "saved probabilities PER LAYER; pad T/Dh to tileable "
                 "sizes or shard the sequence with ring/ulysses attention",
-                T, Dh, saved_gb)
+                T, Dh, saved_bytes / 2**30)
+        if isinstance(q, jax.core.Tracer):
+            # what the rule made of this call site: once per call site per
+            # trace, nothing at run time. ``seq_len`` keeps a model's few-token
+            # ``init`` trace apart from the lengths it trains at.
+            get_registry().counter("fedml_attention_dispatch_total",
+                                   impl=impl, seq_len=T).inc()
     if impl == "flash":
         from .pallas import flash_attention
 

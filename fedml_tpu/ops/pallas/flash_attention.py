@@ -1,28 +1,56 @@
 """Pallas flash attention (TPU): fused QK^T -> online softmax -> V.
 
-The hot op of the transformer stack (FedNLP/Cheetah planes). K-blocked 3-D
-grid design (round-3 rewrite): the grid is (batch*head, q-block, k-block)
-with the k dimension innermost, so Mosaic's pipeline streams (block_k, Dh)
-K/V tiles through VMEM while the online-softmax state (running max,
-normalizer, output accumulator) lives in VMEM scratch across the k steps.
-Nothing stages the full sequence: VMEM use is O(block_q * Dh + block_k * Dh)
-regardless of T — single-chip T is bounded by HBM, not the ~16 MB VMEM
-budget that capped the round-2 full-K/V kernel at T~12k. The (T, T) score
-matrix never exists in HBM — memory O(T * Dh) — and every matmul is a
-(block_q x Dh) x (Dh x block_k) MXU tile.
+The hot op of the transformer stack (FedNLP/Cheetah planes). K-blocked: the
+forward's grid is (batch, head group, q-block, k-block) with the k dimension
+innermost, so Mosaic's pipeline streams (block_k, W) K/V tiles through VMEM
+while the online-softmax state (running max, normalizer, output accumulator)
+lives in VMEM scratch across the k steps. The (T, T) score matrix never
+exists in HBM, in any of the three passes: memory is O(T * Dh).
 
-Causal masking skips fully-masked key blocks via ``pl.when`` (the grid step
-still runs but does no FLOPs and no accumulation), and the diagonal block
-applies the row>=col mask.
+Layout. q/k/v arrive (B, T, H, Dh) and are indexed as they lie, as
+(B, T, H*Dh): a grid step holds the fewest heads whose widths fill whole
+128-lane tiles (two at Dh=64, one at Dh=128), so no transpose to a
+head-major layout runs before or after the kernel and every load, store and
+accumulator is lane-dense. Heads that share a tile are kept apart by zeroing
+the other heads' lanes in one MXU operand: the contraction over the tile's W
+lanes then sees one head, at the cost of a (block, W) select where the
+scores are (block_q, block_k).
 
-Gradients: custom VJP with the same K-blocked scheme (FlashAttention-2):
-dq accumulates over k-blocks on a (bh, qi, ki) grid; dk/dv accumulate over
-q-blocks on a (bh, ki, qi) grid. The forward saves per-row logsumexp;
-probabilities are recomputed blockwise. Cost is the standard ~one extra
-forward of FLOPs.
+Precision. The MXU is fed the dtype the caller gave (bf16 operands contract
+in bf16, float32 in float32) and accumulates float32; scores, running max,
+normalizer, ``lse``, ``delta`` and every accumulator are float32; the
+probabilities and ``ds`` are cast to the operand dtype for their second
+contraction, as the dense path casts ``probs``. 1/sqrt(Dh) is folded into q
+(forward, dq) or k (dk/dv) once a block, rounded to the operand dtype: exact
+at Dh = 64, where it is 1/8.
 
-On non-TPU backends the kernels run in interpret mode so tests validate
-numerics everywhere; the compiled path engages on real TPU.
+Causal masking skips key blocks above the diagonal (``pl.when``; their
+index maps repeat the last block that is needed, so nothing is fetched for
+them), applies the row >= col mask only on blocks that straddle the
+diagonal, and runs the blocks under it unmasked.
+
+Gradients: custom VJP, probabilities recomputed blockwise from the saved
+per-row logsumexp (FlashAttention-2). Where it fits, one kernel on a
+(batch, head group, k-block, q-block) grid makes all three: dk/dv accumulate
+over the inner q blocks, dq in a float32 (T, W) scratch that stays in VMEM
+for the whole head group (five contractions a block pair). That scratch and
+the whole-sequence dq output grow with T, W and the dtype: where
+``_bwd_vmem`` puts them past Mosaic's scoped VMEM (T = 8192 fits at
+W = 128 in bf16, T = 4096 in float32 or at W = 256), dq has its own kernel
+on the forward's grid (seven contractions). That split pair is the only
+backward such lengths have. No cell takes it and nothing has timed it:
+``chip_smoke.py`` runs it against dense on the chip at T = 12288, the tests
+in interpret mode, and it was compiled ahead of time for the v5e over the
+shapes of PERF.md section 6 (PR 27).
+
+What PR 27's sweeps on the v5e found (PERF.md section 6 has the tables):
+the kernels are bound by vector loads, stores and lane shuffles, not by the
+MXU (float32 operands ran as fast as bf16 ones), so the softmax state is
+kept lane-replicated, which took a third off the forward; 1024 squares are
+the fastest forward blocks and 512 squares the fastest backward ones at
+T = 1024, 2048 and 4096; the mask on every block costs 0-3%. On non-TPU
+backends the kernels run in interpret mode so tests validate numerics
+everywhere.
 """
 
 from __future__ import annotations
@@ -34,318 +62,429 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Block sizes are constants carried over from earlier rounds; their speed is
-# not measured on this chip. What IS established on the v5e (chip_smoke.py,
-# PR 21): 1024x1024 blocks compile inside Mosaic's default scoped VMEM,
-# forward and backward, at Dh=64 and Dh=128 in bf16.
-MAX_BLOCK = 1024
 MIN_BLOCK = 128
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+# the largest square block each pass takes by default: the fastest of
+# {128, 256, 512, 1024} squares and rectangles at T = 1024, 2048, 4096 on the
+# v5e, (B, H, Dh) = (8, 16, 64) bf16 causal (PR 27's sweeps)
+FWD_BLOCK, BWD_BLOCK = 1024, 512
+# Mosaic's default scoped-VMEM limit on the v5e: what one kernel instance's
+# buffers and spilled temporaries have to fit (``_fwd_vmem``, ``_bwd_vmem``)
+_VMEM_LIMIT = 16 * 2**20
 
-# scoped-VMEM budget for one kernel instance's working set: the
-# 1536-block working set at Dh=64 bf16, a line drawn above the 1024 blocks
-# that compile on the v5e (2048 is not tried on this chip)
-_VMEM_BUDGET = (1536 + 2 * 2 * 1536) * 64 * 2 + (2 * 128 + 64) * 1536 * 4
 
-
-def auto_block(T: int) -> int | None:
-    """Largest power-of-two block in [128, 1024] dividing T (every candidate
-    is a multiple of 128, as Mosaic's lane dimension requires); at T <= 1024
-    prefer T//2 (diagonal-only work). None if no candidate divides T."""
-    if T <= MAX_BLOCK:
-        half = T // 2
-        if half >= MIN_BLOCK and half % MIN_BLOCK == 0 and T % half == 0:
-            return half
-    for b in (MAX_BLOCK, 512, 256, MIN_BLOCK):
-        if b <= T and T % b == 0:
+def auto_block(T: int, largest: int = FWD_BLOCK,
+               fits=lambda block: True) -> int | None:
+    """Largest power-of-two block in [128, ``largest``] dividing T (every
+    candidate is a multiple of 128, as Mosaic's lane dimension requires)
+    that ``fits``. None if no candidate does."""
+    for b in (1024, 512, 256, MIN_BLOCK):
+        if b <= min(largest, T) and T % b == 0 and fits(b):
             return b
     return None
 
 
+def heads_per_step(H: int, Dh: int) -> int:
+    """Heads one grid step holds: the fewest whose widths fill whole
+    128-lane tiles, else all of them (a block as wide as the array)."""
+    for hp in range(1, H):
+        if H % hp == 0 and (hp * Dh) % 128 == 0:
+            return hp
+    return H
+
+
+def _only_head(x, h: int, hp: int, Dh: int):
+    """x with the lanes of every head but h zeroed."""
+    if hp == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * Dh) & (lane < (h + 1) * Dh), x,
+                     jnp.zeros_like(x))
+
+
+def _by_head(parts, Dh: int):
+    """One tile whose lanes [h*Dh, (h+1)*Dh) are parts[h]'s."""
+    out = parts[-1]
+    if len(parts) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for h in range(len(parts) - 2, -1, -1):
+            out = jnp.where(lane < (h + 1) * Dh, parts[h], out)
+    return out
+
+
+def _lanes(x, W: int):
+    """A lane-replicated (rows, 128) statistic at a tile's width."""
+    tiles = -(-W // 128)
+    if tiles > 1:
+        x = pltpu.repeat(x, tiles, 1)
+    return x if W == tiles * 128 else x[:, :W]
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))   # a @ b.T
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # a.T @ b
+
+
+def _causal_cases(body, causal, q_start, block_q, k_start, block_k):
+    """Run ``body(masked)`` for this block pair: not at all above the
+    diagonal, masked where the pair straddles it, unmasked under it."""
+    if not causal:
+        body(False)
+        return
+    under = k_start + block_k - 1 <= q_start
+    pl.when(under)(lambda: body(False))
+    pl.when(jnp.logical_and(jnp.logical_not(under),
+                            k_start <= q_start + block_q - 1))(
+        lambda: body(True))
+
+
+def _keep(shape, q_start, k_start):
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return rows >= cols
+
+
+def _scores(a, b, keep):
+    s = _dot(a, b, _NT)
+    return s if keep is None else jnp.where(keep, s, NEG_INF)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  m_scr, l_scr, acc_scr, *, block_k: int, causal: bool,
-                  scale: float):
-    """Grid (B*H, T//block_q, T//block_k), k innermost. Refs:
-    q (1, block_q, Dh), k/v (1, block_k, Dh), o (1, block_q, Dh),
-    lse (1, 1, block_q). Scratch (f32): m/l (block_q, 128), acc
-    (block_q, Dh) — softmax state persists across the k steps; o/lse are
-    written once on the last step (their block index is k-invariant, so
-    Mosaic flushes them to HBM only when the q block advances)."""
-    block_q = q_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+                  q_scr, m_scr, l_scr, acc_scr, *, hp: int, Dh: int,
+                  block_k: int, causal: bool, scale: float):
+    """Grid (B, H//hp, T//block_q, T//block_k), k innermost. Refs:
+    q/o (1, block_q, W), k/v (1, block_k, W), lse (1, 1, hp, block_q).
+    Scratch: q_scr (hp, block_q, W) the scaled q with one head's lanes
+    each; m/l (hp, block_q, 128), every lane of a row the same value, so
+    the state's updates are whole-vreg ops with no lane broadcast, and acc
+    (block_q, W), float32 — the softmax state persists across the k steps;
+    o/lse are written once on the last step (their block index is
+    k-invariant)."""
+    block_q, W = q_ref.shape[1], q_ref.shape[2]
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
     q_start = qi * block_q
     k_start = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_scr.dtype)
+        for h in range(hp):
+            q_scr[h] = _only_head(q, h, hp, Dh)
         m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    # causal: key blocks strictly above the diagonal contribute nothing
-    run = (k_start <= q_start + block_q - 1) if causal else True
+    def body(masked: bool):
+        k_blk, v_blk = k_ref[0], v_ref[0]
+        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
+        corrs, pvs = [], []
+        for h in range(hp):
+            s = _scores(q_scr[h], k_blk, keep)           # (block_q, block_k)
+            m = m_scr[h]                                 # lane-replicated
+            new_m = jnp.maximum(m, jnp.max(s, axis=1)[:, None])
+            p = jnp.exp(s - pltpu.repeat(new_m, block_k // 128, 1))
+            corr = jnp.exp(m - new_m)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1)[:, None]
+            m_scr[h] = new_m
+            pvs.append(_dot(p.astype(v_blk.dtype), v_blk, _NN))
+            corrs.append(_lanes(corr, W))
+        acc_scr[...] = acc_scr[...] * _by_head(corrs, Dh) + _by_head(pvs, Dh)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (block_q, block_k)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m = m_scr[:, :1]
-        l = l_scr[:, :1]
-        blk_max = jnp.max(s, axis=1, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        p = jnp.exp(s - new_m)
-        corr = jnp.exp(m - new_m)
-        new_l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = jnp.broadcast_to(new_m, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(new_l, l_scr.shape)
+    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        m = m_scr[:, :1]
-        l_safe = jnp.maximum(l_scr[:, :1], 1e-30)
-        o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m + jnp.log(l_safe))[:, 0]
-
-
-def _bh_layout(t):
-    """(B, T, H, Dh) -> (B*H, T, Dh)."""
-    B, T, H, Dh = t.shape
-    return t.transpose(0, 2, 1, 3).reshape(B * H, T, Dh)
-
-
-def _flash_forward(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
-    block_q: int, block_k: int, interpret: bool,
-):
-    """q/k/v: (B, T, H, Dh) -> (out (B, T, H, Dh), lse (B*H, 1, T) f32).
-    lse carries a singleton middle dim so its blocks satisfy Mosaic's
-    last-two-dims rule (divisible by (8, 128) or equal to the array dims)."""
-    B, T, H, Dh = q.shape
-    scale = 1.0 / (Dh ** 0.5)
-    qb, kb, vb = _bh_layout(q), _bh_layout(k), _bh_layout(v)
-    grid = (B * H, T // block_q, T // block_k)
-    out, lse = pl.pallas_call(
-        functools.partial(_flash_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        out_shape=(
-            jax.ShapeDtypeStruct((B * H, T, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32),
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, Dh), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda bh, qi, ki: (bh, ki, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_q, Dh), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi)),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, Dh), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qb, kb, vb)
-    return out.reshape(B, H, T, Dh).transpose(0, 2, 1, 3), lse
+        ls = [jnp.maximum(l_scr[h], 1e-30) for h in range(hp)]
+        norm = _by_head([_lanes(l, W) for l in ls], Dh)
+        o_ref[0] = (acc_scr[...] / norm).astype(o_ref.dtype)
+        for h in range(hp):
+            lse_ref[0, 0, h] = (m_scr[h] + jnp.log(ls[h]))[:, 0]
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, block_k: int, causal: bool, scale: float):
-    """Grid (B*H, T//block_q, T//block_k), k innermost: one q block
-    accumulates dq over the streamed key blocks; p recomputed from
-    (q, k, lse)."""
+               q_scr, do_scr, dq_scr, *, hp: int, Dh: int, block_k: int,
+               causal: bool, scale: float):
+    """The forward's grid: one q block accumulates dq over the streamed key
+    blocks; p recomputed from (q, k, lse). Only where the fused backward's
+    whole-sequence dq does not fit VMEM (``_bwd_vmem``)."""
     block_q = q_ref.shape[1]
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
     q_start = qi * block_q
     k_start = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_scr.dtype)
+        for h in range(hp):
+            q_scr[h] = _only_head(q, h, hp, Dh)
+            do_scr[h] = _only_head(do_ref[0], h, hp, Dh)
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    run = (k_start <= q_start + block_q - 1) if causal else True
+    def body(masked: bool):
+        k_blk, v_blk = k_ref[0], v_ref[0]
+        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
+        dqs = []
+        for h in range(hp):
+            p = jnp.exp(_scores(q_scr[h], k_blk, keep)
+                        - lse_ref[0, 0, h][:, None])
+            dp = _dot(do_scr[h], v_blk, _NT)
+            ds = p * (dp - delta_ref[0, 0, h][:, None])
+            dqs.append(_dot(ds.astype(k_blk.dtype), k_blk, _NN))
+        dq_scr[...] = dq_scr[...] + _by_head(dqs, Dh)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]       # (block_q, 1)
-        delta = delta_ref[0, 0][:, None]   # (block_q, 1)
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[...] = dq_scr[...] + scale * jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_scr, dv_scr, *, block_q: int,
-                causal: bool, scale: float):
-    """Grid (B*H, T//block_k, T//block_q), q innermost: one key block
-    accumulates dk/dv over the streamed query blocks."""
+def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
+                hp: int, Dh: int, block_q: int, causal: bool, scale: float,
+                fused: bool):
+    """Grid (B, H//hp, T//block_k, T//block_q), q innermost: one key block
+    accumulates dk/dv over the streamed query blocks. k_scr holds the scaled
+    k with one head's lanes each, so ``ds @ k_scr[h]`` is that head's dq
+    with its scale, in its own lanes: ``fused`` adds it into a (T, W)
+    scratch that outlives the key blocks and is written out at the end."""
+    if fused:
+        dk_ref, dv_ref, dq_ref, k_scr, v_scr, dk_scr, dv_scr, dq_scr = refs
+    else:
+        dk_ref, dv_ref, k_scr, v_scr, dk_scr, dv_scr = refs
     block_k = k_ref.shape[1]
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    nk, nq = pl.num_programs(2), pl.num_programs(3)
     k_start = ki * block_k
     q_start = qi * block_q
 
     @pl.when(qi == 0)
     def _init():
+        k = (k_ref[0].astype(jnp.float32) * scale).astype(k_scr.dtype)
+        for h in range(hp):
+            k_scr[h] = _only_head(k, h, hp, Dh)
+            v_scr[h] = _only_head(v_ref[0], h, hp, Dh)
         dk_scr[...] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[...] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    # causal: q blocks entirely above this key block see none of it
-    run = (q_start + block_q - 1 >= k_start) if causal else True
+    if fused:
+        @pl.when(jnp.logical_and(ki == 0, qi == 0))
+        def _init_dq():
+            dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    @pl.when(run)
-    def _body():
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if causal:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)                       # (block_q, block_k)
-        dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk_scr[...] = dk_scr[...] + scale * jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def body(masked: bool):
+        q, do = q_ref[0], do_ref[0]
+        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
+        dks, dvs, dq = [], [], None
+        for h in range(hp):
+            p = jnp.exp(_scores(q, k_scr[h], keep)      # (block_q, block_k)
+                        - lse_ref[0, 0, h][:, None])
+            dp = _dot(do, v_scr[h], _NT)
+            ds = (p * (dp - delta_ref[0, 0, h][:, None])).astype(q.dtype)
+            dvs.append(_dot(p.astype(do.dtype), do, _TN))
+            dks.append(_dot(ds, q, _TN))
+            if fused:
+                part = _dot(ds, k_scr[h], _NN)
+                dq = part if dq is None else dq + part
+        dv_scr[...] = dv_scr[...] + _by_head(dvs, Dh)
+        dk_scr[...] = dk_scr[...] + _by_head(dks, Dh)
+        if fused:
+            rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+            dq_scr[rows, :] = dq_scr[rows, :] + dq
+
+    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(qi == nq - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
+    if fused:
+        @pl.when(jnp.logical_and(ki == nk - 1, qi == nq - 1))
+        def _finalize_dq():
+            dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
-    """Blockwise dq/dk/dv; q/k/v/out/g (B, T, H, Dh), lse (B*H, 1, T)."""
+
+def _specs(block_q: int, block_k: int, W: int, hp: int, causal: bool,
+           q_inner: bool):
+    """BlockSpecs over the (B, T, G*W) arrays and the (B, G, hp, T) rows,
+    for a grid (b, g, i, j) whose inner axis j walks key blocks, or query
+    blocks when ``q_inner``. A causal inner index repeats the nearest
+    block that is needed where the step is skipped: no fetch for it."""
+    if q_inner:
+        def qk(i, j):
+            first = (i * block_k) // block_q  # first q block at or past k's
+            return (jnp.maximum(j, first) if causal else j), i
+    else:
+        def qk(i, j):
+            last = (i * block_q + block_q - 1) // block_k
+            return i, (jnp.minimum(j, last) if causal else j)
+
+    q_tile = pl.BlockSpec(
+        (1, block_q, W), lambda b, g, i, j: (b, qk(i, j)[0], g))
+    k_tile = pl.BlockSpec(
+        (1, block_k, W), lambda b, g, i, j: (b, qk(i, j)[1], g))
+    q_rows = pl.BlockSpec(
+        (1, 1, hp, block_q), lambda b, g, i, j: (b, g, 0, qk(i, j)[0]))
+    return q_tile, k_tile, q_rows
+
+
+def _params(interpret: bool, carried_over_blocks: bool = False):
+    """The inner grid axis always carries scratch state; the fused backward's
+    dq scratch also outlives the key blocks of axis 2, which a chip with two
+    TensorCores must therefore not split between them."""
+    if interpret:
+        return {"interpret": True}
+    blocks = "arbitrary" if carried_over_blocks else "parallel"
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", blocks, "arbitrary"))}
+
+
+def _fwd_vmem(block_q: int, block_k: int, W: int, hp: int, itemsize: int) -> int:
+    """Scoped VMEM of one forward instance, in bytes: the double-buffered
+    q/o and k/v tiles and lse rows, the scratch, and what Mosaic spills (one
+    head's float32 scores; every head's p @ v and correction until
+    ``_by_head`` joins them). Fitted to ahead-of-time compiles for the v5e
+    (PERF.md section 6, PR 27): it refuses every shape Mosaic refused."""
+    piped = 2 * (2 * block_q + 2 * block_k) * W * itemsize + 2 * 8 * block_q * 4
+    scratch = hp * block_q * (W * itemsize + 2 * 128 * 4) + block_q * W * 4
+    spilled = block_q * block_k * 4 + 2 * hp * block_q * W * 4
+    return piped + scratch + spilled
+
+
+def _bwd_vmem(T: int, block_q: int, block_k: int, W: int, hp: int,
+              itemsize: int, fused: bool) -> int:
+    """Scoped VMEM of one dk/dv instance, in bytes, as ``_fwd_vmem`` counts:
+    q/dO/k/v tiles in, dk/dv tiles out, lse/delta rows, the scratch, and the
+    spilled p and dp (float32) and ds (operand dtype). ``fused`` adds dq for
+    the whole sequence: the float32 scratch and the double-buffered output.
+    The dq kernel of the split pair needs less at square blocks."""
+    piped = (2 * (2 * block_q + 2 * block_k) + 4 * block_k) * W * itemsize \
+        + 4 * 8 * block_q * 4
+    scratch = 2 * block_k * W * (hp * itemsize + 4)
+    spilled = block_q * block_k * (8 + itemsize)
+    whole_dq = T * W * (4 + 2 * itemsize) if fused else 0
+    return piped + scratch + spilled + whole_dq
+
+
+# The launchers are jitted so that a model's layers share one trace and one
+# Mosaic lowering of each kernel: lowering a pallas_call is Python-side work
+# that no compilation cache skips, and 96 of them added 21 s to every warm
+# start of the 24-layer LM step (my chip runs, PR 27: 66 s against 45 s).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+def _flash_forward(
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
+    block_q: int, block_k: int, interpret: bool,
+):
+    """q/k/v: (B, T, H, Dh) -> (out (B, T, H, Dh), lse (B, H//hp, hp, T)
+    f32: the head group's rows are a block's last two dims, equal to the
+    array's, which is Mosaic's rule for blocks under (8, 128))."""
     B, T, H, Dh = q.shape
+    hp = heads_per_step(H, Dh)
+    G, W = H // hp, hp * Dh
+    q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, False)
+    out, lse = pl.pallas_call(
+        functools.partial(_flash_kernel, hp=hp, Dh=Dh, block_k=block_k,
+                          causal=causal, scale=1.0 / (Dh ** 0.5)),
+        out_shape=(
+            jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
+            jax.ShapeDtypeStruct((B, G, hp, T), jnp.float32),
+        ),
+        grid=(B, G, T // block_q, T // block_k),
+        in_specs=[q_tile, k_tile, k_tile],
+        out_specs=(q_tile, q_rows),
+        scratch_shapes=[
+            pltpu.VMEM((hp, block_q, W), q.dtype),
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),
+            pltpu.VMEM((hp, block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, W), jnp.float32),
+        ],
+        **_params(interpret),
+    )(*(t.reshape(B, T, H * Dh) for t in (q, k, v)))
+    return out.reshape(B, T, H, Dh), lse
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
+    """Blockwise dq/dk/dv; q/k/v/out/g (B, T, H, Dh), lse (B, H//hp, hp, T)."""
+    B, T, H, Dh = q.shape
+    hp = heads_per_step(H, Dh)
+    G, W = H // hp, hp * Dh
     scale = 1.0 / (Dh ** 0.5)
-    qb, kb, vb = _bh_layout(q), _bh_layout(k), _bh_layout(v)
-    dob = _bh_layout(g)
     # delta_i = sum_d dO_id * O_id — O(T*Dh), plain XLA (fuses into one pass)
-    delta = jnp.sum(dob.astype(jnp.float32) * _bh_layout(out).astype(jnp.float32),
-                    axis=-1)[:, None, :]  # (B*H, 1, T), lse's layout
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = delta.reshape(B, T, G, hp).transpose(0, 2, 3, 1)  # lse's layout
+    flat = (B, T, H * Dh)
+    args = (*(t.reshape(flat) for t in (q, k, v, g)), lse, delta)
+    fused = _bwd_vmem(T, block_q, block_k, W, hp, q.dtype.itemsize,
+                      fused=True) <= _VMEM_LIMIT
+    like = lambda t: jax.ShapeDtypeStruct(flat, t.dtype)  # noqa: E731
 
-    def qblk(blk):
-        return pl.BlockSpec((1, blk, Dh), lambda bh, i, j: (bh, i, 0))
-
-    def jblk(blk):
-        return pl.BlockSpec((1, blk, Dh), lambda bh, i, j: (bh, j, 0))
-
-    def row_i(blk):
-        return pl.BlockSpec((1, 1, blk), lambda bh, i, j: (bh, 0, i))
-
-    def row_j(blk):
-        return pl.BlockSpec((1, 1, blk), lambda bh, i, j: (bh, 0, j))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        out_shape=jax.ShapeDtypeStruct((B * H, T, Dh), q.dtype),
-        grid=(B * H, T // block_q, T // block_k),
-        in_specs=[qblk(block_q), jblk(block_k), jblk(block_k), qblk(block_q),
-                  row_i(block_q), row_i(block_q)],
-        out_specs=qblk(block_q),
-        scratch_shapes=[pltpu.VMEM((block_q, Dh), jnp.float32)],
-        interpret=interpret,
-    )(qb, kb, vb, dob, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale),
-        out_shape=(jax.ShapeDtypeStruct((B * H, T, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((B * H, T, Dh), v.dtype)),
-        grid=(B * H, T // block_k, T // block_q),
-        in_specs=[jblk(block_q), qblk(block_k), qblk(block_k), jblk(block_q),
-                  row_j(block_q), row_j(block_q)],
-        out_specs=(qblk(block_k), qblk(block_k)),
-        scratch_shapes=[pltpu.VMEM((block_k, Dh), jnp.float32),
-                        pltpu.VMEM((block_k, Dh), jnp.float32)],
-        interpret=interpret,
-    )(qb, kb, vb, dob, lse, delta)
-
-    from_bh = lambda t: t.reshape(B, H, T, Dh).transpose(0, 2, 1, 3)  # noqa: E731
-    return from_bh(dq), from_bh(dk), from_bh(dv)
-
-
-# Measured-fastest (block_q, block_k) per sequence length, from a sweep on
-# the chip. Empty: no such sweep has run on this chip, so every shape
-# falls back to auto_block squares. Rectangular blocks (small q x large k)
-# keep the softmax state resident while streaming more K per grid step.
-BLOCK_TABLE: dict = {}
-# the shape family the sweep measures (q/k/v head dim, element bytes):
-# table entries qualify ONLY here — other Dh/itemsize would resolve to
-# unmeasured auto blocks. Dispatch (ops/attention.py) and any future
-# sweep extension read this, so the qualifying condition lives in one
-# place next to the table it scopes.
-BLOCK_TABLE_SWEPT_SHAPE = (64, 2)
+    q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, True)
+    dq_whole = pl.BlockSpec((1, T, W), lambda b, g, i, j: (b, 0, g))
+    grads = pl.pallas_call(
+        functools.partial(_dkv_kernel, hp=hp, Dh=Dh, block_q=block_q,
+                          causal=causal, scale=scale, fused=fused),
+        out_shape=(like(k), like(v)) + ((like(q),) if fused else ()),
+        grid=(B, G, T // block_k, T // block_q),
+        in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
+        out_specs=(k_tile, k_tile) + ((dq_whole,) if fused else ()),
+        scratch_shapes=[
+            pltpu.VMEM((hp, block_k, W), k.dtype),
+            pltpu.VMEM((hp, block_k, W), v.dtype),
+            pltpu.VMEM((block_k, W), jnp.float32),
+            pltpu.VMEM((block_k, W), jnp.float32),
+        ] + ([pltpu.VMEM((T, W), jnp.float32)] if fused else []),
+        **_params(interpret, carried_over_blocks=fused),
+    )(*args)
+    if fused:
+        dk, dv, dq = grads
+    else:
+        dk, dv = grads
+        q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, False)
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, hp=hp, Dh=Dh, block_k=block_k,
+                              causal=causal, scale=scale),
+            out_shape=like(q),
+            grid=(B, G, T // block_q, T // block_k),
+            in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
+            out_specs=q_tile,
+            scratch_shapes=[
+                pltpu.VMEM((hp, block_q, W), q.dtype),
+                pltpu.VMEM((hp, block_q, W), g.dtype),
+                pltpu.VMEM((block_q, W), jnp.float32),
+            ],
+            **_params(interpret),
+        )(*args)
+    return tuple(t.reshape(B, T, H, Dh) for t in (dq, dk, dv))
 
 
-def _resolve_blocks(T, block_q, block_k, Dh: int = 64, itemsize: int = 2):
-    table = BLOCK_TABLE.get(T)
-    if block_q is None and block_k is None and table is not None:
-        bq, bk = table
-        # table entries face the SAME guards the auto path does: lane
-        # alignment (Mosaic needs multiples of 128) and scoped VMEM for
-        # the larger tile — a mis-adopted (128, 2048) entry must fall
-        # back to auto squares, not blow VMEM at chip time
-        if ((Dh, itemsize) == BLOCK_TABLE_SWEPT_SHAPE
-                and T % bq == 0 and T % bk == 0
-                and bq % MIN_BLOCK == 0 and bk % MIN_BLOCK == 0
-                and flash_vmem_ok(T, Dh, itemsize, block=max(bq, bk))):
-            return bq, bk
-    auto = auto_block(T)
+def _auto_blocks(T: int, H: int, Dh: int, itemsize: int):
+    """(forward, backward) square blocks for a problem: the largest under
+    each pass's measured cap whose working set fits scoped VMEM."""
+    hp = heads_per_step(H, Dh)
+    return (
+        auto_block(T, FWD_BLOCK, lambda b: _fwd_vmem(
+            b, b, hp * Dh, hp, itemsize) <= _VMEM_LIMIT),
+        auto_block(T, BWD_BLOCK, lambda b: _bwd_vmem(
+            T, b, b, hp * Dh, hp, itemsize, fused=False) <= _VMEM_LIMIT))
+
+
+def _resolve_blocks(q, block_q, block_k, backward: bool):
+    _, T, H, Dh = q.shape
+    auto = _auto_blocks(T, H, Dh, q.dtype.itemsize)[backward]
     bq = block_q or auto
     bk = block_k or auto
     if bq is None or bk is None or T % bq or T % bk:
         raise ValueError(
-            f"flash_attention: T={T} has no block tiling (callers should "
-            "gate on flash_shapes_ok and fall back to dense)")
+            f"flash_attention: T={T} has no block tiling that fits VMEM "
+            "(callers should gate on flash_shapes_ok and fall back to dense)")
     return bq, bk
 
 
@@ -357,21 +496,16 @@ def flash_attention(
     block_k: int | None = None,
 ) -> jax.Array:
     """Flash attention with K-blocked pallas forward AND backward.
-    q/k/v (B, T, H, Dh); block sizes default to auto_block's tiling for T;
-    requires T % block == 0 (callers fall back to dense otherwise)."""
-    interpret = jax.default_backend() != "tpu"
-    block_q, block_k = _resolve_blocks(
-        q.shape[1], block_q, block_k, Dh=q.shape[-1],
-        itemsize=jnp.dtype(q.dtype).itemsize)
-    out, _ = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
-    return out
+    q/k/v (B, T, H, Dh); block sizes default to auto_block's tiling for T
+    under each pass's measured cap (FWD_BLOCK, BWD_BLOCK), and explicit ones
+    hold for both passes; requires T % block == 0 (callers fall back to
+    dense otherwise)."""
+    return _fwd(q, k, v, causal, block_q, block_k)[0]
 
 
 def _fwd(q, k, v, causal, block_q, block_k):
     interpret = jax.default_backend() != "tpu"
-    block_q, block_k = _resolve_blocks(
-        q.shape[1], block_q, block_k, Dh=q.shape[-1],
-        itemsize=jnp.dtype(q.dtype).itemsize)
+    block_q, block_k = _resolve_blocks(q, block_q, block_k, backward=False)
     out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
     return out, (q, k, v, out, lse)
 
@@ -379,9 +513,7 @@ def _fwd(q, k, v, causal, block_q, block_k):
 def _bwd(causal, block_q, block_k, res, g):
     q, k, v, out, lse = res
     interpret = jax.default_backend() != "tpu"
-    block_q, block_k = _resolve_blocks(
-        q.shape[1], block_q, block_k, Dh=q.shape[-1],
-        itemsize=jnp.dtype(q.dtype).itemsize)
+    block_q, block_k = _resolve_blocks(q, block_q, block_k, backward=True)
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret)
 
 
@@ -389,31 +521,35 @@ flash_attention.defvjp(_fwd, _bwd)
 
 
 def flash_vmem_ok(T: int, Dh: int, itemsize: int = 2,
-                  block: int | None = None) -> bool:
-    """K-blocked kernels hold only O(block * Dh) in VMEM, independent of T —
-    the round-2 full-K/V staging limit (T~12k at Dh=64 bf16) is gone.
-    Retained as a guard against configs where the block pipeline plus
-    scratch would still exceed scoped VMEM (huge Dh or oversized explicit
-    blocks)."""
-    block = block or auto_block(T) or MIN_BLOCK
-    # q + double-buffered k/v tiles in the input dtype...
-    per_block = (block + 2 * 2 * block) * Dh * itemsize
-    # ...plus the f32 m/l/acc scratch rows
-    scratch = (2 * 128 + Dh) * block * 4
-    return per_block + scratch <= _VMEM_BUDGET
+                  block: int | None = None, heads: int | None = None) -> bool:
+    """Whether the forward and the split backward fit scoped VMEM: at
+    ``block`` squares if given, else at some auto block of each pass. Their
+    working set is O(block * W), independent of T; the fused backward, which
+    is not, is taken only where it fits (``_flash_backward``). Refuses a
+    huge Dh, oversized explicit blocks, and a head count whose lane tile is
+    the whole H * Dh and wide (an odd H at Dh = 64; without ``heads`` the
+    tile is taken to be the fewest whole lane tiles)."""
+    H = heads or max(1, 128 // Dh)
+    if block is None:
+        return None not in _auto_blocks(T, H, Dh, itemsize)
+    hp = heads_per_step(H, Dh)
+    return (_fwd_vmem(block, block, hp * Dh, hp, itemsize) <= _VMEM_LIMIT
+            and _bwd_vmem(T, block, block, hp * Dh, hp, itemsize,
+                          fused=False) <= _VMEM_LIMIT)
 
 
 def flash_shapes_ok(T: int, Dh: int, block_q: int | None = None,
                     block_k: int | None = None,
-                    itemsize: int = 2) -> bool:
+                    itemsize: int = 2, heads: int | None = None) -> bool:
     """Static dispatch guard used by ops.attention.multihead_attention: the
     sequence must tile into whole blocks, Dh must fill lanes reasonably,
     and the requested (or auto) blocks must fit scoped VMEM; T itself is
     unbounded on a single chip (HBM is the ceiling)."""
     bq = block_q or auto_block(T)
     bk = block_k or auto_block(T)
+    explicit = max(bq or 0, bk or 0) if (block_q or block_k) else None
     return (bq is not None and bk is not None
             and T % bq == 0 and T % bk == 0
             and bq % MIN_BLOCK == 0 and bk % MIN_BLOCK == 0
             and (Dh % 128 == 0 or Dh == 64)
-            and flash_vmem_ok(T, Dh, itemsize, block=max(bq, bk)))
+            and flash_vmem_ok(T, Dh, itemsize, block=explicit, heads=heads))

@@ -48,7 +48,9 @@ def test_flash_gradients_long_context_T1024():
     q, k, v = _qkv(B=1, T=1024, H=1, Dh=64, seed=3)
 
     def loss_flash(q, k, v):
-        return flash_attention(q, k, v, True).sum()
+        # 512 blocks, so that a (1024, 1024) tensor could only be the scores
+        # (the forward's default block at this length is the whole of T)
+        return flash_attention(q, k, v, True, 512, 512).sum()
 
     def loss_dense(q, k, v):
         return multihead_attention(q, k, v, causal=True, impl="dense").sum()
@@ -70,16 +72,165 @@ def test_flash_gradients_long_context_T1024():
 
 
 def test_auto_impl_memory_aware():
-    """Dispatch goes flash below the T=4096 speed crossover whenever one
-    layer's saved dense probabilities would cross 512 MB (the MFU-bench
-    lesson: 12 layers x 2.15 GB of probs at B=16 H=16 T=2048 = 26 GB)."""
+    """Off the chip the kernel is interpreted, so the speed rule stays at
+    T >= 4096 there; the memory rule sends a layer whose saved dense
+    probabilities would cross 512 MB to flash on every platform (the
+    MFU-bench lesson: 12 layers x 2.15 GB of probs at B=16 H=16 T=2048 =
+    26 GB)."""
     from fedml_tpu.ops.attention import auto_attention_impl
 
+    assert jax.default_backend() == "cpu"
     assert auto_attention_impl(4, 8, 2048, 64) == "dense"    # 268 MB: speed
+    assert auto_attention_impl(8, 16, 1024, 64) == "dense"   # the LM cell
     assert auto_attention_impl(16, 16, 2048, 64) == "flash"  # 2.1 GB/layer
     assert auto_attention_impl(1, 1, 8192, 64) == "flash"    # past crossover
     # memory wants flash but shapes refuse (lane-hostile Dh) -> dense
     assert auto_attention_impl(16, 16, 2048, 48) == "dense"
+
+
+# (B, H, T, Dh): the LM cell; FedNLP's longest; ViT-B/16 at 224; a length
+# past every rule; the memory rule; a lane-hostile head
+@pytest.mark.parametrize("shape,on_tpu,off_tpu", [
+    ((8, 16, 1024, 64), "flash", "dense"),
+    ((4, 8, 2048, 64), "flash", "dense"),
+    ((8, 16, 512, 64), "dense", "dense"),
+    ((8, 12, 197, 64), "dense", "dense"),
+    ((1, 1, 8192, 64), "flash", "flash"),
+    ((16, 16, 2048, 64), "flash", "flash"),
+    ((8, 16, 1024, 48), "dense", "dense"),
+])
+def test_auto_impl_follows_platform_and_shape(shape, on_tpu, off_tpu,
+                                              monkeypatch):
+    """One rule over platform and shape: on a TPU backend the kernel takes
+    T >= 1024 (PR 27's sweep); off it the rule does not widen."""
+    from fedml_tpu.ops.attention import auto_attention_impl
+
+    assert auto_attention_impl(*shape) == off_tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert auto_attention_impl(*shape) == on_tpu
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    assert auto_attention_impl(*shape) == off_tpu
+
+
+@pytest.fixture(scope="module")
+def lm_cell_grads():
+    """The LM cell's attention (T=1024, Dh=64, bf16, causal) at a small
+    B x H, interpreted, at the blocks ``auto_block`` adopts: output and the
+    three gradients, beside dense run in float32 on the same bf16 values."""
+    rng = np.random.default_rng(5)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(1, 1024, 2, 64)),
+                              jnp.bfloat16) for _ in range(4))
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+
+    def run(attn, cast):
+        out, vjp = jax.vjp(attn, cast(q), cast(k), cast(v))
+        return (out, *vjp(cast(w)))
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, True), lambda t: t)
+    want = run(lambda q, k, v: multihead_attention(
+        q, k, v, causal=True, impl="dense"), f32)
+    return [(np.asarray(f32(g)), np.asarray(r)) for g, r in zip(got, want)]
+
+
+@pytest.mark.parametrize("which", ["out", "dq", "dk", "dv"])
+def test_flash_bf16_at_the_lm_cell_shape(lm_cell_grads, which):
+    """bf16 operands into the MXU, float32 softmax state: within bf16's
+    rounding (2**-8 relative) of dense in float32, on every tensor."""
+    got, want = lm_cell_grads[["out", "dq", "dk", "dv"].index(which)]
+    assert got.shape == want.shape == (1, 1024, 2, 64)
+    assert np.abs(got - want).max() <= 2.0 ** -6 * np.abs(want).max()
+    # and not by chance of scale: the mean error is far inside it
+    assert np.abs(got - want).mean() <= 2.0 ** -8 * np.abs(want).mean()
+
+
+@pytest.mark.parametrize("H,Dh,hp", [(16, 64, 2), (12, 64, 2), (8, 128, 1),
+                                     (1, 64, 1), (3, 64, 3), (4, 256, 1)])
+def test_heads_per_step_fills_whole_lane_tiles(H, Dh, hp):
+    from fedml_tpu.ops.pallas.flash_attention import heads_per_step
+
+    assert heads_per_step(H, Dh) == hp
+    assert H % hp == 0 and ((hp * Dh) % 128 == 0 or hp == H)
+
+
+@pytest.mark.parametrize("H,Dh", [(3, 64), (2, 128), (4, 64)])
+def test_flash_keeps_heads_apart(H, Dh):
+    """Heads that share a lane tile (and an odd count that shares one
+    block) come out as if each ran alone."""
+    q, k, v = _qkv(B=1, T=256, H=H, Dh=Dh, seed=9)
+    together = flash_attention(q, k, v, True)
+    for h in range(H):
+        alone = flash_attention(q[:, :, h:h + 1], k[:, :, h:h + 1],
+                                v[:, :, h:h + 1], True)
+        np.testing.assert_allclose(np.asarray(together[:, :, h:h + 1]),
+                                   np.asarray(alone), atol=2e-6)
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_flash_backward_fused_and_split_agree(fused, monkeypatch):
+    """Where the whole sequence's dq does not fit VMEM it has its own
+    kernel: same gradients."""
+    import sys
+
+    mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
+    if not fused:  # room for the 128 x 256 tiles, none for 512 rows of dq
+        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+            512, 128, 256, 128, 2, 4, fused=False))
+    # the jitted launcher's cache does not see the limit: trace anew, and
+    # leave no trace made under a patched limit behind
+    jax.clear_caches()
+    q, k, v = _qkv(B=1, T=512, H=2, Dh=64, seed=4)
+    w = jnp.cos(jnp.arange(q.size).reshape(q.shape) * 0.01)
+    flash = jax.grad(lambda q, k, v: (flash_attention(q, k, v, True, 128, 256)
+                                      * w).sum(), (0, 1, 2))
+    assert str(jax.make_jaxpr(flash)(q, k, v)).count("pallas_call") == (
+        2 if fused else 3)
+    gf = flash(q, k, v)
+    jax.clear_caches()
+    gd = jax.grad(lambda q, k, v: (multihead_attention(
+        q, k, v, causal=True, impl="dense") * w).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+
+
+def test_dispatch_counter_advances_once_per_traced_call_site(monkeypatch):
+    """``fedml_attention_dispatch_total{impl, seq_len}`` counts where
+    ``multihead_attention`` resolves ``impl``, whatever the rule says: at
+    trace time, so a compiled call adds nothing."""
+    from fedml_tpu.core.telemetry import get_registry
+    from fedml_tpu.ops import attention
+
+    def count(T=128):
+        return tuple(get_registry().counter(
+            "fedml_attention_dispatch_total", impl=impl, seq_len=T).value
+            for impl in ("dense", "flash"))
+
+    q = jnp.zeros((1, 128, 2, 64), jnp.float32)
+    dense0, flash0 = count()
+
+    @jax.jit
+    def three_sites(q):
+        a = multihead_attention(q, q, q, causal=True)          # auto: dense
+        with monkeypatch.context() as m:
+            m.setattr(attention, "auto_attention_impl", lambda *s: "flash")
+            a = multihead_attention(a, a, a, causal=True)      # auto: flash
+        # named, not resolved: nothing to count
+        return multihead_attention(a, a, a, causal=True, impl="flash")
+
+    three_sites(q)
+    assert count() == (dense0 + 1, flash0 + 1)
+    three_sites(q)  # compiled: nothing is traced again
+    assert count() == (dense0 + 1, flash0 + 1)
+    multihead_attention(q, q, q, causal=True)  # eager: no program, no count
+    assert count() == (dense0 + 1, flash0 + 1)
+    # a shape the kernel cannot take is a dispatch like any other, under its
+    # own length: ViT-B/16's 197 tokens, and the eight that the trainer's
+    # jitted ``model.init`` is traced over
+    for T in (197, 8):
+        before = count(T)
+        short = jnp.zeros((1, T, 2, 64), jnp.float32)
+        jax.jit(lambda x: multihead_attention(x, x, x))(short)
+        assert count(T) == (before[0] + 1, before[1])
+    assert count() == (dense0 + 1, flash0 + 1)
 
 
 def test_auto_dispatch_guard():
@@ -90,9 +241,9 @@ def test_auto_dispatch_guard():
 
 
 def test_shapes_gate_is_t_independent():
-    """The K-blocked kernel's VMEM use is O(block * Dh), so the gate no
-    longer depends on T (the round-2 full-K/V cap at T~12k is gone) —
-    only block divisibility and lane-friendly Dh matter."""
+    """The forward's and the split backward's VMEM use is O(block * W), so
+    the gate no longer depends on T (the round-2 full-K/V cap at T~12k is
+    gone) — only block divisibility and lane-friendly Dh matter."""
     from fedml_tpu.ops.pallas import flash_shapes_ok, flash_vmem_ok
 
     assert flash_shapes_ok(12288, 64, itemsize=2)
@@ -105,27 +256,80 @@ def test_shapes_gate_is_t_independent():
 
 
 def test_auto_block_is_lane_legal():
-    """Blocks must be multiples of 128 (Mosaic lane dim) that divide T."""
-    from fedml_tpu.ops.pallas.flash_attention import auto_block
+    """Blocks must be multiples of 128 (Mosaic lane dim) that divide T; each
+    pass caps them at what PR 27's sweep found fastest."""
+    from fedml_tpu.ops.pallas.flash_attention import (
+        BWD_BLOCK,
+        FWD_BLOCK,
+        auto_block,
+    )
 
     assert auto_block(8192) == 1024
-    assert auto_block(1024) == 512    # measured: T<=1024 prefers T//2
+    assert auto_block(1024) == 1024
+    assert auto_block(1024, 512) == 512
     assert auto_block(12288) == 1024
     assert auto_block(640) == 128     # 320 divides but is lane-illegal
     assert auto_block(384) == 128
     assert auto_block(100) is None
     for T in (256, 384, 640, 896, 2048, 12288):
-        b = auto_block(T)
-        assert b % 128 == 0 and T % b == 0
+        for largest in (FWD_BLOCK, BWD_BLOCK):
+            b = auto_block(T, largest)
+            assert b % 128 == 0 and T % b == 0 and b <= largest
 
 
 def test_shapes_gate_rejects_oversized_explicit_blocks():
     """flash_shapes_ok must veto block sizes the VMEM budget can't hold
-    (2048 blocks fail to compile on the v5e)."""
+    (2048 blocks fail to compile on the v5e; so do 1024 ones in float32,
+    in the split backward)."""
     from fedml_tpu.ops.pallas import flash_shapes_ok
 
     assert flash_shapes_ok(8192, 64, block_q=1024, block_k=1024)
     assert not flash_shapes_ok(8192, 64, block_q=2048, block_k=2048)
+    assert not flash_shapes_ok(8192, 64, block_q=1024, block_k=1024,
+                               itemsize=4)
+
+
+# what Mosaic did with each at its default 16 MiB, compiled ahead of time for
+# the v5e at B = 2 and two head groups (PR 27, PERF.md section 6): the fused
+# backward at 512 squares, (T, H, Dh, itemsize) -> compiled
+FUSED_COMPILED_FOR_V5E = [
+    (8192, 4, 64, 2, True), (12288, 4, 64, 2, False),
+    (4096, 4, 64, 4, True), (8192, 4, 64, 4, False),
+    (8192, 2, 128, 2, True), (16384, 2, 128, 2, False),
+    (4096, 2, 128, 4, True), (8192, 2, 128, 4, False),
+    (4096, 2, 256, 2, True), (8192, 2, 256, 2, False),
+    (2048, 2, 256, 4, False), (4096, 2, 256, 4, False)]
+
+
+@pytest.mark.parametrize("T,H,Dh,itemsize,compiled", FUSED_COMPILED_FOR_V5E)
+def test_fused_backward_is_taken_only_where_mosaic_took_it(T, H, Dh, itemsize,
+                                                           compiled):
+    """The rule is bytes, not a length: dq's whole-sequence scratch and
+    output grow with T, the tile's width and the dtype."""
+    from fedml_tpu.ops.pallas.flash_attention import (
+        _VMEM_LIMIT,
+        _bwd_vmem,
+        heads_per_step,
+    )
+
+    hp = heads_per_step(H, Dh)
+    need = _bwd_vmem(T, 512, 512, hp * Dh, hp, itemsize, fused=True)
+    assert (need <= _VMEM_LIMIT) == compiled
+
+
+@pytest.mark.parametrize("H,Dh,itemsize,blocks", [
+    (16, 64, 2, (1024, 512)), (16, 64, 4, (1024, 512)),
+    (8, 128, 4, (1024, 512)), (4, 256, 2, (1024, 512)),
+    (3, 64, 2, (512, 512)),    # one 192-lane tile: 1024 squares were refused
+    (5, 64, 4, (256, 256)),
+    (25, 64, 2, (None, None))])  # GPT-2 XL's heads: one 1600-lane tile
+def test_auto_blocks_fit_the_tile_that_the_heads_make(H, Dh, itemsize, blocks):
+    from fedml_tpu.ops.pallas import flash_shapes_ok
+    from fedml_tpu.ops.pallas.flash_attention import _auto_blocks
+
+    assert _auto_blocks(4096, H, Dh, itemsize) == blocks
+    assert flash_shapes_ok(4096, Dh, itemsize=itemsize, heads=H) == (
+        None not in blocks)
 
 
 def test_auto_dispatch_warns_on_long_dense_fallback(caplog):

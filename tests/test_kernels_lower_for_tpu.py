@@ -62,17 +62,114 @@ def test_fused_quantize_pack_lowers(bits, m):
         _sds((C,), jnp.uint32)) == 1
 
 
-@pytest.mark.parametrize("Dh", [64, 128])
-def test_flash_forward_and_backward_lower(Dh, monkeypatch):
+def _flash_loss(q, k, v):
+    return flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+
+# where the whole sequence's dq fits VMEM: forward + the one backward kernel;
+# where not: forward, dk/dv, dq. (8, 1024, 16, 64) is the LM cell's attention.
+@pytest.mark.parametrize("shape,dtype,calls", [
+    ((1, 4096, 2, 64), "bfloat16", 2), ((1, 4096, 2, 128), "bfloat16", 2),
+    ((8, 1024, 16, 64), "bfloat16", 2), ((1, 8192, 2, 64), "bfloat16", 2),
+    ((1, 8192, 2, 64), "float32", 3), ((1, 16384, 2, 64), "bfloat16", 3),
+    ((1, 16384, 1, 128), "bfloat16", 3)])
+def test_flash_forward_and_backward_lower(shape, dtype, calls, monkeypatch):
     # the kernel picks interpret mode from the backend it is traced on
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    x = _sds((1, 4096, 2, Dh), jnp.bfloat16)
+    x = _sds(shape, jnp.dtype(dtype))
+    assert _mosaic_calls(jax.grad(_flash_loss, (0, 1, 2)), x, x, x) == calls
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, through nested jaxprs (``pl.when``'s
+    branches, jitted launchers, a pallas_call's kernel)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _pallas_calls(fn, *shapes):
+    return [eqn for eqn in _eqns(jax.make_jaxpr(fn)(*shapes).jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+def test_fused_backward_keeps_its_key_blocks_on_one_core(monkeypatch):
+    """dq's scratch outlives the key blocks of grid axis 2: the fused kernel
+    declares that axis ``arbitrary``, so a chip with two TensorCores does
+    not split it; the forward and the split pair keep it ``parallel``."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def semantics(T):
+        x = _sds((1, T, 2, 64), jnp.bfloat16)
+        return [call.params["compiler_params"]["mosaic_tpu"].dimension_semantics
+                for call in _pallas_calls(jax.grad(_flash_loss, (0, 1, 2)),
+                                          x, x, x)]
+
+    free = ("parallel", "parallel", "parallel", "arbitrary")
+    assert semantics(1024) == [
+        free, ("parallel", "parallel", "arbitrary", "arbitrary")]
+    assert semantics(16384) == [free, free, free]
+
+
+def test_flash_layers_share_one_lowering_of_each_kernel(monkeypatch):
+    """A stack of equal layers, rematerialised as the LM step's are, lowers
+    each kernel once and calls it from every layer: Mosaic lowering is
+    Python-side work at every process start, cache or no cache (PR 27:
+    96 lowerings were 21 s of the LM cell's set-up)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = _sds((1, 1024, 2, 64), jnp.bfloat16)
+
+    def loss(x):
+        for _ in range(4):
+            x = jax.checkpoint(lambda t: flash_attention(t, t, t, True))(x)
+        return x.astype(jnp.float32).sum()
+
+    # forward, the recompute's forward (its outputs differ), backward
+    assert _mosaic_calls(jax.grad(loss), x) <= 3
+
+
+def test_flash_backward_splits_at_explicit_blocks_over_its_cap(monkeypatch):
+    """1024 blocks asked for by hand: the fused backward would pass scoped
+    VMEM from T = 2048 (AOT for the v5e, PR 27), so dq takes its own kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = _sds((1, 2048, 2, 64), jnp.bfloat16)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, True).astype(jnp.float32).sum()
+        return flash_attention(q, k, v, True, 1024, 1024).astype(
+            jnp.float32).sum()
 
-    # forward, dq, dk/dv
     assert _mosaic_calls(jax.grad(loss, (0, 1, 2)), x, x, x) == 3
+
+
+def _kernel_dots(fn, *shapes):
+    """(lhs dtype, rhs dtype, preferred dtype) of every dot_general inside
+    the pallas_calls of ``fn``."""
+    return [(*(str(v.aval.dtype) for v in eqn.invars),
+             str(eqn.params["preferred_element_type"]))
+            for call in _pallas_calls(fn, *shapes)
+            for eqn in _eqns(call.params["jaxpr"])
+            if eqn.primitive.name == "dot_general"]
+
+
+# contractions a head: 2 forward + 5 in the fused backward; with the
+# split pair 2 + 4 (dk/dv) + 3 (dq). Each causal case and each
+# sub-tile of a diagonal block traces its own, so these are floors.
+@pytest.mark.parametrize("T,dots_a_head", [(1024, 2 + 5), (16384, 2 + 4 + 3)])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_feeds_the_mxu_the_dtype_it_was_given(T, dots_a_head, dtype,
+                                                    monkeypatch):
+    """No float32 upcast of an MXU operand at bf16 inputs, in any of the
+    kernels: every contraction takes q/k/v/dO, p and ds in the input dtype
+    and accumulates float32."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = _sds((1, T, 2, 64), jnp.dtype(dtype))
+    found = _kernel_dots(jax.grad(_flash_loss, (0, 1, 2)), x, x, x)
+    assert len(found) >= 2 * dots_a_head
+    assert set(found) == {(dtype, dtype, "float32")}
 
 
 @pytest.mark.parametrize("hw,c", [(32, 16), (16, 32), (8, 64)])
