@@ -10,7 +10,10 @@ One process, the normal entry points, no bench-only switch:
 3. lm        — DistributedLMTrainer at the README's LM flagship width
                (dim 1024, 12 layers, 16 heads, vocab 32000, bf16, AdamW)
                at T=4096 (attention dispatches to the flash kernel on a
-               TPU from T=1024 up: PR 27's sweep, PERF.md section 6)
+               TPU from T=1024 up: PR 27's sweep, PERF.md section 6);
+               then a small layer_types decoder (short convolution, grouped
+               rotary attention, dropless routed experts on a share of the
+               experts: models/hybrid_lm.py) through the same trainer
 4. kernels   — every other pallas_call against its in-repo reference:
                fused_gram (alone at a 1000-row cohort, and inside
                fused_sanitize_krum on a flagship cohort of ResNet-56
@@ -50,6 +53,14 @@ LM_SEQ = 4096                 # long context; "flash" on a TPU from T=1024
 FLASH_SPLIT_SEQ = 12288       # past what one backward kernel holds of dq in VMEM
 LM_BATCH = 4                  # ~5.5 GB of the v5e's 16 GB (XLA's own estimate)
 LM_STEPS = 4
+# a small layer_types decoder: every operator of models/hybrid_lm.py once,
+# 8 of 16 experts held, long enough for the flash kernels on the chip
+HYBRID_LM = dict(
+    vocab_size=4096, hidden_size=512, num_dense_layers=1,
+    layer_types=("conv", "full_attention", "conv"), intermediate_size=1024,
+    moe_intermediate_size=256, num_experts=16, num_experts_per_tok=4,
+    experts_held=(4, 8), num_attention_heads=8, num_key_value_heads=2)
+HYBRID_SEQ, HYBRID_BATCH = 1024, 2
 GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
 GRAM_REFUSED = (10, 1_000_000)  # wider than full-row tiles fit in VMEM
 QUANT_SHAPE = (1000, 65536)
@@ -252,7 +263,7 @@ def stage_lm(model: dict, seq: int, batch: int, steps: int,
                           dtype=np.int32)
     x, y = tokens[:, :-1], tokens[:, 1:]
     n_mosaic = trainer._train_step.lower(
-        trainer.params, trainer.opt_state,
+        trainer.params, trainer.opt_state, trainer.constants,
         jax.ShapeDtypeStruct(x.shape, jnp.int32, sharding=trainer.batch_sharding),
         jax.ShapeDtypeStruct(y.shape, jnp.int32, sharding=trainer.batch_sharding),
     ).as_text().count("tpu_custom_call")
@@ -279,6 +290,55 @@ def stage_lm(model: dict, seq: int, batch: int, steps: int,
         "largest_param": list(biggest.shape),
         "memory": memory_stats(),
     }
+
+
+def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
+    """The layer_types decoder (short convolution, grouped rotary attention,
+    dropless routed experts on a share of the experts) through the trainer:
+    finite falling loss, no assignment dropped, and how many Mosaic calls
+    the step lowers to (flash forward and backward, the grouped products)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.core.telemetry import get_registry
+    from fedml_tpu.models.hybrid_lm import DecoderConfig
+    from fedml_tpu.ops.attention import auto_attention_impl
+    from fedml_tpu.parallel.trainer import (
+        DistributedLMTrainer,
+        DistTrainConfig,
+    )
+
+    cfg = DecoderConfig(**model)
+    heads = cfg.num_attention_heads
+    impl = auto_attention_impl(batch, heads, seq, cfg.hidden_size // heads,
+                               itemsize=2)
+    trainer = DistributedLMTrainer(DistTrainConfig(), dtype=jnp.bfloat16,
+                                   seed=0, model=cfg)
+    rng = np.random.default_rng(0)
+    tokens = rng.integers(0, cfg.vocab_size, (batch, seq + 1), dtype=np.int32)
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    spec = jax.ShapeDtypeStruct(x.shape, jnp.int32,
+                                sharding=trainer.batch_sharding)
+    n_mosaic = trainer._train_step.lower(
+        trainer.params, trainer.opt_state, trainer.constants, spec, spec,
+    ).as_text().count("tpu_custom_call")
+    registry = get_registry()
+    before = registry.counter_total("fedml_moe_assignments_total")
+    losses = [trainer.step(x, y) for _ in range(steps)]
+    require(bool(np.all(np.isfinite(losses))),
+            f"hybrid LM loss not finite: {losses}")
+    require(losses[-1] < losses[0], f"hybrid LM loss did not fall: {losses}")
+    routed = registry.counter_total("fedml_moe_assignments_total") - before
+    expert_layers = len(cfg.layer_types) - cfg.num_dense_layers
+    require(routed == steps * expert_layers * batch * seq
+            * cfg.num_experts_per_tok,
+            f"the expert layers counted {routed} assignments")
+    dropped = registry.counter_total("fedml_moe_dropped_total")
+    require(dropped == 0, f"{dropped} assignments were dropped")
+    return {"attention_impl": impl, "mosaic_calls_lowered": n_mosaic,
+            "batch": batch, "seq": seq, "loss": [round(v, 4) for v in losses],
+            "assignments": int(routed), "memory": memory_stats()}
 
 
 def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
@@ -662,6 +722,12 @@ def main() -> int:
         gap = abs(lm4["loss"][0] - lm["loss"][0])
         require(gap <= 2e-2, "dp=2 x tp=2 first-step loss differs from the "
                 f"one-chip step by {gap:.4f}")
+    hybrid = run("hybrid_lm", stage_hybrid_lm, HYBRID_LM, HYBRID_SEQ,
+                 HYBRID_BATCH, LM_STEPS)
+    # flash forward and backward, the grouped product and its transpose
+    require(hybrid["attention_impl"] == "flash"
+            and hybrid["mosaic_calls_lowered"] >= 4,
+            f"the hybrid LM's kernels did not engage compiled: {hybrid}")
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
     require(fvd["mosaic_calls_lowered"] >= 2, "flash check ran interpreted")

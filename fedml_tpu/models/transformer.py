@@ -89,37 +89,13 @@ class SelfAttention(nn.Module):
         return nn.Dense(self.dim, use_bias=False, dtype=self.dtype, name="proj")(out)
 
     def _local_attention(self, q, k, v):
-        """Attention with no sequence axis. GSPMD cannot partition a Mosaic
-        kernel (jax refuses to lower one inside a sharded jit), so when the
-        flash path engages under a data x model mesh the call is wrapped in
-        shard_map: batch and heads are independent, each device runs the
-        kernel on its own (B/dp, T, H/tp, Dh) shard. The dense path stays
-        plain XLA, which GSPMD partitions itself."""
-        from ..ops.attention import auto_attention_impl, multihead_attention
+        """Attention with no sequence axis (``ops.attention.local_attention``
+        under this module's mesh); the method is the scope the device trace
+        names the attention core by."""
+        from ..ops.attention import local_attention
 
-        B, T, H, Dh = q.shape
-        impl, mesh = self.attn_impl, self.mesh
-        if mesh is not None and mesh.size > 1:
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            from ..parallel.mesh import AXIS_DATA, AXIS_MODEL
-
-            b_ax = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
-            h_ax = AXIS_MODEL if mesh.shape.get(AXIS_MODEL, 1) > 1 else None
-            dp = mesh.shape[b_ax] if b_ax else 1
-            tp = mesh.shape[h_ax] if h_ax else 1
-            if B % dp == 0 and H % tp == 0 and (impl or auto_attention_impl(
-                    B // dp, H // tp, T, Dh,
-                    jnp.dtype(q.dtype).itemsize)) == "flash":
-                spec = P(b_ax, None, h_ax, None)
-                return shard_map(
-                    lambda q, k, v: multihead_attention(
-                        q, k, v, causal=self.causal, impl="flash"),
-                    mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                    check_vma=False,
-                )(q, k, v)
-        return multihead_attention(q, k, v, causal=self.causal, impl=impl)
+        return local_attention(q, k, v, causal=self.causal,
+                               impl=self.attn_impl, mesh=self.mesh)
 
 
 class Block(nn.Module):
@@ -164,6 +140,15 @@ class TransformerLM(nn.Module):
     # of its recompute FLOPs (the classic middle point on the
     # memory/compute curve; not measured on this chip)
     remat: Union[bool, str] = False
+
+    # what ``DistributedLMTrainer`` asks of a model: the statistics a step
+    # hands back beside the loss (none here), and the output head
+    STEP_STATS = ()
+
+    @staticmethod
+    def head_kernel(params):
+        """The output head, (D, V), from the parameter tree."""
+        return params["params"]["head"]["kernel"]
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,

@@ -67,12 +67,25 @@ def multihead_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
     impl: Optional[str] = None,
 ) -> jax.Array:
-    """Attention. q/k/v: (B, T, H, Dh) -> (B, T, H, Dh).
+    """Attention. q: (B, T, H, Dh); k/v: (B, T, Hkv, Dh) -> (B, T, H, Dh).
 
     ``impl``: 'flash' (pallas kernel, ops/pallas/flash_attention.py),
     'dense', or None = auto (flash when shapes tile into whole blocks).
+
+    Grouped KV heads (Hkv < H, Hkv | H): each KV head serves H // Hkv
+    consecutive query heads. K and V are repeated to H heads here, before
+    the dispatch rule, so the rule, its counter and both implementations
+    stay one path; the copy is (H // Hkv - 1) x the K/V bytes a call, and
+    its gradient the sum over the group.
     """
     T, Dh = q.shape[1], q.shape[-1]
+    if k.shape[2] != q.shape[2]:
+        if q.shape[2] % k.shape[2] or v.shape[2] != k.shape[2]:
+            raise ValueError(
+                f"grouped attention needs the KV heads ({k.shape[2]}, "
+                f"{v.shape[2]}) to divide the query heads ({q.shape[2]})")
+        groups = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
     if impl is None:
         itemsize = jnp.dtype(q.dtype).itemsize
         impl = auto_attention_impl(q.shape[0], q.shape[2], T, Dh, itemsize)
@@ -111,6 +124,41 @@ def multihead_attention(
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = False, impl: Optional[str] = None,
+                    mesh=None) -> jax.Array:
+    """Attention with no sequence axis, under the mesh the enclosing step is
+    partitioned over. GSPMD cannot partition a Mosaic kernel (jax refuses to
+    lower one inside a sharded jit), so when the flash path engages under a
+    data x model mesh the call is wrapped in shard_map: batch and heads are
+    independent, each device runs the kernel on its own (B/dp, T, H/tp, Dh)
+    shard (and its Hkv/tp KV heads). The dense path stays plain XLA, which
+    GSPMD partitions itself."""
+    B, T, H, Dh = q.shape
+    if mesh is not None and mesh.size > 1:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel.mesh import AXIS_DATA, AXIS_MODEL
+
+        b_ax = AXIS_DATA if mesh.shape.get(AXIS_DATA, 1) > 1 else None
+        h_ax = AXIS_MODEL if mesh.shape.get(AXIS_MODEL, 1) > 1 else None
+        dp = mesh.shape[b_ax] if b_ax else 1
+        tp = mesh.shape[h_ax] if h_ax else 1
+        if (B % dp == 0 and H % tp == 0 and k.shape[2] % tp == 0
+                and (impl or auto_attention_impl(
+                    B // dp, H // tp, T, Dh,
+                    jnp.dtype(q.dtype).itemsize)) == "flash"):
+            spec = P(b_ax, None, h_ax, None)
+            return shard_map(
+                lambda q, k, v: multihead_attention(
+                    q, k, v, causal=causal, impl="flash"),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False,
+            )(q, k, v)
+    return multihead_attention(q, k, v, causal=causal, impl=impl)
 
 
 def ulysses_attention(

@@ -1,4 +1,7 @@
-"""Mixture-of-Experts FFN with expert parallelism.
+"""Mixture-of-Experts FFNs: the capacity-factor ``MoEBlock`` and the dropless
+``RoutedExperts`` that holds a chip's share of a layer's experts.
+
+**``MoEBlock``** (the older one).
 
 Absent in the reference (like TP/SP, SURVEY.md §2.8); first-class here
 because the ``expert`` mesh axis is part of the parallelism contract. Design:
@@ -7,11 +10,29 @@ one-hot routing tensor, so the whole layer is dense linear algebra the MXU
 likes; the stacked expert weights (E, D, H) shard over ``AXIS_EXPERT`` and
 GSPMD turns the dispatch einsum into the all-to-all. Aux load-balancing loss
 follows Shazeer et al. (fraction-routed x mean-gate dot product).
+
+**``RoutedExperts``** (``dropless_moe``): top-k routing with no capacity, so
+no token is dropped at any imbalance, and static shapes under ``jit``, so one
+compiled program whatever the routing. The router (float32: sigmoid scores,
+a selection bias that takes no gradient, top-k, normalised weights) runs
+over all ``num_experts``; the layer is told which experts it holds,
+``experts_held = (offset, count)``, and computes their part of the result:
+what expert parallelism asks of it. The ``N * k`` assignments are sorted by
+expert, strangers last; the tokens' rows are gathered in that order into a
+buffer (of ``N * k`` rows where it must be: every assignment may land here;
+of twice a uniform router's mean load where that holds them); three grouped
+products over the held experts (the bundled megablox ``gmm``, whose grid
+ends with the last held row: work follows the rows held, not the buffer)
+make the SwiGLU; the rows go back by the inverse permutation and each token
+sums its k weighted rows. Gathers both ways, forward and backward (each
+permutation's transpose is the other's gather): no scatter-add runs. On
+one chip there is no exchange, and nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+import functools
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -146,3 +167,205 @@ def expert_param_shardings(mesh, params):
         return NamedSharding(mesh, which)
 
     return jax.tree_util.tree_map_with_path(spec_for, params)
+
+
+# --- dropless routed experts: a chip's share of a layer ---------------------
+
+# (held, all, largest load of a held expert, dropped) assignments of a call
+MOE_STATS = ("held", "total", "held_load_max", "dropped")
+# gmm's (rows, k, n) tile: see ``_gmm_tiling``
+GMM_TILE = (256, 2048, 768)
+# the usual row buffer, over the mean load of a uniform router
+BUFFER_OVER_MEAN = 2
+
+
+def _gmm_tiling(rows: int, k: int, n: int) -> Tuple[int, int, int]:
+    """The grouped product's tile, cut to the problem: a tile as deep as k
+    keeps an expert's weight block in VMEM while its rows stream past."""
+    tm, tk, tn = GMM_TILE
+    return min(tm, rows), min(tk, k), min(tn, n)
+
+
+@jax.custom_vjp
+def _take_rows(x: jax.Array, index: jax.Array, takers: jax.Array) -> jax.Array:
+    """``x[index]`` whose transpose is a gather too: ``takers[m]`` lists the
+    output rows that took source row ``m`` (every row the same number), so
+    the gradient of row ``m`` is the sum of theirs, and no scatter-add runs."""
+    return x[index]
+
+
+def _take_rows_fwd(x, index, takers):
+    return x[index], takers
+
+
+def _take_rows_bwd(takers, g):
+    return g[takers].sum(axis=1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def route_top_k(x, gate, bias, top_k: int):
+    """(N, D) tokens -> (chosen experts (N, k) int32, their weights (N, k)
+    float32). Float32 throughout: ``s = sigmoid(x gate)``; the choice is
+    ``top_k(s + bias)`` (the bias steers the choice only and takes no
+    gradient; zeros are no bias); the weights are ``s`` at the chosen, over
+    their sum."""
+    with jax.named_scope("moe.shuffle.route"):
+        s = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), gate.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        _, chosen = jax.lax.top_k(
+            s + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        return (chosen.astype(jnp.int32),
+                w / (w.sum(axis=-1, keepdims=True) + 1e-6))
+
+
+def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
+                 experts_held: Tuple[int, int]):
+    """The held experts' part of a routed SwiGLU layer. x: (N, D); gate: (D,
+    E) over all E experts; bias: (E,); w1, w3: (held, D, F); w2: (held, F,
+    D), the experts ``offset .. offset + held - 1``. Returns ((N, D), stats
+    (4,) int32 as MOE_STATS names them).
+
+    The row buffer has a static size, and gathers and elementwise passes
+    cost by the buffer, not by the rows in it. So there are two sizes in
+    the one program, chosen by the count of rows held: ``BUFFER_OVER_MEAN``
+    x the mean load of a uniform router where that holds them, else all
+    ``N * k`` (every assignment may land here; nothing is ever dropped:
+    ``dropped`` counts the held assignments that the size taken did not
+    give a row of their own)."""
+    N, D = x.shape
+    offset, count = experts_held
+    chosen, w = route_top_k(x, gate, bias, top_k)
+    A = N * top_k
+    tm = _gmm_tiling(A, D, D)[0]
+    # whole row tiles, and one spare: a stranger's clamped row index then
+    # reads a row that is masked, never a held one
+    buffer_of = lambda cap: (-(-cap // tm) + 1) * tm  # noqa: E731
+    usual = BUFFER_OVER_MEAN * A * count // gate.shape[1]
+    with jax.named_scope("moe.shuffle.dispatch"):
+        group_sizes = jnp.bincount(
+            chosen.reshape(A), length=gate.shape[1]).astype(jnp.int32)[
+                offset:offset + count]
+        n_held = group_sizes.sum()
+        local = chosen.reshape(A) - offset
+        local = jnp.where((local >= 0) & (local < count), local, count)
+        order = jnp.argsort(local, stable=True)   # buffer row -> assignment
+        place = jnp.argsort(order)                # assignment -> buffer row
+        order = jnp.pad(order, (0, buffer_of(A) - A))
+    held_part = functools.partial(
+        _held_rows, order=order, place=place, is_held=local < count,
+        group_sizes=group_sizes, n_held=n_held, top_k=top_k)
+    if buffer_of(usual) >= buffer_of(A):
+        out, placed = held_part(x, w, w1, w3, w2, rows=buffer_of(A))
+    else:
+        # each size saves only its inputs for the backward pass: a cond's
+        # two sides would otherwise both hand back their residuals, the
+        # side not taken as zeros of the large buffers' sizes
+        out, placed = jax.lax.cond(
+            n_held <= usual,
+            jax.checkpoint(functools.partial(held_part, rows=buffer_of(usual))),
+            jax.checkpoint(functools.partial(held_part, rows=buffer_of(A))),
+            x, w, w1, w3, w2)
+    stats = jnp.stack([n_held, jnp.int32(A), group_sizes.max(),
+                       n_held - placed])
+    return out, stats.astype(jnp.int32)
+
+
+def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
+               n_held, top_k: int, rows: int):
+    """The held experts' SwiGLU over a buffer of ``rows`` rows (at least
+    ``n_held + 1``): the tokens' rows gathered in expert order, the three
+    grouped products, the rows taken back and each token's k weighted.
+    Returns ((N, D), the count of held assignments that found, at the row
+    they read back, a computed row of their own: ``n_held`` unless the
+    buffer was too small for them)."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    N, D = x.shape
+    interpret = jax.default_backend() != "tpu"
+    valid = (jnp.arange(rows) < n_held)[:, None]
+    order = order[:rows]
+    place = jnp.minimum(place, rows - 1)  # strangers: a masked row
+    with jax.named_scope("moe.shuffle.dispatch"):
+        placed = jnp.sum(is_held & valid[place, 0]
+                         & (order[place] == jnp.arange(N * top_k)),
+                         dtype=jnp.int32)
+        xs = _take_rows(x, order // top_k, place.reshape(N, top_k))
+        xs = jnp.where(valid, xs, 0)  # and keeps gmm's unwritten rows' gradient out
+    with jax.named_scope("moe.experts"):
+        product = lambda a, b: gmm(  # noqa: E731
+            a, b, group_sizes, a.dtype,
+            _gmm_tiling(rows, b.shape[1], b.shape[2]), None, None, False,
+            interpret)
+        act = jax.nn.silu(product(xs, w1.astype(x.dtype))) * product(
+            xs, w3.astype(x.dtype))
+        ys = product(jnp.where(valid, act, 0), w2.astype(x.dtype))
+    with jax.named_scope("moe.shuffle.combine"):
+        ys = jnp.where(valid, ys, 0)  # strangers' rows add nothing
+        back = _take_rows(ys, place, order[:, None])
+        out = jnp.einsum("nkd,nk->nd", back.reshape(N, top_k, D), w,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+    return out, placed
+
+
+class RoutedExperts(nn.Module):
+    """A chip's share of a dropless top-k expert layer (``dropless_moe``).
+    (B, T, D) -> ((B, T, D), stats (4,) int32). ``num_experts`` is the
+    router's width; ``experts_held = (offset, count)`` the experts whose
+    weights live here (all of them by default). The selection bias lives in
+    the collection ``buffers``, outside the optimizer: the layer reads it
+    and never moves it (how it is balanced is the training recipe's).
+    Under a mesh with a data axis each device routes its own rows (the
+    grouped product is a Mosaic kernel GSPMD cannot partition): counts are
+    summed over the devices, the largest load is the largest on any."""
+
+    dim: int
+    width: int
+    num_experts: int
+    top_k: int
+    experts_held: Optional[Tuple[int, int]] = None
+    dtype: jnp.dtype = jnp.float32
+    mesh: Optional[object] = None
+
+    @nn.compact
+    def __call__(self, x):
+        B, T, D = x.shape
+        offset, count = self.experts_held or (0, self.num_experts)
+        if not 0 <= offset <= offset + count <= self.num_experts:
+            raise ValueError(
+                f"experts_held {(offset, count)} lies outside the router's "
+                f"{self.num_experts} experts")
+        init = nn.initializers.normal(0.02)
+        gate = self.param("gate", init, (D, self.num_experts), jnp.float32)
+        bias = self.variable("buffers", "expert_bias", jnp.zeros,
+                             (self.num_experts,), jnp.float32).value
+        w1 = self.param("w1", init, (count, D, self.width), jnp.float32)
+        w3 = self.param("w3", init, (count, D, self.width), jnp.float32)
+        w2 = self.param("w2", init, (count, self.width, D), jnp.float32)
+
+        def held_part(x, gate, bias, w1, w3, w2, data_axis=None):
+            out, stats = dropless_moe(
+                x.reshape(-1, D).astype(self.dtype), gate, bias, w1, w3, w2,
+                top_k=self.top_k, experts_held=(offset, count))
+            if data_axis:
+                sums = jax.lax.psum(stats, data_axis)
+                stats = sums.at[2].set(jax.lax.pmax(stats[2], data_axis))
+            return out.reshape(x.shape), stats
+
+        from ..parallel.mesh import AXIS_DATA
+
+        mesh = self.mesh
+        dp = mesh.shape.get(AXIS_DATA, 1) if mesh is not None else 1
+        if dp > 1 and B % dp == 0:  # (a model's one-row init stays whole)
+            from jax import shard_map
+            from jax.sharding import PartitionSpec as P
+
+            rep = P()
+            held_part = shard_map(
+                functools.partial(held_part, data_axis=AXIS_DATA), mesh=mesh,
+                in_specs=(P(AXIS_DATA), rep, rep, rep, rep, rep),
+                out_specs=(P(AXIS_DATA), rep), check_vma=False)
+        return held_part(x, gate, bias, w1, w3, w2)

@@ -165,6 +165,13 @@ def prepend_axis(spec_tree: Any, axis_name: Optional[str]) -> Any:
         is_leaf=lambda x: isinstance(x, P))
 
 
+def replicated_specs(tree: Any) -> Any:
+    """Every leaf replicated: the layout of a model that trains under data
+    parallelism alone (the ``layer_types`` decoder's tree, whose routed
+    experts and grouped KV heads have no model-axis rule yet)."""
+    return jax.tree.map(lambda _: P(), tree)
+
+
 def transformer_param_specs(params: Any) -> Any:
     """Megatron-style TP layout by parameter path.
 

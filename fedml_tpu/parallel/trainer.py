@@ -33,9 +33,10 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..core.telemetry import get_registry, get_tracer
+from ..models.hybrid_lm import DecoderConfig, HybridLM
 from ..models.transformer import TransformerLM
 from .mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ, MeshConfig, create_mesh
-from .sharding import transformer_param_specs, tree_shardings
+from .sharding import replicated_specs, transformer_param_specs, tree_shardings
 
 PyTree = Any
 
@@ -67,6 +68,9 @@ class DistTrainConfig:
     # gradients against the accumulated sum, stalling the second moment).
     # Its effect on the step is not measured on this chip.
     mu_dtype: Optional[str] = None
+    # linear learning-rate warm-up: step t (from 1) runs at lr * t /
+    # warmup_steps until it reaches lr; 0 is a constant lr
+    warmup_steps: int = 0
 
 
 def make_lm_mesh(cfg: DistTrainConfig, devices=None) -> Mesh:
@@ -87,7 +91,23 @@ __all__ = ["transformer_param_specs", "DistTrainConfig", "DistributedLMTrainer",
 
 
 class DistributedLMTrainer:
-    """Compiled distributed causal-LM trainer (the Cheetah engine)."""
+    """Compiled distributed causal-LM trainer (the Cheetah engine).
+
+    ``model`` is the model's configuration: a ``DecoderConfig`` builds the
+    ``layer_types`` decoder (models/hybrid_lm.py; dp only: its expert layer
+    and its grouped KV heads have no tp or sp layout yet). Without it the
+    five integers build the GPT-2-style ``TransformerLM``, as before.
+
+    ``params`` holds what the optimizer trains (flax's ``params``
+    collection); ``constants`` every other collection the model made, which
+    each step reads and no step moves (a router's selection bias).
+
+    What the trainer asks of a model beside ``init`` and ``apply``:
+    ``head_kernel(params)``, the (D, V) output head (for ``ce_chunk``), and
+    ``STEP_STATS``, the names of the statistics ``apply(...,
+    return_stats=True)`` gives beside its output (none: no such keyword).
+    A step hands them back in one vector with the loss, and the model's
+    ``count_step_stats(registry, stats, dp)`` files them."""
 
     def __init__(
         self,
@@ -100,79 +120,126 @@ class DistributedLMTrainer:
         dtype=jnp.bfloat16,
         mesh: Optional[Mesh] = None,
         seed: int = 0,
+        model: Optional[DecoderConfig] = None,
     ):
         tracer = get_tracer()
         with tracer.span("lm.trainer_init"):
             self.cfg = cfg
             self.mesh = mesh or make_lm_mesh(cfg)
-            self.model = TransformerLM(
-                vocab_size=vocab_size, dim=dim, num_heads=num_heads,
-                num_layers=num_layers, max_len=max_len, dtype=dtype,
-                seq_axis=AXIS_SEQ if cfg.sp > 1 else None,
-                mesh=self.mesh,
-                sp_impl=cfg.sp_impl,
-                # per-block remat: O(1) layers of activations alive in bwd —
-                # strictly better than checkpointing the whole apply (which
-                # still holds every layer alive during the recompute)
-                remat=(cfg.remat_policy if cfg.remat_policy != "full" else True)
-                if cfg.use_remat else False,
-            )
+            # per-block remat: O(1) layers of activations alive in bwd —
+            # strictly better than checkpointing the whole apply (which
+            # still holds every layer alive during the recompute)
+            remat = ((cfg.remat_policy if cfg.remat_policy != "full" else True)
+                     if cfg.use_remat else False)
+            if model is not None:
+                if cfg.tp > 1 or cfg.sp > 1:
+                    raise NotImplementedError(
+                        "the layer_types decoder trains under dp only: its "
+                        "routed experts and grouped KV heads have no tensor- "
+                        f"or sequence-parallel layout (tp={cfg.tp}, sp={cfg.sp})")
+                self.model = HybridLM(
+                    model, dtype=dtype, mesh=self.mesh, remat=remat)
+            else:
+                self.model = TransformerLM(
+                    vocab_size=vocab_size, dim=dim, num_heads=num_heads,
+                    num_layers=num_layers, max_len=max_len, dtype=dtype,
+                    seq_axis=AXIS_SEQ if cfg.sp > 1 else None,
+                    mesh=self.mesh,
+                    sp_impl=cfg.sp_impl,
+                    remat=remat,
+                )
             with tracer.span("lm.init_params"):
                 # init on host with a tiny batch, then place with TP
                 # shardings; the init token length must divide by sp (ring
                 # attention shards T)
-                variables = self.model.init(
+                # (the decoder's init as one program: op by op, its kernels
+                # and sorts each compile alone)
+                init = self.model.init if model is None else jax.jit(
+                    self.model.init)
+                variables = init(
                     jax.random.PRNGKey(seed),
                     jnp.zeros((1, 8 * max(1, cfg.sp)), jnp.int32)
                 )
-                self.param_specs = transformer_param_specs(variables)
+                specs = (transformer_param_specs if model is None
+                         else replicated_specs)(variables)
+                constants = {k: v for k, v in variables.items() if k != "params"}
+                self.param_specs = {"params": specs["params"]}
                 self.param_shardings = tree_shardings(self.mesh, self.param_specs)
-                self.params = jax.device_put(variables, self.param_shardings)
+                self.params = jax.device_put(
+                    {"params": variables["params"]}, self.param_shardings)
+                self.constants = jax.device_put(constants, tree_shardings(
+                    self.mesh, {k: specs[k] for k in constants}))
             with tracer.span("lm.opt_init"):
+                lr = cfg.lr
+                if cfg.warmup_steps:
+                    lr = lambda count: cfg.lr * jnp.minimum(  # noqa: E731
+                        1.0, (count + 1) / cfg.warmup_steps)
                 self.opt = optax.adamw(
-                    cfg.lr, weight_decay=cfg.weight_decay,
+                    lr, weight_decay=cfg.weight_decay,
                     mu_dtype=jnp.dtype(cfg.mu_dtype) if cfg.mu_dtype else None)
-                # moments inherit the params' shardings (init maps over
-                # sharded params)
-                self.opt_state = self.opt.init(self.params)
+                self.opt_state = self.init_opt_state()
             self.batch_sharding = NamedSharding(self.mesh, P(AXIS_DATA, AXIS_SEQ))
+            self.step_stats = self.model.STEP_STATS
             with tracer.span("lm.build_step"):
                 self._train_step = self._build_train_step()
+
+    def init_opt_state(self):
+        """Fresh optimizer state for ``params``, placed as a step hands it
+        back: the moments inherit the params' shardings (init maps over
+        sharded params), the step count is committed to the mesh, so the
+        first step's program is the second's too."""
+        rep = NamedSharding(self.mesh, P())
+        return jax.tree.map(
+            lambda a: a if isinstance(a.sharding, NamedSharding)
+            else jax.device_put(a, rep), self.opt.init(self.params))
 
     def _build_train_step(self) -> Callable:
         model = self.model
         opt = self.opt
         ce_chunk = self.cfg.ce_chunk
+        # a model that routes hands back, in one vector with the loss, the
+        # statistics its STEP_STATS names
+        stats_kw = {"return_stats": True} if self.step_stats else {}
 
-        def loss_fn(params, tokens, targets):
+        def loss_fn(params, constants, tokens, targets):
             # block-level remat is baked into the model (cfg.use_remat)
             # the scopes are metadata: they name the ops of the loss and of
             # the optimizer in a device trace, and change no arithmetic
+            def apply(**kw):
+                out = model.apply({**params, **constants}, tokens, **kw,
+                                  **stats_kw)
+                return out if stats_kw else (out, None)
+
             if ce_chunk:
                 from ..ops.losses import chunked_lm_cross_entropy
 
-                hid = model.apply(params, tokens, return_hidden=True)
+                hid, stats = apply(return_hidden=True)
                 with jax.named_scope("lm.loss"):
-                    head = params["params"]["head"]["kernel"].astype(hid.dtype)
+                    head = model.head_kernel(params).astype(hid.dtype)
                     return chunked_lm_cross_entropy(hid, head, targets,
-                                                    chunk=ce_chunk)
-            logits = model.apply(params, tokens)
+                                                    chunk=ce_chunk), stats
+            logits, stats = apply()
             with jax.named_scope("lm.loss"):
                 logz = jax.nn.log_softmax(logits.astype(jnp.float32))
                 ll = jnp.take_along_axis(logz, targets[..., None], -1)[..., 0]
-                return -ll.mean()
+                return -ll.mean(), stats
 
-        def train_step(params, opt_state, tokens, targets):
-            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets)
+        def train_step(params, opt_state, constants, tokens, targets):
+            (loss, stats), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, constants, tokens, targets)
             with jax.named_scope("lm.optimizer"):
                 updates, opt_state = opt.update(grads, opt_state, params)
                 params = optax.apply_updates(params, updates)
+            if stats_kw:  # counts far under 2**24: exact in float32
+                loss = jnp.concatenate([loss[None], stats.astype(jnp.float32)])
             return params, opt_state, loss
 
         rep = NamedSharding(self.mesh, P())
         return jax.jit(
             train_step,
-            in_shardings=(self.param_shardings, None, self.batch_sharding, self.batch_sharding),
+            in_shardings=(self.param_shardings, None,
+                          jax.tree.map(lambda a: a.sharding, self.constants),
+                          self.batch_sharding, self.batch_sharding),
             out_shardings=(self.param_shardings, None, rep),
             donate_argnums=(0, 1),
         )
@@ -189,13 +256,19 @@ class DistributedLMTrainer:
                 targets = jax.device_put(jnp.asarray(targets, jnp.int32), self.batch_sharding)
             with tracer.span("lm.dispatch"):
                 self.params, self.opt_state, loss = self._train_step(
-                    self.params, self.opt_state, tokens, targets
-                )
+                    self.params, self.opt_state, self.constants, tokens,
+                    targets)
             with tracer.span("lm.loss_wait"):
-                loss = float(loss)
+                if self.step_stats:
+                    loss, *stats = np.asarray(loss).tolist()
+                else:
+                    loss = float(loss)
         registry = get_registry()
         registry.counter("fedml_lm_steps_total").inc()
         registry.counter("fedml_lm_tokens_total").inc(tokens.size)
+        if self.step_stats:
+            self.model.count_step_stats(
+                registry, dict(zip(self.step_stats, stats)), dp=self.cfg.dp)
         return loss
 
     def train(self, data_iter, steps: int, log_every: int = 10, log_fn=print) -> list:

@@ -54,6 +54,15 @@ def test_stage_lm_and_flash_check_tiny():
     assert fvd["mosaic_calls_lowered"] == 0
 
 
+def test_stage_hybrid_lm_tiny():
+    tiny = dict(chip_smoke.HYBRID_LM, vocab_size=64, hidden_size=32,
+                intermediate_size=64, moe_intermediate_size=16,
+                num_attention_heads=4)
+    out = chip_smoke.stage_hybrid_lm(tiny, seq=16, batch=2, steps=3)
+    assert out["attention_impl"] == "dense" and out["mosaic_calls_lowered"] == 0
+    assert out["assignments"] == 3 * 2 * 2 * 16 * 4
+
+
 def test_kernel_checks_tiny_interpret(monkeypatch):
     from jax.experimental import pallas as pl
 

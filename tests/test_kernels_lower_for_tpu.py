@@ -184,3 +184,36 @@ def test_conv2d_pallas_lowers_at_resnet56_stages(hw, c):
     lanes = lambda s: _sds((2,) + s.shape, s.dtype)  # noqa: E731
     assert _mosaic_calls(jax.vmap(jax.grad(loss, (0, 1))),
                          lanes(x), lanes(w)) == 3
+
+
+def test_dropless_experts_and_grouped_flash_lower(monkeypatch):
+    """The routed-expert layer at LFM2-24B-A2B's widths (8 of 64 experts
+    held, top-4): the grouped products forward and their transposes
+    backward; the flash kernels behind 32 query heads on 8 KV heads."""
+    from fedml_tpu.ops.attention import multihead_attention
+    from fedml_tpu.ops.moe import dropless_moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    D, F, held = 2048, 1536, 8
+
+    def loss(x, gate, bias, w1, w3, w2):
+        out, *_ = dropless_moe(x, gate, bias, w1, w3, w2, top_k=4,
+                               experts_held=(0, held))
+        return out.astype(jnp.float32).sum()
+
+    shapes = (_sds((1024, D), jnp.bfloat16), _sds((D, 64), jnp.float32),
+              _sds((64,), jnp.float32), _sds((held, D, F), jnp.float32),
+              _sds((held, D, F), jnp.float32), _sds((held, F, D), jnp.float32))
+    # the jitted launcher is lowered once a shape: the two products into
+    # the hidden width share one kernel, and each of the two row buffers
+    # has its own; backward adds each one's transpose by rows (gmm) and by
+    # weights (tgmm)
+    assert _mosaic_calls(loss, *shapes) == 2 * 2
+    assert _mosaic_calls(jax.grad(loss, (0, 1, 3, 4, 5)), *shapes) == 2 * 6
+
+    def grouped(q, k, v):
+        return multihead_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    q, kv = _sds((2, 8192, 32, 64), jnp.bfloat16), _sds((2, 8192, 8, 64), jnp.bfloat16)
+    assert _mosaic_calls(jax.grad(grouped, (0, 1, 2)), q, kv, kv) == 2

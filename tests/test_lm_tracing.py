@@ -161,7 +161,7 @@ def test_every_op_of_the_compiled_step_falls_in_one_class(
     t = built[0] if ce_chunk == 0 else _trainer(ce_chunk=ce_chunk)
     tokens = jnp.zeros((B, T), jnp.int32)
     text = t._train_step.lower(
-        t.params, t.opt_state, tokens, tokens).compile().as_text()
+        t.params, t.opt_state, t.constants, tokens, tokens).compile().as_text()
     by_class = {c: set() for c in scope_reduce.CLASSES}
     for line in text.splitlines():
         named = re.search(r'op_name="([^"]*)"', line)
