@@ -47,6 +47,60 @@ def masked_accuracy(logits: jax.Array, labels: jax.Array, mask: jax.Array):
     return correct, m.sum()
 
 
+def lm_token_nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Per-token negative log-likelihood, float32 (...): logsumexp - picked
+    logit. logits (..., V) in the model's dtype, targets (...) int with
+    0 <= target < V: a target that names no column (an ignore id, a negative
+    one, an id of another chip's share of the vocabulary) picks the row's
+    max and gets a plain softmax back, silently, where ``take_along_axis``
+    wrapped or gave NaN.
+
+    The backward hands ``(softmax - onehot) * g`` straight back in the
+    logits' dtype, from the logits as they came and the (...) logsumexp: no
+    (..., V) float32 array is kept from the forward. Written this way, and
+    not as ``log_softmax`` + ``take_along_axis``, because XLA:TPU turns
+    ``x - max(x)`` on 3-D logits with V <= 8192 into a full-width
+    ``reduce-window`` (PERF.md section 6, PR 30)."""
+    return _token_nll(logits, targets, jnp.arange(logits.shape[-1]))
+
+
+@jax.custom_vjp
+def _token_nll(logits, targets, cols):
+    # cols = arange(V), made by the caller: an array made from no input
+    # inside chunked_lm_cross_entropy's loop is moved out of it by scan's
+    # partial evaluation, and out of the caller's named scope with it
+    return _token_nll_fwd(logits, targets, cols)[0]
+
+
+def _token_nll_fwd(logits, targets, cols):
+    x = logits.astype(jnp.float32)
+    m = x.max(-1, keepdims=True)
+    lse = m[..., 0] + jnp.log(jnp.exp(x - m).sum(-1))
+    # the picked logit by a one-hot select in the same pass as the sum (no
+    # gather over a float32 copy of the logits). Off the target the row's
+    # max, so the least is the picked logit: a constant there (0 under a
+    # sum) is moved out of the chunked loop too, and kept whole at
+    # (B, chunk, V)
+    picked = jnp.where(targets[..., None] == cols, x, m).min(-1)
+    return lse - picked, (logits, targets, cols, lse)
+
+
+def _token_nll_bwd(res, g):
+    logits, targets, cols, lse = res
+    p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    d = (p - (targets[..., None] == cols)) * g[..., None]
+    return d.astype(logits.dtype), None, None
+
+
+_token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
+
+
+def lm_cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """Mean next-token CE over every position, float32 statistics whatever
+    the logits' dtype. logits (..., V), targets (...) int."""
+    return lm_token_nll(logits, targets).mean()
+
+
 def chunked_lm_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
                              targets: jax.Array,
                              chunk: int = 256) -> jax.Array:
@@ -54,8 +108,8 @@ def chunked_lm_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
     logits tensor — the HBM hog of large-vocab LM training (V=32k at
     T=8k/B=4 is 4 GB in f32, times the bwd copies).
 
-    Computes ``hidden @ head_kernel`` and the log-softmax one sequence
-    chunk at a time under ``lax.map``; peak extra memory is
+    Computes ``hidden @ head_kernel`` and ``lm_token_nll``'s arithmetic one
+    sequence chunk at a time under ``lax.map``; peak extra memory is
     O(B * chunk * V) and the bwd re-derives each chunk's logits from the
     (tiny) saved hidden chunk. hidden (B, T, D), head_kernel (D, V),
     targets (B, T) int. T must be divisible by ``chunk`` (pad upstream)."""
@@ -64,20 +118,18 @@ def chunked_lm_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
         raise ValueError(f"T={T} not divisible by chunk={chunk}")
     hc = hidden.reshape(B, T // chunk, chunk, D).transpose(1, 0, 2, 3)
     tc = targets.reshape(B, T // chunk, chunk).transpose(1, 0, 2)
+    cols = jnp.arange(head_kernel.shape[-1])
 
     @jax.checkpoint
     def one(args):
         # checkpointed: without it lax.map's backward saves each chunk's
-        # softmax intermediates — the full (B, T, V) f32 tensor in
-        # disguise. Recomputing the chunk logits from the (tiny) saved
-        # hidden chunk is the whole point of this op.
+        # logits — the full (B, T, V) tensor in disguise. Recomputing the
+        # chunk logits from the (tiny) saved hidden chunk is the whole
+        # point of this op.
         h, t = args
-        logits = (h @ head_kernel).astype(jnp.float32)
-        logz = jax.nn.log_softmax(logits, axis=-1)
-        return jnp.take_along_axis(logz, t[..., None], axis=-1)[..., 0]
+        return _token_nll(h @ head_kernel, t, cols)
 
-    ll = jax.lax.map(one, (hc, tc))
-    return -jnp.mean(ll)
+    return jnp.mean(jax.lax.map(one, (hc, tc)))
 
 
 def _bce_with_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:

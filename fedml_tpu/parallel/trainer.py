@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.telemetry import get_registry, get_tracer
 from ..models.hybrid_lm import DecoderConfig, HybridLM
 from ..models.transformer import TransformerLM
+from ..ops.losses import chunked_lm_cross_entropy, lm_cross_entropy
 from .mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ, MeshConfig, create_mesh
 from .sharding import replicated_specs, transformer_param_specs, tree_shardings
 
@@ -211,8 +212,6 @@ class DistributedLMTrainer:
                 return out if stats_kw else (out, None)
 
             if ce_chunk:
-                from ..ops.losses import chunked_lm_cross_entropy
-
                 hid, stats = apply(return_hidden=True)
                 with jax.named_scope("lm.loss"):
                     head = model.head_kernel(params).astype(hid.dtype)
@@ -220,9 +219,7 @@ class DistributedLMTrainer:
                                                     chunk=ce_chunk), stats
             logits, stats = apply()
             with jax.named_scope("lm.loss"):
-                logz = jax.nn.log_softmax(logits.astype(jnp.float32))
-                ll = jnp.take_along_axis(logz, targets[..., None], -1)[..., 0]
-                return -ll.mean(), stats
+                return lm_cross_entropy(logits, targets), stats
 
         def train_step(params, opt_state, constants, tokens, targets):
             (loss, stats), grads = jax.value_and_grad(

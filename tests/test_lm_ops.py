@@ -32,6 +32,19 @@ def test_chunked_ce_matches_plain():
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
+def test_chunked_ce_keeps_no_chunk_of_logits_outside_its_loop():
+    """An array the chunk body makes from no input (a zero to select
+    against) is moved out of the differentiated loop by scan's partial
+    evaluation and kept whole, at (B, chunk, V): the loss's one-hot pick
+    selects against the row's max for that reason."""
+    B, T, D, V, chunk = 2, 12, 16, 50, 4
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda h, w, t: chunked_lm_cross_entropy(h, w, t, chunk=chunk), (0, 1)))(
+        jnp.zeros((B, T, D)), jnp.zeros((D, V)), jnp.zeros((B, T), jnp.int32))
+    outside = [v.aval.shape for eqn in jaxpr.eqns for v in eqn.outvars]
+    assert not [s for s in outside if s[-2:] == (chunk, V)]
+
+
 def test_chunked_ce_rejects_indivisible_t():
     h = jnp.zeros((1, 10, 4))
     w = jnp.zeros((4, 7))
