@@ -224,6 +224,12 @@ def stage_flagship(config: dict, rounds: int, backend: str = "sp") -> dict:
     require(losses[-1] < losses[0], f"train_loss did not fall: {losses}")
     require(accs and bool(np.all(np.isfinite(accs))),
             f"no finite test_acc in {rounds} rounds: {accs}")
+    # the simulator's one phase clock: named phases + host_other partition
+    # each round's wall (float addition only: a few ulps of a round)
+    off = [r["round"] for r in history
+           if abs(sum(r["phases"].values()) - r["round_time"])
+           > 1e-9 + 1e-6 * r["round_time"]]
+    require(not off, f"phases do not sum to round_time in rounds {off}")
     return {
         "engaged": tap.lines, "train_loss": losses, "test_acc": accs,
         "run_s": round(wall, 2),

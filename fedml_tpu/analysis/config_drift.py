@@ -17,10 +17,11 @@ generates ``docs/config_reference.md``):
 - **read-but-undocumented** — a key the code reads that the committed doc
   doesn't list (same staleness, from the other side; both disappear when
   ``scripts/gen_config_reference.py`` is re-run).
-- **phase-name drift** — every phase string the simulator accumulates via
-  ``_phase_acc.append(("<name>", dt))`` must appear in
-  ``docs/observability.md``; dashboards and the anomaly detector key on
-  these names, so an undocumented phase is an invisible one.
+- **phase-name drift** — every phase the simulator times, ``with
+  self._phase("<name>") as ph:`` and the ``ph.name = "<name>"`` its body
+  may set, must appear in ``docs/observability.md``; dashboards and the
+  anomaly detector key on these names, so an undocumented phase is an
+  invisible one.
 """
 
 from __future__ import annotations
@@ -36,21 +37,36 @@ from .core import Checker, Finding, Module
 _DOC_KEY_RE = re.compile(r"^\|\s*`([a-z_][a-z0-9_]*)`\s*\|")
 
 
-def _phase_appends(tree: ast.AST) -> Iterable[Tuple[str, int]]:
-    """Yield ``(phase_name, lineno)`` for ``*._phase_acc.append(("x", dt))``."""
-    for node in ast.walk(tree):
-        if not (isinstance(node, ast.Call)
+def _phase_names(tree: ast.AST) -> Iterable[Tuple[str, int]]:
+    """Yield ``(phase_name, lineno)`` for every ``*._phase("x", ...)`` call
+    and, where a ``with`` binds that call's handle to a name, for every
+    ``<handle>.name = "y"`` in its body. A name that is not a string
+    constant is not seen."""
+    def is_str(node) -> bool:
+        return isinstance(node, ast.Constant) and isinstance(node.value, str)
+
+    def is_phase_call(node) -> bool:
+        return (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
-                and node.func.attr == "append"
-                and isinstance(node.func.value, ast.Attribute)
-                and node.func.value.attr == "_phase_acc"
-                and node.args):
+                and node.func.attr == "_phase")
+
+    for node in ast.walk(tree):
+        if is_phase_call(node) and node.args and is_str(node.args[0]):
+            yield node.args[0].value, node.lineno
+        if not isinstance(node, ast.With):
             continue
-        arg = node.args[0]
-        if (isinstance(arg, ast.Tuple) and arg.elts
-                and isinstance(arg.elts[0], ast.Constant)
-                and isinstance(arg.elts[0].value, str)):
-            yield arg.elts[0].value, node.lineno
+        handles = {item.optional_vars.id for item in node.items
+                   if is_phase_call(item.context_expr)
+                   and isinstance(item.optional_vars, ast.Name)}
+        if not handles:
+            continue
+        for inner in ast.walk(node):
+            if isinstance(inner, ast.Assign) and is_str(inner.value):
+                for tgt in inner.targets:
+                    if (isinstance(tgt, ast.Attribute) and tgt.attr == "name"
+                            and isinstance(tgt.value, ast.Name)
+                            and tgt.value.id in handles):
+                        yield inner.value.value, inner.lineno
 
 
 def _literal(text: str):
@@ -91,7 +107,7 @@ class ConfigDriftChecker(Checker):
             if "*" in ids or self.id in ids:
                 continue
             merge_read(self._records, read)
-        for name, lineno in _phase_appends(module.tree):
+        for name, lineno in _phase_names(module.tree):
             ids = module.suppressions.get(lineno, ())
             if "*" in ids or self.id in ids:
                 continue

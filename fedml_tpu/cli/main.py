@@ -296,7 +296,7 @@ def run(config_file, backend, flight_record):
               help="Directory flight bundles land in (with --flight-record).")
 @click.option("--json", "as_json", is_flag=True,
               help="Emit the drill outcome as one JSON line (the same "
-                   "reporter bench.py --chaos uses) instead of the summary.")
+                   "reporter every drill shares) instead of the summary.")
 @click.option("--straggler", is_flag=True,
               help="Run the straggler drill instead: sync vs buffered-async "
                    "engines under one seeded heavy-tail delay plan; gates "

@@ -220,8 +220,7 @@ def _lane_schedule_cached(
     # same way with pow2 slot counts)
     candidates = []
     if force_lanes is not None:
-        # caller pins G (bench-swept: per-step cost is superlinear in lane
-        # count because per-lane weights lower to grouped convs); still a
+        # caller pins G (SimConfig.packed_lanes says why one would); still a
         # multiple of the mesh axis — both the round-up and the cohort
         # clamp floor to axis multiples so mesh shards stay even
         g = max(axis, -(-int(force_lanes) // axis) * axis)

@@ -31,8 +31,8 @@ cuts one device class off behind a :class:`NetworkPartition` window, and
 asserts the day degrades instead of breaking: accuracy within tolerance of
 the churn-free reference, sheds and drops fully accounted, no hangs.
 
-Front doors: ``fedml-tpu chaos-drill --device-churn``, ``bench.py
---device-day``, ``scripts/device_day_smoke.py``, ``tests/test_device_day.py``.
+Front doors: ``fedml-tpu chaos-drill --device-churn``,
+``scripts/device_day_smoke.py``, ``tests/test_device_day.py``.
 """
 
 from __future__ import annotations

@@ -6,10 +6,10 @@ switches on the requested ``fault_*`` plan, runs it to completion, and
 reports whether every round closed plus what the resilience plane did along
 the way (faults injected, sends retried, sends declared dead).
 
-Shared by the ``fedml-tpu chaos-drill`` CLI command, ``bench.py --chaos``,
-and the ``tests/test_chaos.py`` suite — one implementation, three front
-doors, so the drill the CI gate runs is exactly the drill an operator can
-run by hand against a proposed config change.
+Shared by the ``fedml-tpu chaos-drill`` CLI command and the
+``tests/test_chaos.py`` suite — one implementation, two front doors, so the
+drill the CI gate runs is exactly the drill an operator can run by hand
+against a proposed config change.
 """
 
 from __future__ import annotations
@@ -95,8 +95,8 @@ class ChaosDrillResult:
 
     def json_record(self) -> dict:
         """The drill outcome as one JSON-able dict — the single reporter
-        behind ``bench.py --chaos`` and ``fedml-tpu chaos-drill --json``
-        (callers add their own ``metric``/``unit`` framing on top)."""
+        behind ``fedml-tpu chaos-drill --json`` (callers add their own
+        ``metric``/``unit`` framing on top)."""
         rec = {
             "rounds_completed": self.rounds_completed,
             "rounds_expected": self.rounds_expected,
@@ -196,8 +196,8 @@ class StragglerDrillResult:
 
     def json_record(self) -> dict:
         """Same single-reporter contract as :meth:`ChaosDrillResult.
-        json_record` — one JSON-able dict behind ``bench.py --async-sweep``
-        and ``fedml-tpu chaos-drill --straggler --json``."""
+        json_record` — one JSON-able dict behind ``fedml-tpu chaos-drill
+        --straggler --json``."""
         return {
             "commits": self.commits,
             "committed_updates": self.committed_updates,
@@ -347,8 +347,8 @@ class TierDrillResult:
 
     def json_record(self) -> dict:
         """Same single-reporter contract as :meth:`ChaosDrillResult.
-        json_record` — one JSON-able dict behind ``bench.py --chaos`` and
-        ``fedml-tpu chaos-drill --leaf-crash/--partition --json``."""
+        json_record` — one JSON-able dict behind ``fedml-tpu chaos-drill
+        --leaf-crash/--partition --json``."""
         return {
             "scenario": self.scenario,
             "rounds_completed": self.rounds_completed,

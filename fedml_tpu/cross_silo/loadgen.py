@@ -22,8 +22,8 @@ serialization cost) against a bounded
   exceed ``queue_maxsize`` — that bound is the "zero unbounded memory
   growth" guarantee).
 
-Front doors: ``fedml-tpu loadgen`` (CLI), ``bench.py --loadgen`` (JSON
-line), and ``tests/test_tenancy.py`` (``-m loadgen``).
+Front doors: ``fedml-tpu loadgen`` (CLI; ``--json`` for one JSON line) and
+``tests/test_tenancy.py`` (``-m loadgen``).
 """
 
 from __future__ import annotations
@@ -168,8 +168,8 @@ class LoadGenReport:
         )
 
     def json_record(self) -> dict:
-        """The throughput/shed frontier as one JSON-able dict (the shape
-        ``bench.py --loadgen`` emits)."""
+        """The throughput/shed frontier as one JSON-able dict (what
+        ``fedml-tpu loadgen --json`` prints)."""
         return {
             "elapsed_s": round(self.elapsed_s, 4),
             "offered": self.offered,

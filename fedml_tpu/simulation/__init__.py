@@ -169,7 +169,6 @@ def build_simulator(args, fed_data=None, model=None, mesh=None) -> tuple:
         async_delay_skew=float(getattr(args, "async_delay_skew", 0.0) or 0.0),
         async_delay_jitter=float(getattr(args, "async_delay_jitter", 0.2)),
         rounds_per_dispatch=int(getattr(args, "rounds_per_dispatch", 1)),
-        sync_device_phase=bool(getattr(args, "bench_sync_device_phase", False)),
     )
 
     attack_type = getattr(args, "attack_type", None)

@@ -440,32 +440,21 @@ class AsyncFedSimulator(FedSimulator):
             for gen in rounds:
                 if self._round_gate is not None:
                     self._round_gate(gen)
-                t0 = time.perf_counter()
-                self._next_gen = gen + 1
-                if self._prefetcher is not None:
-                    inputs = self._prefetcher.get(gen)
-                else:
-                    inputs = self.build_round_inputs(gen)
-                pack_wait = time.perf_counter() - t0
-                self._phase_acc.append(("pack_wait", pack_wait))
+                with self._phase("pack_wait") as pw:
+                    self._next_gen = gen + 1
+                    if self._prefetcher is not None:
+                        inputs = self._prefetcher.get(gen)
+                    else:
+                        inputs = self.build_round_inputs(gen)
+                t0 = pw.start
                 step_rng = jax.random.fold_in(base_rng, gen)
-                t_disp = time.perf_counter()
-                n_acc = len(self._phase_acc)
-                with self._span("round_dispatch", str(gen)):
+                with self._phase("dispatch", str(gen)):
                     if self._lockstep:
                         metrics_vec = self._dispatch_even(inputs, step_rng)
                     else:
                         update, w, metrics_vec = self._dispatch_train(
                             inputs, step_rng)
-                t_inner = sum(dt for _, dt in self._phase_acc[n_acc:])
-                self._phase_acc.append(
-                    ("dispatch", time.perf_counter() - t_disp - t_inner))
-                timing = {
-                    "pack_time": inputs.pack_time,
-                    "pack_wait": pack_wait,
-                    "overlap": (max(0.0, 1.0 - pack_wait / inputs.pack_time)
-                                if inputs.pack_time > 0 else 0.0),
-                }
+                timing = self._pack_timing(inputs.pack_time, pw.wall)
                 if self._lockstep:
                     self._lockstep_commit(gen, inputs, t0, metrics_vec,
                                           timing, apply_fn, ckpt, log_fn)
@@ -505,31 +494,30 @@ class AsyncFedSimulator(FedSimulator):
         committed the whole cohort inside its donated round jit — only the
         event/commit accounting runs here, so the model math is the sync
         engine's own, bit for bit."""
-        tc = time.perf_counter()
-        ids = [int(c) for c in inputs.client_ids]
-        arrivals = []
-        for c in ids:
-            a = self._clock.get(c, 0.0) + self._delay(c, gen)
-            self._clock[c] = a
-            arrivals.append(a)
-        # the barriered commit waits for the slowest client, exactly the
-        # sync virtual round time
-        self._vt = max(self._vt, max(arrivals))
-        self._version += 1
-        self._committed += len(ids)
-        reg = telemetry.get_registry()
-        if reg.enabled:
-            reg.counter("fedml_commits_total").inc()
-            hist = reg.histogram("fedml_update_staleness")
-            for _ in ids:
-                hist.observe(0.0)
-            reg.gauge("fedml_goodput_updates_per_s").set(
-                self._committed / max(self._vt, 1e-12))
-        trace_plane.record_instant(
-            "commit", round_idx=gen,
-            attrs={"n": len(ids), "version": self._version,
-                   "virtual_time_s": round(self._vt, 6)})
-        self._phase_acc.append(("commit", time.perf_counter() - tc))
+        with self._phase("commit"):
+            ids = [int(c) for c in inputs.client_ids]
+            arrivals = []
+            for c in ids:
+                a = self._clock.get(c, 0.0) + self._delay(c, gen)
+                self._clock[c] = a
+                arrivals.append(a)
+            # the barriered commit waits for the slowest client, exactly the
+            # sync virtual round time
+            self._vt = max(self._vt, max(arrivals))
+            self._version += 1
+            self._committed += len(ids)
+            reg = telemetry.get_registry()
+            if reg.enabled:
+                reg.counter("fedml_commits_total").inc()
+                hist = reg.histogram("fedml_update_staleness")
+                for _ in ids:
+                    hist.observe(0.0)
+                reg.gauge("fedml_goodput_updates_per_s").set(
+                    self._committed / max(self._vt, 1e-12))
+            trace_plane.record_instant(
+                "commit", round_idx=gen,
+                attrs={"n": len(ids), "version": self._version,
+                       "virtual_time_s": round(self._vt, 6)})
         timing.update({
             "version": gen,
             "buffer_fill": len(ids),
@@ -554,21 +542,19 @@ class AsyncFedSimulator(FedSimulator):
         ids = inputs.client_ids
         stateful = self._client_state_proto != ()
         if stateful:
-            t = time.perf_counter()
-            states = self._gather_states(ids)
-            self._phase_acc.append(("state_gather", time.perf_counter() - t))
+            with self._phase("state_gather"):
+                states = self._gather_states(ids)
         else:
             states = ()
         step_args = (self.params, cohort, states, step_rng)
         if self._codec_rt is not None:
-            t = time.perf_counter()
-            codec_res = ()
-            if self._codec_arena is not None:
-                codec_res = self._codec_arena.gather(ids)
-            step_args += (codec_res,
-                          jnp.asarray(ids.astype(np.uint32)),
-                          jnp.uint32(inputs.round_idx))
-            self._phase_acc.append(("codec", time.perf_counter() - t))
+            with self._phase("codec"):
+                codec_res = ()
+                if self._codec_arena is not None:
+                    codec_res = self._codec_arena.gather(ids)
+                step_args += (codec_res,
+                              jnp.asarray(ids.astype(np.uint32)),
+                              jnp.uint32(inputs.round_idx))
         if self._use_device_data:
             step_args += (self._x_dev, self._y_dev)
         out = self._async_step(*step_args)
@@ -576,19 +562,17 @@ class AsyncFedSimulator(FedSimulator):
             *out, new_codec_res = out
         update, w, new_states, metrics_vec = out
         if stateful:
-            t = time.perf_counter()
-            self._scatter_states(ids, new_states)
-            self._phase_acc.append(("state_scatter", time.perf_counter() - t))
+            with self._phase("state_scatter"):
+                self._scatter_states(ids, new_states)
         if self._codec_rt is not None:
-            t = time.perf_counter()
-            if self._codec_arena is not None:
-                # EF residuals update at ENCODE time (the client owns them),
-                # not at commit — same as a real uplink
-                self._codec_arena.scatter(ids, new_codec_res)
-            dt = time.perf_counter() - t
-            self._phase_acc.append(("codec", dt))
+            with self._phase("codec") as ph:
+                if self._codec_arena is not None:
+                    # EF residuals update at ENCODE time (the client owns
+                    # them), not at commit — same as a real uplink
+                    self._codec_arena.scatter(ids, new_codec_res)
             raw, coded = self._codec_wire
-            self._codec_record("encode", raw * len(ids), coded * len(ids), dt)
+            self._codec_record(
+                "encode", raw * len(ids), coded * len(ids), ph.wall)
         return update, w, metrics_vec
 
     def _push_arrivals(self, gen: int, ids) -> None:
@@ -651,45 +635,44 @@ class AsyncFedSimulator(FedSimulator):
         from their generation stacks device-side, then one donated commit
         jit (sanitize/defense/aggregate/server-update) — the critical path
         never bounces through host."""
-        t0 = time.perf_counter()
-        refs = self._buffer
-        self._buffer = []
-        n = len(refs)
-        rows = [jax.tree.map(lambda x, p=pos: x[p], self._gens[g].update)
-                for g, pos, _ in refs]
-        stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
-        w = jnp.stack([self._gens[g].w[pos] for g, pos, _ in refs])
-        stale = np.asarray([s for _, _, s in refs], np.float32)
-        sw = jnp.asarray((1.0 + stale) ** (-self._alpha), jnp.float32)
-        out = self._commit_step(n)(
-            self.params, self.server_state, stacked, w, sw)
-        if self._detect:
-            self.params, self.server_state, qz = out
-            self._last_qz = qz
-            self._last_cohort_ids = np.asarray(
-                [int(self._gens[g].ids[pos]) for g, pos, _ in refs])
-        else:
-            self.params, self.server_state = out
-        version = self._version
-        self._version += 1
-        self._committed += n
-        metrics_vec = self._gens[refs[-1][0]].metrics_vec
-        # release generation stacks with no outstanding arrivals or refs
-        live = {g for g, _, _ in self._buffer}
-        for g in [g for g, e in self._gens.items()
-                  if e.remaining <= 0 and g not in live]:
-            del self._gens[g]
-        reg = telemetry.get_registry()
-        goodput = self._committed / max(self._vt, 1e-12)
-        if reg.enabled:
-            reg.counter("fedml_commits_total").inc()
-            reg.gauge("fedml_goodput_updates_per_s").set(goodput)
-        trace_plane.record_instant(
-            "commit", round_idx=version,
-            attrs={"n": n, "version": self._version,
-                   "staleness_max": int(stale.max()),
-                   "virtual_time_s": round(self._vt, 6)})
-        self._phase_acc.append(("commit", time.perf_counter() - t0))
+        with self._phase("commit") as ph:
+            refs = self._buffer
+            self._buffer = []
+            n = len(refs)
+            rows = [jax.tree.map(lambda x, p=pos: x[p], self._gens[g].update)
+                    for g, pos, _ in refs]
+            stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
+            w = jnp.stack([self._gens[g].w[pos] for g, pos, _ in refs])
+            stale = np.asarray([s for _, _, s in refs], np.float32)
+            sw = jnp.asarray((1.0 + stale) ** (-self._alpha), jnp.float32)
+            out = self._commit_step(n)(
+                self.params, self.server_state, stacked, w, sw)
+            if self._detect:
+                self.params, self.server_state, qz = out
+                self._last_qz = qz
+                self._last_cohort_ids = np.asarray(
+                    [int(self._gens[g].ids[pos]) for g, pos, _ in refs])
+            else:
+                self.params, self.server_state = out
+            version = self._version
+            self._version += 1
+            self._committed += n
+            metrics_vec = self._gens[refs[-1][0]].metrics_vec
+            # release generation stacks with no outstanding arrivals or refs
+            live = {g for g, _, _ in self._buffer}
+            for g in [g for g, e in self._gens.items()
+                      if e.remaining <= 0 and g not in live]:
+                del self._gens[g]
+            reg = telemetry.get_registry()
+            goodput = self._committed / max(self._vt, 1e-12)
+            if reg.enabled:
+                reg.counter("fedml_commits_total").inc()
+                reg.gauge("fedml_goodput_updates_per_s").set(goodput)
+            trace_plane.record_instant(
+                "commit", round_idx=version,
+                attrs={"n": n, "version": self._version,
+                       "staleness_max": int(stale.max()),
+                       "virtual_time_s": round(self._vt, 6)})
         rec_timing = dict(timing) if timing else {}
         rec_timing.update({
             "version": version,
@@ -704,8 +687,8 @@ class AsyncFedSimulator(FedSimulator):
         # post-increment self._version this commit just produced, so the
         # serving plane sees one publish per commit with the right number
         self._pending = self._defer_rec(
-            version, t0, metrics_vec, self._pending, apply_fn, ckpt, log_fn,
-            rec_timing)
+            version, ph.start, metrics_vec, self._pending, apply_fn, ckpt,
+            log_fn, rec_timing)
 
     def _gen_boundary(self, gen: int, timing, apply_fn, ckpt,
                       log_fn) -> None:

@@ -77,16 +77,17 @@ class SimConfig:
     # derive from the largest client (padding+mask covers the rest)
     num_local_batches: Optional[int] = None
     # packed schedule: force the lane count (None = the G*L cost search in
-    # core/scheduler.lane_schedule). Measured on the v5e: per-step cost is
-    # SUPERLINEAR in lane count (per-lane weights lower to grouped convs,
-    # whose thin per-group channels starve the MXU), so fewer, longer lanes
-    # can beat the padded-work optimum — set from a bench sweep.
+    # core/scheduler.lane_schedule). The hypothesis it serves (ROADMAP S1,
+    # its evidence predates the ledger): per-step cost is superlinear in
+    # lane count (per-lane weights lower to grouped convs, whose thin
+    # per-group channels starve the MXU), so fewer, longer lanes could beat
+    # the padded-work optimum. No benchmark cell sets it.
     packed_lanes: Optional[int] = None
     # flat-carry packed executor: the lane scan carries params/opt-state/
-    # delta as ONE ravelled vector instead of a ~170-leaf pytree. Measured
-    # 1.6x faster per step on the v5e at depth-56 (per-leaf update ops
-    # dominate the step); numerically parity-exact (same elementwise math).
-    # Default OFF until chip-validated end-to-end; bench.py opts in.
+    # delta as ONE ravelled vector instead of a ~170-leaf pytree;
+    # numerically parity-exact (same elementwise math,
+    # tests/test_packed_schedule.py). Off by default and turned on by tests
+    # only: which carry is faster end to end is not measured (ROADMAP D2).
     packed_flat_carry: bool = False
     # checkpoint/resume (orbax; the reference has none — SURVEY.md §5.4)
     checkpoint_dir: Optional[str] = None
@@ -260,13 +261,6 @@ class SimConfig:
     # packed/bucketed schedules, async mode, host-resident data or dict
     # state backends) raise ScanIncompatibleError at construction.
     rounds_per_dispatch: int = 1
-    # honest "device" phase stamping for benchmarks: block on the committed
-    # params (not just the tiny metric vector) before taking the completion
-    # timestamp. Under async dispatch the metric readback can return while
-    # the round's larger executables are still retiring, which shifted tail
-    # device time into host_other in earlier bench runs (BENCH_r07). Costs
-    # one extra sync per round, so off by default; bench.py opts in.
-    sync_device_phase: bool = False
 
 
 @dataclasses.dataclass
@@ -341,6 +335,24 @@ def pads_cohort(algorithm, update_transform) -> bool:
     return algorithm.aggregate is None and update_transform is None
 
 
+class _Phase:
+    """What ``FedSimulator._phase`` yields. The body may set ``name`` before
+    the exit: the time is filed under the name it has then. After the exit
+    ``end`` is the closing stamp and ``wall`` the whole bracket, the phases
+    opened inside it included."""
+
+    __slots__ = ("name", "start", "end", "inner")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.inner = 0.0  # wall of the phases opened inside this one
+        self.start = self.end = time.perf_counter()
+
+    @property
+    def wall(self) -> float:
+        return self.end - self.start
+
+
 class FedSimulator:
     """Generic over FedAlgorithm; placement decided by ``mesh``."""
 
@@ -393,10 +405,12 @@ class FedSimulator:
         # packed schedule: round-independent lane structure per (cohort,
         # drop) pattern — full-participation runs hit every round
         self._lane_plan_cache: Dict[Any, Dict[str, Any]] = {}
-        # phase attribution: (phase, seconds) intervals accrued since the
-        # last round-completion stamp; drained into rec["phases"] by
-        # _finalize_rec so the named phases + host_other sum to round_time
-        self._phase_acc: List[Any] = []
+        # phase attribution: seconds by phase accrued since the last
+        # round-completion stamp. Written by _phase alone; drained into
+        # rec["phases"] by _drain_phases, so the named phases + host_other
+        # sum to round_time. _phase_stack holds the phases now open.
+        self._phase_acc: Dict[str, float] = {}
+        self._phase_stack: List[_Phase] = []
         # sanitizer readback: the last dispatched round's (2, C) device
         # array of [quarantine flag, robust z] plus its cohort ids; drained
         # into the round record by _defer_rec
@@ -411,10 +425,10 @@ class FedSimulator:
         # multi-tenant round gate (simulation/multi_run.py): called with the
         # round index at the top of every round-loop iteration, BEFORE the
         # round's own timing starts — the fair scheduler blocks here until
-        # this job's turn on the mesh. The gate may append a
-        # ("tenant_wait", seconds) interval to _phase_acc so the wait is
-        # attributed rather than lumped into host_other. None (default) =
-        # single-tenant, zero behavior change.
+        # this job's turn on the mesh. The gate may wait inside
+        # ``_phase("tenant_wait")`` so the wait is attributed rather than
+        # lumped into host_other. None (default) = single-tenant, zero
+        # behavior change.
         self._round_gate: Optional[Callable[[int], None]] = None
         # commit→publish hook (serving plane): called with
         # ``(version, params_copy)`` after each round's params commit —
@@ -1489,43 +1503,25 @@ class FedSimulator:
             for round_idx in rounds:
                 if self._round_gate is not None:
                     self._round_gate(round_idx)
-                t0 = time.perf_counter()
-                if self._prefetcher is not None:
-                    inputs = self._prefetcher.get(round_idx)
-                else:
-                    inputs = self.build_round_inputs(round_idx)
                 # host stall on packing: with the pipeline warm this is a
                 # queue pop (~µs) while pack_time was spent on the worker
                 # under the PREVIOUS round's device compute
-                pack_wait = time.perf_counter() - t0
-                self._phase_acc.append(("pack_wait", pack_wait))
+                with self._phase("pack_wait") as pw:
+                    if self._prefetcher is not None:
+                        inputs = self._prefetcher.get(round_idx)
+                    else:
+                        inputs = self.build_round_inputs(round_idx)
                 step_rng = jax.random.fold_in(base_rng, round_idx)
-                t_disp = time.perf_counter()
-                n_acc = len(self._phase_acc)
-                with self._span("round_dispatch", str(round_idx)):
+                with self._phase("dispatch", str(round_idx)):
                     if inputs.kind == "packed":
                         metrics_vec = self._dispatch_packed(inputs, step_rng)
                     elif inputs.kind == "bucketed":
                         metrics_vec = self._dispatch_bucketed(inputs, step_rng)
                     else:
                         metrics_vec = self._dispatch_even(inputs, step_rng)
-                # the arena's state_gather/state_scatter phases are recorded
-                # inside the dispatch call — exclude them here so the phase
-                # breakdown partitions the round instead of double counting
-                t_inner = sum(dt for _, dt in self._phase_acc[n_acc:])
-                self._phase_acc.append(
-                    ("dispatch", time.perf_counter() - t_disp - t_inner))
-                timing = {
-                    "pack_time": inputs.pack_time,
-                    "pack_wait": pack_wait,
-                    # fraction of this round's host packing hidden behind
-                    # earlier device work (0 when synchronous)
-                    "overlap": (max(0.0, 1.0 - pack_wait / inputs.pack_time)
-                                if inputs.pack_time > 0 else 0.0),
-                }
                 pending = self._defer_rec(
-                    round_idx, t0, metrics_vec, pending, apply_fn, ckpt,
-                    log_fn, timing,
+                    round_idx, pw.start, metrics_vec, pending, apply_fn,
+                    ckpt, log_fn, self._pack_timing(inputs.pack_time, pw.wall),
                 )
         finally:
             # pregathered stacks are only valid within one prefetched run
@@ -1602,17 +1598,13 @@ class FedSimulator:
             attempts = 0
             t0 = time.perf_counter()
             while True:
-                t_pack = time.perf_counter()
-                inputs = self.build_round_inputs(round_idx, exclude=excluded)
-                self._phase_acc.append(
-                    ("pack_wait", time.perf_counter() - t_pack))
+                with self._phase("pack_wait"):
+                    inputs = self.build_round_inputs(
+                        round_idx, exclude=excluded)
                 start_state = snap()
                 step_rng = jax.random.fold_in(base_rng, round_idx)
-                t_disp = time.perf_counter()
-                with self._span("round_dispatch", str(round_idx)):
+                with self._phase("dispatch", str(round_idx)):
                     metrics_vec = self._dispatch_even(inputs, step_rng)
-                self._phase_acc.append(
-                    ("dispatch", time.perf_counter() - t_disp))
                 # sync by design: the watchdog verdict gates the next round's
                 # dispatch, so self-heal mode cannot defer this readback
                 mvec = np.asarray(metrics_vec)  # graftcheck: disable=host-sync
@@ -1693,19 +1685,66 @@ class FedSimulator:
     def _publish_params(self, version: int) -> None:
         if self._publisher is None:
             return
-        t_pub = time.perf_counter()
-        self._publisher(int(version), jax.tree.map(jnp.copy, self.params))
-        self._phase_acc.append(("publish", time.perf_counter() - t_pub))
+        with self._phase("publish"):
+            self._publisher(int(version), jax.tree.map(jnp.copy, self.params))
 
-    def _span(self, name: str, value: Optional[str] = None):
-        """A host phase of the round loop or of the prefetch thread: a span
-        of the telemetry tracer (so ``fedml:<name>`` on a device trace) and,
-        with an MLOps profiler, its started / ended events."""
-        stack = contextlib.ExitStack()
-        stack.enter_context(telemetry.get_tracer().span(name, value=value))
-        if self._profiler is not None:
-            stack.enter_context(self._profiler.span(name, event_value=value))
-        return stack
+    # The one alias: the phase ``dispatch`` of rec["phases"] is the span,
+    # and the MLOps event, ``round_dispatch``.
+    _PHASE_SPAN = {"dispatch": "round_dispatch"}
+    # The MLOps wire carries these two events and no other.
+    _MLOPS_EVENTS = frozenset({"round_dispatch", "host_pack"})
+    # Runs on the prefetch thread when there is one, beside the round and not
+    # inside it (its share of round_time is what pack_wait waited): a span
+    # and an event, never an entry of the accumulator, which only the round
+    # loop's thread touches.
+    _BESIDE_THE_ROUND = "host_pack"
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, value: Optional[str] = None):
+        """The one clock of the round loop's host side. Every named phase
+        runs inside it: a span of the telemetry tracer (``fedml:<name>`` on
+        a device trace; nothing with telemetry off), for ``_MLOPS_EVENTS``
+        the profiler's started / ended events, and on exit the phase's OWN
+        time (its wall less that of the phases opened inside it) added to
+        the accumulator that ``_drain_phases`` empties into
+        ``rec["phases"]``. Yields the :class:`_Phase`."""
+        span_name = self._PHASE_SPAN.get(name, name)
+        accrues = name != self._BESIDE_THE_ROUND
+        mlops = (self._profiler.span(span_name, event_value=value)
+                 if self._profiler is not None
+                 and span_name in self._MLOPS_EVENTS
+                 else contextlib.nullcontext())
+        ph = _Phase(name)
+        if accrues:
+            self._phase_stack.append(ph)
+        try:
+            with telemetry.get_tracer().span(span_name, value=value), mlops:
+                yield ph
+        finally:
+            ph.end = time.perf_counter()
+            if accrues:
+                self._phase_stack.pop()
+                if self._phase_stack:
+                    self._phase_stack[-1].inner += ph.wall
+                self._phase_acc[ph.name] = (
+                    self._phase_acc.get(ph.name, 0.0) + ph.wall - ph.inner)
+
+    def _drain_phases(self) -> Dict[str, float]:
+        """Everything the host did between the previous completion stamp and
+        this one, keyed by phase; the accumulator starts again empty."""
+        phases, self._phase_acc = self._phase_acc, {}
+        return phases
+
+    @staticmethod
+    def _pack_timing(pack_time: float, pack_wait: float) -> Dict[str, float]:
+        return {
+            "pack_time": pack_time,
+            "pack_wait": pack_wait,
+            # fraction of this round's host packing hidden behind earlier
+            # device work (0 when synchronous)
+            "overlap": (max(0.0, 1.0 - pack_wait / pack_time)
+                        if pack_time > 0 else 0.0),
+        }
 
     def _paused_prefetch(self):
         """Sync point: guarantees the prefetch worker is quiescent for the
@@ -1761,16 +1800,10 @@ class FedSimulator:
         metric read proves the round's executables retired); with the
         pipelined readback this is the honest per-round throughput number —
         the raw host dispatch time is kept as ``dispatch_time``."""
-        t_dev = time.perf_counter()
-        if self.cfg.sync_device_phase:
-            # the metric vector is a few scalars — its readback can land
-            # before the round's params-producing executables retire, so
-            # bench runs block on the committed params too before stamping
-            jax.block_until_ready(self.params)  # graftcheck: disable=host-sync
-        mvec = np.asarray(rec.pop("_mvec"))
-        now = time.perf_counter()
         # the blocking readback IS the wait on device compute still in flight
-        self._phase_acc.append(("device", now - t_dev))
+        with self._phase("device") as dev:
+            mvec = np.asarray(rec.pop("_mvec"))
+        now = dev.end
         rec["round_time"] = now - self._last_round_end
         self._last_round_end = now
         rec["train_loss"] = float(mvec[0])
@@ -1790,14 +1823,10 @@ class FedSimulator:
                 trace_plane.record_instant(
                     "quarantine", round_idx=rec["round"],
                     attrs={"clients": quarantined})
-        # drain the interval accumulator: everything the host did between the
-        # previous completion stamp and this one, keyed by phase; the
-        # remainder (logging, bookkeeping, deferred eval of earlier rounds'
-        # records...) is host_other, so the breakdown sums to round_time
-        phases: Dict[str, float] = {}
-        for name, dt in self._phase_acc:
-            phases[name] = phases.get(name, 0.0) + dt
-        self._phase_acc.clear()
+        # the remainder (logging, bookkeeping, deferred eval of earlier
+        # rounds' records...) is host_other, so the breakdown sums to
+        # round_time
+        phases = self._drain_phases()
         phases["host_other"] = max(
             0.0, rec["round_time"] - sum(phases.values()))
         rec["phases"] = phases
@@ -1841,32 +1870,26 @@ class FedSimulator:
 
     def _post_round_body(self, rec, round_idx, apply_fn, ckpt, log_fn) -> None:
         if apply_fn is not None and self._should_eval(round_idx):
-            t_eval = time.perf_counter()
-            # inner phases stamped during eval (the model-sharded path's
-            # params gather lands on "reshard") are subtracted so eval +
-            # reshard + ... still partition the round
-            n_eval_acc = len(self._phase_acc)
-            handled = False
-            if self._server_tester is not None:
-                # reference signature (FedAVGAggregator.py:130): the real
-                # device + the original args, not None placeholders —
-                # ported aggregators read args.* and the device
-                res = self._server_tester.test_on_the_server(
-                    self.fed.train_data_local_dict,
-                    self.fed.test_data_local_dict,
-                    jax.devices()[0], self._hook_args,
-                )
-                if res:  # truthy return replaces the default evaluation
-                    handled = True
-                    if isinstance(res, dict):
-                        rec.update(res)
-            if not handled:
-                rec.update(self.evaluate(apply_fn))
-                if self.cfg.local_test_on_all_clients:
-                    rec.update(self.local_test_on_all_clients(apply_fn))
-            t_inner = sum(dt for _, dt in self._phase_acc[n_eval_acc:])
-            self._phase_acc.append(
-                ("eval", time.perf_counter() - t_eval - t_inner))
+            # the model-sharded path's params gather opens "reshard" inside
+            with self._phase("eval"):
+                handled = False
+                if self._server_tester is not None:
+                    # reference signature (FedAVGAggregator.py:130): the real
+                    # device + the original args, not None placeholders —
+                    # ported aggregators read args.* and the device
+                    res = self._server_tester.test_on_the_server(
+                        self.fed.train_data_local_dict,
+                        self.fed.test_data_local_dict,
+                        jax.devices()[0], self._hook_args,
+                    )
+                    if res:  # truthy return replaces the default evaluation
+                        handled = True
+                        if isinstance(res, dict):
+                            rec.update(res)
+                if not handled:
+                    rec.update(self.evaluate(apply_fn))
+                    if self.cfg.local_test_on_all_clients:
+                        rec.update(self.local_test_on_all_clients(apply_fn))
         self.history.append(rec)
         # commit→publish: version = rounds folded (resume-stable, monotone —
         # a pending record always finalizes before the next one is created).
@@ -1879,10 +1902,8 @@ class FedSimulator:
         if ckpt is not None and self._should_checkpoint(round_idx):
             from ..utils.checkpoint import save_simulator_state
 
-            t_ckpt = time.perf_counter()
-            save_simulator_state(ckpt, self, round_idx)
-            self._phase_acc.append(
-                ("checkpoint", time.perf_counter() - t_ckpt))
+            with self._phase("checkpoint"):
+                save_simulator_state(ckpt, self, round_idx)
         if log_fn:
             log_fn(f"[round {round_idx}] " + " ".join(
                 f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
@@ -1917,8 +1938,7 @@ class FedSimulator:
         after sampling, so the cohort itself (and every other client's RNG
         stream) is unchanged vs the original run of the round."""
         cfg = self.cfg
-        t0 = time.perf_counter()
-        with self._span("host_pack", str(round_idx)):
+        with self._phase("host_pack", str(round_idx)) as pack:
             client_ids = np.asarray(sample_clients(
                 cfg.seed, round_idx,
                 cfg.client_num_in_total, cfg.client_num_per_round,
@@ -1949,7 +1969,7 @@ class FedSimulator:
                 kind = "even"
                 payload = self._build_even_inputs(client_ids, round_idx, drop)
         return RoundInputs(round_idx, client_ids, drop, kind, payload,
-                           time.perf_counter() - t0)
+                           pack.wall)
 
     def _build_even_inputs(self, client_ids, round_idx: int, drop):
         cfg = self.cfg
@@ -2020,8 +2040,7 @@ class FedSimulator:
         the vectorized permutation streams and one registry gather per
         round instead of per-client Python loops."""
         cfg = self.cfg
-        t0 = time.perf_counter()
-        with self._span("host_pack", f"{rounds[0]}+{len(rounds)}"):
+        with self._phase("host_pack", f"{rounds[0]}+{len(rounds)}") as pack:
             reg, sizes_all, lut = self._ensure_idx_registry()
             rounds = tuple(int(r) for r in rounds)
             L = len(rounds)
@@ -2068,7 +2087,7 @@ class FedSimulator:
                 gids = ids if not pad else np.concatenate(
                     [ids, np.repeat(ids[:, -1:], pad, axis=1)], axis=1)
                 xs["cids_u32"] = gids.astype(np.uint32)
-        return BlockInputs(rounds, ids, xs, time.perf_counter() - t0)
+        return BlockInputs(rounds, ids, xs, pack.wall)
 
     def _build_block(self, block: tuple):
         """Prefetchable builder for one block plan entry: length-1 blocks
@@ -2114,18 +2133,16 @@ class FedSimulator:
         self._last_round_end = time.perf_counter()
         try:
             for block in blocks:
-                t0 = time.perf_counter()
-                if self._prefetcher is not None:
-                    inputs = self._prefetcher.get(block)
-                else:
-                    inputs = self._build_block(block)
-                pack_wait = time.perf_counter() - t0
-                self._phase_acc.append(("pack_wait", pack_wait))
+                with self._phase("pack_wait") as pw:
+                    if self._prefetcher is not None:
+                        inputs = self._prefetcher.get(block)
+                    else:
+                        inputs = self._build_block(block)
                 if len(block) == 1:
-                    self._run_one_round(inputs, t0, pack_wait, base_rng,
+                    self._run_one_round(inputs, pw, base_rng,
                                         apply_fn, ckpt, log_fn)
                 else:
-                    self._dispatch_scan_block(inputs, t0, base_rng,
+                    self._dispatch_scan_block(inputs, pw.start, base_rng,
                                               apply_fn, ckpt, log_fn)
         finally:
             self._pregathered_state = self._pregathered_codec = None
@@ -2133,28 +2150,21 @@ class FedSimulator:
                 self._prefetcher.close()
                 self._prefetcher = None
 
-    def _run_one_round(self, inputs: RoundInputs, t0, pack_wait, base_rng,
+    def _run_one_round(self, inputs: RoundInputs, pw: _Phase, base_rng,
                        apply_fn, ckpt, log_fn) -> None:
         """One round on the per-round program inside the scan loop —
-        hook boundaries and capacity fallbacks. Finalized synchronously
-        (these rounds evaluate/checkpoint, which are sync points anyway)."""
+        hook boundaries and capacity fallbacks; ``pw`` is its closed
+        ``pack_wait`` phase. Finalized synchronously (these rounds
+        evaluate/checkpoint, which are sync points anyway)."""
         r = inputs.round_idx
         step_rng = jax.random.fold_in(base_rng, r)
-        t_disp = time.perf_counter()
-        n_acc = len(self._phase_acc)
-        with self._span("round_dispatch", str(r)):
+        with self._phase("dispatch", str(r)):
             metrics_vec = self._dispatch_even(inputs, step_rng)
-        t_inner = sum(dt for _, dt in self._phase_acc[n_acc:])
-        self._phase_acc.append(
-            ("dispatch", time.perf_counter() - t_disp - t_inner))
         rec = {
             "round": r,
-            "dispatch_time": time.perf_counter() - t0,
+            "dispatch_time": time.perf_counter() - pw.start,
             "_mvec": metrics_vec,
-            "pack_time": inputs.pack_time,
-            "pack_wait": pack_wait,
-            "overlap": (max(0.0, 1.0 - pack_wait / inputs.pack_time)
-                        if inputs.pack_time > 0 else 0.0),
+            **self._pack_timing(inputs.pack_time, pw.wall),
         }
         if self._last_qz is not None:
             rec["_qz"] = self._last_qz
@@ -2179,13 +2189,11 @@ class FedSimulator:
         xs = dict(inputs.xs)
         slots = cslots = None
         if self._arena is not None or self._codec_arena is not None:
-            t = time.perf_counter()
-            if self._arena is not None:
-                slots = self._arena.ensure_block(gids)
-            if self._codec_arena is not None:
-                cslots = self._codec_arena.ensure_block(gids)
-            self._phase_acc.append(
-                ("state_gather", time.perf_counter() - t))
+            with self._phase("state_gather"):
+                if self._arena is not None:
+                    slots = self._arena.ensure_block(gids)
+                if self._codec_arena is not None:
+                    cslots = self._codec_arena.ensure_block(gids)
             if ((self._arena is not None and slots is None)
                     or (self._codec_arena is not None and cslots is None)):
                 # the block's cohort union exceeds the arena capacity: the
@@ -2196,11 +2204,9 @@ class FedSimulator:
                            "exceeds client_state_capacity — running "
                            "per-round")
                 for r in block:
-                    t_r = time.perf_counter()
-                    inp = self.build_round_inputs(r)
-                    pw = time.perf_counter() - t_r
-                    self._phase_acc.append(("pack_wait", pw))
-                    self._run_one_round(inp, t_r, pw, base_rng, apply_fn,
+                    with self._phase("pack_wait") as pw:
+                        inp = self.build_round_inputs(r)
+                    self._run_one_round(inp, pw, base_rng, apply_fn,
                                         ckpt, log_fn)
                 return
         if slots is not None:
@@ -2213,21 +2219,20 @@ class FedSimulator:
             step = self._build_scan_step(L)
             self._scan_steps[L] = step
         # one staged upload per block (a few KB/round of indices)
-        t = time.perf_counter()
-        if self.mesh is not None:
-            blk_sh = shard_along(self.mesh, cfg.cohort_shard_axis, 1)
-            rep = replicated(self.mesh)
-            xs_dev = {k: jax.device_put(v, rep if v.ndim == 1 else blk_sh)
-                      for k, v in xs.items()}
-        else:
-            xs_dev = {k: jnp.asarray(v) for k, v in xs.items()}
-        self._phase_acc.append(("scan_pack", time.perf_counter() - t))
+        with self._phase("scan_pack"):
+            if self.mesh is not None:
+                blk_sh = shard_along(self.mesh, cfg.cohort_shard_axis, 1)
+                rep = replicated(self.mesh)
+                xs_dev = {
+                    k: jax.device_put(v, rep if v.ndim == 1 else blk_sh)
+                    for k, v in xs.items()}
+            else:
+                xs_dev = {k: jnp.asarray(v) for k, v in xs.items()}
         arena_leaves = (self._arena.take_leaves()
                         if self._arena is not None else [])
         codec_leaves = (self._codec_arena.take_leaves()
                         if self._codec_arena is not None else [])
-        t_disp = time.perf_counter()
-        with self._span("round_dispatch", f"{block[0]}+{L}"):
+        with self._phase("dispatch", f"{block[0]}+{L}"):
             (self.params, self.server_state, new_arena, new_codec, ys) = step(
                 self.params, self.server_state, arena_leaves,
                 codec_leaves, base_rng, xs_dev)
@@ -2235,7 +2240,6 @@ class FedSimulator:
                 self._arena.set_leaves(new_arena, slots[:, :c_real])
             if self._codec_arena is not None:
                 self._codec_arena.set_leaves(new_codec, cslots[:, :c_real])
-        self._phase_acc.append(("dispatch", time.perf_counter() - t_disp))
         if fresh_program:
             # the first block of a given length compiles its own program —
             # a planned event, not the recompile detector's business
@@ -2250,22 +2254,17 @@ class FedSimulator:
         # ONE blocking readback per block; the wait IS the device phase
         # (deliberate sync point, same contract as _finalize_rec) —
         # graftcheck: disable=host-sync
-        t_dev = time.perf_counter()
-        mvec = np.asarray(mvec_dev)  # graftcheck: disable=host-sync
-        qz = (np.asarray(qz_dev)  # graftcheck: disable=host-sync
-              if qz_dev is not None else None)
-        self._phase_acc.append(("device", time.perf_counter() - t_dev))
-        now = time.perf_counter()
+        with self._phase("device") as dev:
+            mvec = np.asarray(mvec_dev)  # graftcheck: disable=host-sync
+            qz = (np.asarray(qz_dev)  # graftcheck: disable=host-sync
+                  if qz_dev is not None else None)
+        now = dev.end
         span = now - self._last_round_end
         self._last_round_end = now
         # amortized attribution: each interval the host spent on this block
         # splits evenly over its rounds; the remainder is host_other, so
         # every round's phases sum exactly to its round_time (= span / L)
-        acc: Dict[str, float] = {}
-        for name, dt in self._phase_acc:
-            acc[name] = acc.get(name, 0.0) + dt
-        self._phase_acc.clear()
-        per_round = {k: v / L for k, v in acc.items()}
+        per_round = {k: v / L for k, v in self._drain_phases().items()}
         rt = span / L
         per_round["host_other"] = max(0.0, rt - sum(per_round.values()))
         reg = telemetry.get_registry()
@@ -2322,11 +2321,10 @@ class FedSimulator:
             # GSPMD schedules at dispatch, so round phases keep summing
             # exactly to round_time instead of hiding layout traffic in
             # dispatch/host_other
-            t = time.perf_counter()
-            c_sh = shard_along(self.mesh, self.cfg.cohort_shard_axis, 0)
-            cohort = {k: jax.device_put(np.asarray(v), c_sh)
-                      for k, v in inputs.payload.items()}
-            self._phase_acc.append(("reshard", time.perf_counter() - t))
+            with self._phase("reshard"):
+                c_sh = shard_along(self.mesh, self.cfg.cohort_shard_axis, 0)
+                cohort = {k: jax.device_put(np.asarray(v), c_sh)
+                          for k, v in inputs.payload.items()}
         else:
             cohort = {k: jnp.asarray(v) for k, v in inputs.payload.items()}
         ids = inputs.client_ids
@@ -2338,36 +2336,34 @@ class FedSimulator:
             [ids, np.repeat(ids[-1], pad)])
         gkey = gather_ids.tobytes()
         if stateful:
-            t = time.perf_counter()
             # a matching pregathered stack (dispatched under the PREVIOUS
             # round's device shadow via put_take) makes this a tree
             # unflatten + the prepare dispatch; prepare must run at consume
             # time because it reads the previous round's server_state OUTPUT
-            states = self._take_pregathered(
-                "_pregathered_state", inputs.round_idx, gkey)
-            if states is not None:
-                if self._prepare_fn is not None:
-                    states = self._prepare_fn(self.server_state, states)
-            else:
-                states = self._gather_states(gather_ids)
-            self._phase_acc.append(("state_gather", time.perf_counter() - t))
+            with self._phase("state_gather"):
+                states = self._take_pregathered(
+                    "_pregathered_state", inputs.round_idx, gkey)
+                if states is not None:
+                    if self._prepare_fn is not None:
+                        states = self._prepare_fn(self.server_state, states)
+                else:
+                    states = self._gather_states(gather_ids)
         else:
             states = ()
         step_args = (self.params, self.server_state, cohort, states, step_rng)
         if self._codec_rt is not None:
             # EF residuals ride the same padded-gather pattern as client
             # state; the id vector keys each row's stochastic-rounding stream
-            t = time.perf_counter()
-            codec_res = ()
-            if self._codec_arena is not None:
-                codec_res = self._take_pregathered(
-                    "_pregathered_codec", inputs.round_idx, gkey)
-                if codec_res is None:
-                    codec_res = self._codec_arena.gather(gather_ids)
-            step_args += (codec_res,
-                          jnp.asarray(gather_ids.astype(np.uint32)),
-                          jnp.uint32(inputs.round_idx))
-            self._phase_acc.append(("codec", time.perf_counter() - t))
+            with self._phase("codec"):
+                codec_res = ()
+                if self._codec_arena is not None:
+                    codec_res = self._take_pregathered(
+                        "_pregathered_codec", inputs.round_idx, gkey)
+                    if codec_res is None:
+                        codec_res = self._codec_arena.gather(gather_ids)
+                step_args += (codec_res,
+                              jnp.asarray(gather_ids.astype(np.uint32)),
+                              jnp.uint32(inputs.round_idx))
         if self._use_device_data:
             step_args += (self._x_dev, self._y_dev)
         out = self._round_step(*step_args)
@@ -2388,37 +2384,35 @@ class FedSimulator:
         else:
             self.params, self.server_state, new_states, metrics_vec = out
         if stateful:
-            t = time.perf_counter()
-            if pad:
-                new_states = jax.tree.map(lambda x: x[: len(ids)], new_states)
-            if (nxt is not None and self._arena is not None
-                    and self._try_move(self._arena, "_pregathered_state",
-                                       nxt, ids, new_states)):
-                # the scatter AND round r+1's gather just dispatched under
-                # the in-flight step — stamped as their own phase so
-                # state_gather/state_scatter honestly show only what is
-                # left on the between-rounds critical path
-                self._phase_acc.append(
-                    ("state_move", time.perf_counter() - t))
-            else:
-                self._scatter_states(ids, new_states)
-                self._phase_acc.append(
-                    ("state_scatter", time.perf_counter() - t))
-        if self._codec_rt is not None:
-            t = time.perf_counter()
-            if self._codec_arena is not None:
+            with self._phase("state_scatter") as ph:
                 if pad:
-                    new_codec_res = jax.tree.map(
-                        lambda x: x[: len(ids)], new_codec_res)
-                if not (nxt is not None
-                        and self._try_move(self._codec_arena,
-                                           "_pregathered_codec",
-                                           nxt, ids, new_codec_res)):
-                    self._codec_arena.scatter(ids, new_codec_res)
-            dt = time.perf_counter() - t
-            self._phase_acc.append(("codec", dt))
+                    new_states = jax.tree.map(
+                        lambda x: x[: len(ids)], new_states)
+                if (nxt is not None and self._arena is not None
+                        and self._try_move(self._arena, "_pregathered_state",
+                                           nxt, ids, new_states)):
+                    # the scatter AND round r+1's gather just dispatched
+                    # under the in-flight step — filed as their own phase so
+                    # state_gather/state_scatter honestly show only what is
+                    # left on the between-rounds critical path (the span
+                    # keeps the name it was entered under)
+                    ph.name = "state_move"
+                else:
+                    self._scatter_states(ids, new_states)
+        if self._codec_rt is not None:
+            with self._phase("codec") as ph:
+                if self._codec_arena is not None:
+                    if pad:
+                        new_codec_res = jax.tree.map(
+                            lambda x: x[: len(ids)], new_codec_res)
+                    if not (nxt is not None
+                            and self._try_move(self._codec_arena,
+                                               "_pregathered_codec",
+                                               nxt, ids, new_codec_res)):
+                        self._codec_arena.scatter(ids, new_codec_res)
             raw, coded = self._codec_wire
-            self._codec_record("encode", raw * len(ids), coded * len(ids), dt)
+            self._codec_record(
+                "encode", raw * len(ids), coded * len(ids), ph.wall)
         return metrics_vec
 
     def _packed_lane_plan(self, client_ids: np.ndarray, drop):
@@ -2543,10 +2537,8 @@ class FedSimulator:
                                   round_idx: int, drop):
         """Pre-pipeline reference packer: per-client Python loop with
         slice-by-slice lane writes. Kept as the bit-exactness oracle for
-        ``_build_packed_inputs`` (tests) and as the baseline the
-        ``bench.py --host-pack`` micro-mode measures the speedup against —
-        so it bypasses the lane-schedule memo cache (pre-PR code paid the
-        LPT search every round; same result either way)."""
+        ``_build_packed_inputs`` (``tests/test_prefetch.py``); it bypasses
+        the lane-schedule memo cache (same result either way)."""
         from ..core.scheduler import _lane_schedule_cached
 
         cfg = self.cfg
@@ -2688,10 +2680,8 @@ class FedSimulator:
             ids, n_real = bucket["ids"], bucket["n_real"]
             cohort = {k: jnp.asarray(v) for k, v in bucket["payload"].items()}
             if stateful:
-                t = time.perf_counter()
-                states = self._gather_states(ids)
-                self._phase_acc.append(
-                    ("state_gather", time.perf_counter() - t))
+                with self._phase("state_gather"):
+                    states = self._gather_states(ids)
             else:
                 states = ()
             step_args = (self.params, cohort, states, step_rng)
@@ -2701,13 +2691,11 @@ class FedSimulator:
             sum_wu = swu if sum_wu is None else jax.tree.map(jnp.add, sum_wu, swu)
             total_w = sw if total_w is None else total_w + sw
             if new_states != ():
-                t = time.perf_counter()
-                self._scatter_states(
-                    ids[:n_real],
-                    jax.tree.map(lambda x: x[:n_real], new_states),
-                )
-                self._phase_acc.append(
-                    ("state_scatter", time.perf_counter() - t))
+                with self._phase("state_scatter"):
+                    self._scatter_states(
+                        ids[:n_real],
+                        jax.tree.map(lambda x: x[:n_real], new_states),
+                    )
             ls = mets["train_loss"][:n_real].sum()
             cs = mets["train_correct"][:n_real].sum()
             vs = mets["train_valid"][:n_real].sum()
@@ -2734,10 +2722,8 @@ class FedSimulator:
         ``reshard`` phase so eval timing stays honest."""
         if self._model_axis is None:
             return self.params
-        t = time.perf_counter()
-        p = jax.device_put(self.params, replicated(self.mesh))
-        self._phase_acc.append(("reshard", time.perf_counter() - t))
-        return p
+        with self._phase("reshard"):
+            return jax.device_put(self.params, replicated(self.mesh))
 
     def evaluate(self, apply_fn) -> Dict[str, float]:
         if self._eval_fn is None:

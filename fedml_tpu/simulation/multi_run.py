@@ -181,17 +181,14 @@ class MultiTenantSimDriver:
         t_run = time.perf_counter()
 
         def gate(round_idx: int) -> None:
-            t0 = time.perf_counter()
-            with self._cond:
+            # the scheduler wait is its own phase, so the round's breakdown
+            # still sums exactly to round_time
+            with sim._phase("tenant_wait"), self._cond:
                 self._state[tenant] = _READY
                 self._cond.notify_all()
                 while self._state[tenant] != _GRANTED:
                     self._cond.wait()
                 self._state[tenant] = _RUNNING
-            # attribute the scheduler wait as its own phase so the round's
-            # breakdown still sums exactly to round_time
-            sim._phase_acc.append(
-                ("tenant_wait", time.perf_counter() - t0))
 
         sim._round_gate = gate
         # contextvars do not inherit into threads: the tenant scope must be

@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives.
 
-One rule, shared by ``fedml_tpu.init()``, ``bench.py`` and ``chip_smoke.py``:
+One rule, shared by ``fedml_tpu.init()`` and ``chip_smoke.py``:
 the directory is placed from OUTSIDE when ``JAX_COMPILATION_CACHE_DIR`` is
 set (jax reads the variable itself, so nothing is set in code), and is
 otherwise one fixed, git-ignored directory inside the checkout. The path is

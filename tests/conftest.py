@@ -3,8 +3,8 @@
 The whole suite runs on the CPU platform with eight virtual devices, so the
 sharding tests need no chip and a machine that has one keeps it free: the
 platform and the device-count flag are pinned here, before the first device
-lookup. What runs on the chip is ``chip_smoke.py`` and ``bench.py``, never
-pytest.
+lookup. What runs on the chip is ``chip_smoke.py`` and ``benchmark/run.py``,
+never pytest.
 """
 
 import os

@@ -1,21 +1,27 @@
 """Fixture package: phase emission sites for the phase-name drift rule.
 
-``warp`` is documented in docs/observability.md (clean); ``mystery_phase``
-is not (fires ``phase-undocumented:mystery_phase``).
+``warp`` and ``drift`` are documented in docs/observability.md (clean);
+``mystery_phase`` and ``renamed_mystery`` are not (each fires
+``phase-undocumented:<name>``).
 """
 
-import time
+import contextlib
 
 
 class Sim:
-    def __init__(self):
-        self._phase_acc = []
+    @contextlib.contextmanager
+    def _phase(self, name, value=None):
+        yield self
 
     def step(self):
-        t = time.perf_counter()
-        self._phase_acc.append(("warp", time.perf_counter() - t))
-        self._phase_acc.append(("mystery_phase", time.perf_counter() - t))
-        # non-tuple / non-constant appends are ignored by the rule
-        self._phase_acc.append("not_a_tuple")
+        with self._phase("warp"):
+            pass
+        with self._phase("mystery_phase", "7") as ph:
+            ph.name = "renamed_mystery"
+        with self._phase("drift") as ph:
+            # a name set on anything but the handle is not a phase
+            self.name = "not_a_phase"
+        # a name that is not a constant is not seen by the rule
         name = "dynamic"
-        self._phase_acc.append((name, 0.0))
+        with self._phase(name):
+            pass

@@ -518,7 +518,8 @@ def test_straggler_drill_gates_goodput_and_accuracy():
     seeded heavy-tail skew the async engine's goodput (committed updates
     per virtual second) must beat the synchronous round rate ≥3× with
     final accuracy within 2% of the sync run — and the drill's json_record
-    must carry the gate verdicts for the bench artifact."""
+    must carry the gate verdicts (``fedml-tpu chaos-drill --straggler
+    --json`` prints it)."""
     from fedml_tpu.cross_silo.chaos import run_straggler_drill
 
     result = run_straggler_drill()

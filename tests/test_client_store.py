@@ -57,6 +57,23 @@ def _assert_tree_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def _assert_history_matches_across_layouts(hist_ref, hist):
+    """A client mesh reduces each shard and then combines the shards: the
+    cohort sum runs in another order than the unsharded one, and float32
+    addition is not associative. So across LAYOUTS the history's floats are
+    held to 1e-5 (losses of order 1: a few ulps a round, measured 6e-8 at
+    round 2) and everything that is not a float to ``==``. Runs of one
+    layout (resume, replay, spill, scan against R = 1) stay bit-exact."""
+    assert len(hist_ref) == len(hist)
+    for r1, r2 in zip(_strip_timing(hist_ref), _strip_timing(hist)):
+        assert r1.keys() == r2.keys()
+        for k in r1:
+            if isinstance(r1[k], float):
+                assert abs(r1[k] - r2[k]) < 1e-5, (k, r1[k], r2[k])
+            else:
+                assert r1[k] == r2[k], (k, r1[k], r2[k])
+
+
 def _mesh2():
     return create_mesh(MeshConfig(axes=((AXIS_CLIENT, 2),)),
                        devices=jax.devices()[:2])
@@ -222,10 +239,10 @@ def test_arena_selfheal_rollback_parity():
 
 
 def test_mesh_history_bit_identical_and_never_unsharded():
-    """2-device client mesh: bit-identical round history to the unsharded
-    run, and the stacked update entering aggregation is asserted (via
-    sharding inspection inside the compiled step) to never materialize
-    unsharded."""
+    """2-device client mesh: the round history of the unsharded run (floats
+    to reduction-order noise, the rest equal: the helper says why), and the
+    stacked update entering aggregation is asserted (via sharding
+    inspection inside the compiled step) to never materialize unsharded."""
     sim1, hist1 = _run()
     seen = {}
     mesh = _mesh2()
@@ -235,7 +252,7 @@ def test_mesh_history_bit_identical_and_never_unsharded():
     assert not seen["update"].is_fully_replicated, \
         "stacked update materialized unsharded inside the round step"
     assert seen["agg"].is_fully_replicated
-    assert _strip_timing(hist1) == _strip_timing(hist2)
+    _assert_history_matches_across_layouts(hist1, hist2)
     # params agree to cross-device reduction-order noise (the mesh run
     # reduces per-shard then combines; same tolerance class as the
     # pre-arena mesh path)
@@ -252,14 +269,7 @@ def test_mesh_padded_cohort_matches_unsharded():
     sim2, apply_fn = build_simulator(_args(**kw), mesh=_mesh2())
     assert sim2._cohort_pad == 1
     hist2 = sim2.run(apply_fn, log_fn=None)
-    for r1, r2 in zip(hist1, hist2):
-        for k in r1:
-            if k in TIMING_KEYS:
-                continue
-            if isinstance(r1[k], float):
-                assert abs(r1[k] - r2[k]) < 1e-5, (k, r1[k], r2[k])
-            else:
-                assert r1[k] == r2[k], (k, r1[k], r2[k])
+    _assert_history_matches_across_layouts(hist1, hist2)
 
 
 def test_mesh_padding_with_attack_rejected():

@@ -163,16 +163,24 @@ def test_auto_specs_deterministic():
 def test_2d_mesh_history_bit_identical():
     """The whole point of the lazy-gather design: model-axis sharding is a
     LAYOUT change, not a numerics change. History and final params from the
-    2×2 mesh match the 1-D mesh and the unsharded path bit-for-bit, with
-    the stateful SCAFFOLD algorithm (server c + per-client c_local rows all
-    live on the model axis)."""
+    2×2 mesh match the 1-D mesh bit-for-bit, with the stateful SCAFFOLD
+    algorithm (server c + per-client c_local rows all live on the model
+    axis). Against the UNSHARDED run the client axis splits the cohort sum
+    (each shard reduced, then the shards combined): another order of float32
+    additions, so that history's floats are held to 1e-5 (losses of order 1;
+    the runs differ in the last ulp from round 2 on) and the rest to ==."""
     sim0, h0 = _run()
     sim1, h1 = _run(mesh=_mesh1())
     sim2, h2 = _run(mesh=_mesh2x2())
-    assert _strip_timing(h0) == _strip_timing(h1) == _strip_timing(h2)
-    # param BITS are compared mesh-to-mesh: the unsharded path computes the
-    # client reduction unsplit, so (as with the seed's 1-D guarantee) its
-    # parity claim is the round history; the model axis itself must not
+    assert _strip_timing(h1) == _strip_timing(h2)
+    for r0, r1 in zip(_strip_timing(h0), _strip_timing(h1), strict=True):
+        assert r0.keys() == r1.keys()
+        for k in r0:
+            if isinstance(r0[k], float):
+                assert abs(r0[k] - r1[k]) < 1e-5, (k, r0[k], r1[k])
+            else:
+                assert r0[k] == r1[k], (k, r0[k], r1[k])
+    # param BITS are compared mesh-to-mesh: the model axis itself must not
     # perturb a single bit
     _assert_tree_equal(sim1.params, sim2.params)
     _assert_tree_equal(sim1.server_state, sim2.server_state)
@@ -315,6 +323,26 @@ def test_reshard_phase_and_hbm_gauge(monkeypatch):
     gauges = [k for k in snap["gauges"]
               if k.startswith("fedml_device_hbm_peak_bytes")]
     assert bool(gauges) == has_stats
+
+
+def test_resident_state_bytes_scale_inverse_model_axis():
+    """A count: what a device keeps between rounds (params, server
+    opt-state, the arena's per-client rows) divides by the model axis.
+    Client axis 2 throughout; 25% of slack for the small leaves that no
+    axis divides and that stay replicated (the lr bias, SCAFFOLD's)."""
+    def resident_max(mesh):
+        sim, _ = _run(mesh=mesh, comm_round=1)
+        per_device = {}
+        trees = [sim.params, sim.server_state, list(sim._arena._leaves)]
+        for leaf in jax.tree.leaves(trees):
+            for shard in leaf.addressable_shards:
+                per_device[shard.device] = (
+                    per_device.get(shard.device, 0) + shard.data.nbytes)
+        return max(per_device.values())
+
+    base = resident_max(_mesh1())
+    for m, mesh in ((2, _mesh2x2()), (4, _mesh2x4())):
+        assert resident_max(mesh) <= 1.25 * base / m, m
 
 
 def test_model_shard_axis_off_disables_sharding():

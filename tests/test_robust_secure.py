@@ -178,18 +178,25 @@ def test_sanitize_valid_mask_matches_subset_run():
 
 def test_pairwise_dists_tiled_matches_untiled():
     """The client-axis tiling (how the sharded Krum path bounds the C x C
-    distance matrix working set) is exact — including a non-divisor tile,
-    whose last partial block is zero-padded and trimmed. Only a
-    non-positive tile is a hard error."""
+    distance matrix working set) computes the untiled matrix — including a
+    non-divisor tile, whose last partial block is zero-padded and trimmed.
+    Only a non-positive tile is a hard error."""
     import pytest
 
     rng = np.random.default_rng(1)
     stacked = {"w": jnp.asarray(rng.normal(size=(8, 5)).astype(np.float32))}
     base = np.asarray(pairwise_sq_dists(stacked))
+    # d_ij = |x_i|^2 + |x_j|^2 - 2 x_i.x_j from a float32 Gram: a tile runs
+    # its products in another order, so an entry moves by a few ulps of the
+    # NORMS it is the difference of (|x|^2 ~ 5 at width 5, 2.4e-7 measured
+    # on the diagonal, which is 0 untiled). rtol alone cannot hold a 0;
+    # atol = 8 ulps of the largest squared norm can.
+    atol = 8 * np.finfo(np.float32).eps * float(
+        (np.asarray(stacked["w"]) ** 2).sum(axis=1).max())
     for t in (1, 2, 3, 4, 8):
         np.testing.assert_allclose(
             np.asarray(pairwise_sq_dists(stacked, tile_size=t)), base,
-            rtol=1e-5)
+            rtol=1e-5, atol=atol)
     with pytest.raises(ValueError, match="must be positive"):
         pairwise_sq_dists(stacked, tile_size=0)
 

@@ -179,6 +179,11 @@ def test_config_drift_fires_on_fixture_repo():
     # phase-name drift: emitted-but-undocumented fires, documented is clean
     assert "phase-undocumented:mystery_phase" in keys
     assert "phase-undocumented:warp" not in keys
+    # a name the body sets on the handle is a phase too; one set on anything
+    # else, or not a constant, is not
+    assert "phase-undocumented:renamed_mystery" in keys
+    assert not {"phase-undocumented:drift", "phase-undocumented:not_a_phase",
+                "phase-undocumented:dynamic"} & keys
 
 
 # -------------------------------------------------------------- no-print

@@ -304,6 +304,98 @@ def test_simulator_phase_breakdown_sums_to_round_time():
                snap["histograms"])
 
 
+def test_simulator_phase_clock_is_the_one_timer(monkeypatch):
+    """``FedSimulator._phase``: nested phases file exclusive times that sum
+    to the outer wall; a handle renamed in the body files under its new
+    name; ``host_pack`` is a span and an MLOps event but no share of a
+    round; every phase is a ``fedml:`` span when telemetry is on, and
+    ``rec["phases"]`` is whole when it is off."""
+    from fedml_tpu.simulation import build_simulator
+
+    annotated = []
+
+    class Annotation:
+        def __init__(self, name):
+            annotated.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(telemetry, "_annotation_cls", Annotation)
+    events = []
+    sink = MetricsSink()
+    sink.emit = events.append
+    args = fedml_tpu.init(config=dict(
+        dataset="mnist", model="lr", debug_small_data=True,
+        client_num_in_total=8, client_num_per_round=4, comm_round=3,
+        learning_rate=0.1, epochs=1, batch_size=8, frequency_of_the_test=2,
+        random_seed=0, federated_optimizer="SCAFFOLD", prefetch=False,
+    ))
+    args.profiler = MLOpsProfilerEvent(sink=sink)
+    telemetry.configure(enabled=True, reset=True)
+    sim, _ = build_simulator(args)
+
+    with sim._phase("eval") as outer:
+        with sim._phase("reshard") as inner:
+            time.sleep(0.002)
+        with sim._phase("state_scatter") as moved:
+            moved.name = "state_move"
+        with sim._phase("host_pack", "0") as pack:
+            time.sleep(0.001)
+    with sim._phase("dispatch", "7"):
+        pass
+    phases = sim._drain_phases()
+    assert sim._drain_phases() == {}
+    assert set(phases) == {"eval", "reshard", "state_move", "dispatch"}
+    assert phases["reshard"] == inner.wall >= 0.002
+    assert pack.wall >= 0.001  # timed, and charged to the phase around it
+    assert phases["eval"] == pytest.approx(
+        outer.wall - inner.wall - moved.wall, abs=1e-12)
+    assert phases["eval"] + phases["reshard"] + phases["state_move"] == \
+        pytest.approx(outer.wall, abs=1e-12)
+    spans = telemetry.get_tracer().finished_spans()
+    # the span keeps the name it was entered under; ``dispatch`` is the one
+    # phase whose span (and MLOps event) has another name
+    assert [s["name"] for s in spans] == [
+        "reshard", "state_scatter", "host_pack", "eval", "round_dispatch"]
+    assert spans[-1]["value"] == "7" and spans[0]["value"] is None
+    assert annotated == ["fedml:" + n for n in (
+        "eval", "reshard", "state_scatter", "host_pack", "round_dispatch")]
+    assert {e["event"] for e in events} == {"host_pack", "round_dispatch"}
+
+    def run_whole():
+        # a simulator a run: what a run times after its last completion
+        # stamp (the final eval) stays in the accumulator
+        sim, apply_fn = build_simulator(args)
+        history = sim.run(apply_fn, log_fn=None)
+        for rec in history:
+            assert sum(rec["phases"].values()) == pytest.approx(
+                rec["round_time"], rel=1e-6, abs=1e-9)
+        # over the run (a deferred record holds its successor's dispatch);
+        # SCAFFOLD's arena moves are timed inside ``dispatch``
+        assert {"pack_wait", "dispatch", "device", "state_gather",
+                "state_scatter", "eval", "host_other"} <= {
+                    k for rec in history for k in rec["phases"]}
+
+    telemetry.configure(enabled=False, reset=True)
+    del annotated[:], events[:]
+    run_whole()
+    assert telemetry.get_tracer().finished_spans() == [] and annotated == []
+    # the MLOps wire does not depend on the tracer: its two events, no other
+    assert {e["event"] for e in events} == {"host_pack", "round_dispatch"}
+
+    telemetry.configure(enabled=True, reset=True)
+    run_whole()
+    names = {s["name"] for s in telemetry.get_tracer().finished_spans()}
+    assert {"pack_wait", "round_dispatch", "device", "state_gather",
+            "state_scatter", "eval", "host_pack"} <= names
+    assert "dispatch" not in names
+    assert set(annotated) == {"fedml:" + n for n in names}
+
+
 # --- exporters ---------------------------------------------------------------
 
 
