@@ -13,7 +13,9 @@ One process, the normal entry points, no bench-only switch:
                TPU from T=1024 up: PR 27's sweep, PERF.md section 6);
                then a small layer_types decoder (short convolution, grouped
                rotary attention, dropless routed experts on a share of the
-               experts: models/hybrid_lm.py) through the same trainer
+               experts: models/hybrid_lm.py) through the same trainer, and
+               that layer's row-move kernels against x[index] at the LFM2
+               cell's shape (16,640 buffer rows for 65,536 slots, 2048 wide)
 4. kernels   — every other pallas_call against its in-repo reference:
                fused_gram (alone at a 1000-row cohort, and inside
                fused_sanitize_krum on a flagship cohort of ResNet-56
@@ -61,6 +63,9 @@ HYBRID_LM = dict(
     moe_intermediate_size=256, num_experts=16, num_experts_per_tok=4,
     experts_held=(4, 8), num_attention_heads=8, num_key_value_heads=2)
 HYBRID_SEQ, HYBRID_BATCH = 1024, 2
+# the LFM2 cell's expert layer: 16,384 tokens x top-4 over 64 experts of
+# which 8 are held, so 16,640 buffer rows for 65,536 slots; rows 2048 wide
+ROW_MOVES = (16384, 4, 64, 8, 2048)
 GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
 GRAM_REFUSED = (10, 1_000_000)  # wider than full-row tiles fit in VMEM
 QUANT_SHAPE = (1000, 65536)
@@ -385,6 +390,59 @@ def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
 
 
 # --------------------------------------------------------------- stage 4
+
+def check_row_moves(tokens: int, top_k: int, experts: int, held: int,
+                    width: int) -> dict:
+    """The expert layer's two row moves (ops/pallas/row_move.py) against
+    XLA's gather on seeded bf16 rows, routed as a balanced router would:
+    ``held`` of ``experts`` experts' rows in a buffer of twice their mean
+    load. The rows out are ``x[index]`` bit for bit and zero past the rows
+    held; the rows back, each token's weighted sum in float32, at bf16
+    tolerance."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops.pallas.row_move import (
+        rows_from_tokens,
+        token_lists,
+        tokens_from_rows,
+    )
+
+    rng = np.random.default_rng(3)
+    slots = tokens * top_k
+    rows = (-(-2 * slots * held // experts // 256) + 1) * 256
+    chosen = jnp.asarray(np.argsort(rng.random((tokens, experts)))[:, :top_k]
+                         .reshape(slots), jnp.int32)
+    local = jnp.where(chosen < held, chosen, held)
+    by_expert = jnp.argsort(local, stable=True).astype(jnp.int32)
+    order = jnp.pad(by_expert, (0, max(0, rows - slots)))[:rows]
+    place = jnp.argsort(by_expert).reshape(tokens, top_k)
+    n_held = jnp.sum(chosen < held, dtype=jnp.int32)
+    require(int(n_held) < rows, f"{int(n_held)} rows held of {rows}")
+    x = jnp.asarray(rng.standard_normal((tokens, width)), jnp.bfloat16)
+    ys = jnp.asarray(rng.standard_normal((rows, width)), jnp.bfloat16)
+    w = jnp.where(place < n_held,
+                  jnp.asarray(rng.random((tokens, top_k)), jnp.float32), 0)
+
+    out = jax.jit(lambda x, o, n: rows_from_tokens(x, o // top_k, n))
+    back = jax.jit(lambda ys, o, n, w: tokens_from_rows(
+        ys, token_lists(o, n, tokens, top_k), w))
+    got = out(x, order, n_held)
+    want = jnp.where((jnp.arange(rows) < n_held)[:, None], x[order // top_k], 0)
+    require(bool((got == want).all()),
+            "rows_from_tokens differs from x[index] over the rows held")
+    got = back(ys, order, n_held, w)
+    want = jnp.einsum("nkd,nk->nd", ys[jnp.minimum(place, rows - 1)], w,
+                      preferred_element_type=jnp.float32)
+    err = rel_err(got, want)
+    require(np.isfinite(err) and err <= 1e-2,
+            f"tokens_from_rows vs the float32 sum: relative error {err:.3e}")
+    return {"slots": slots, "rows": rows, "rows_held": int(n_held),
+            "width": width, "rel_err_back": float(f"{err:.3e}"),
+            "mosaic_calls_lowered": mosaic_calls(out, x, order, n_held)
+            + mosaic_calls(back, ys, order, n_held, w)}
+
 
 def check_gram(shape, interpret=None) -> dict:
     import jax
@@ -730,10 +788,15 @@ def main() -> int:
                 f"one-chip step by {gap:.4f}")
     hybrid = run("hybrid_lm", stage_hybrid_lm, HYBRID_LM, HYBRID_SEQ,
                  HYBRID_BATCH, LM_STEPS)
-    # flash forward and backward, the grouped product and its transpose
+    # flash forward and backward (2); the grouped product into the hidden
+    # width and out of it, each one's transpose by rows and by weights (6);
+    # the row moves: rows out, rows back (2)
     require(hybrid["attention_impl"] == "flash"
-            and hybrid["mosaic_calls_lowered"] >= 4,
+            and hybrid["mosaic_calls_lowered"] >= 2 + 6 + 2,
             f"the hybrid LM's kernels did not engage compiled: {hybrid}")
+    moves = run("row_moves", check_row_moves, *ROW_MOVES)
+    require(moves["mosaic_calls_lowered"] == 2,  # rows out, rows back
+            f"the row moves did not run as compiled Mosaic calls: {moves}")
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
     require(fvd["mosaic_calls_lowered"] >= 2, "flash check ran interpreted")

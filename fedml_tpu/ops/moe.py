@@ -25,8 +25,31 @@ products over the held experts (the bundled megablox ``gmm``, whose grid
 ends with the last held row: work follows the rows held, not the buffer)
 make the SwiGLU; the rows go back by the inverse permutation and each token
 sums its k weighted rows. Gathers both ways, forward and backward (each
-permutation's transpose is the other's gather): no scatter-add runs. On
-one chip there is no exchange, and nothing stands in for the absent chips.
+permutation's transpose is the other's gather): no scatter-add runs.
+
+What moves the rows (PR 33): one Pallas kernel pair
+(``ops/pallas/row_move.py``, behind ``_take_rows``), rows out of tokens and
+tokens out of rows with the weighted sum folded in, each the other's
+transpose. They copy a row by one DMA from indices they are handed, do work
+by the rows held and read nothing for a stranger, so no array of ``N * k``
+rows exists, forward or backward (XLA:TPU ran such row gathers at a fifth of
+HBM's rate and the combine ran one over all ``N * k`` slots: PERF.md section
+6). What XLA still does here: the reshape that makes a row one slab, the
+backward's scale and dot of the rows moved, and scalar gathers (indices,
+weights, the count of rows placed). **The rule**, applied at trace time to
+what the code sees and to nothing else (``_row_move_impl``): the kernels
+where a row makes whole 128-lane 32-bit words in its dtype and the layer
+has at least ``row_move.MIN_ROWS`` tokens; XLA's gather (the same algorithm,
+its own fallback) at any other width, and under that many tokens, where a
+kernel's set-up buys nothing (a model's 8-token init, a toy layer).
+``fedml_moe_row_move_total{impl="pallas"|"xla", use="rows"|"tokens"}``
+reports the choice, once a call site a trace, for layers of ``MIN_ROWS``
+tokens and more. ``dropless_moe`` is jitted, so a model's equal layers, and
+the passes a remat step makes of each, share one trace and one lowering of
+the layer and of every kernel under it: set-up every start pays, cache or no
+cache.
+On one chip there is no exchange, and nothing stands in for the absent
+chips.
 """
 
 from __future__ import annotations
@@ -37,6 +60,9 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..core.telemetry import get_registry
+from .pallas import row_move
 
 
 def _rank_queue(onehot: jax.Array, capacity: int, offset=0.0):
@@ -186,20 +212,102 @@ def _gmm_tiling(rows: int, k: int, n: int) -> Tuple[int, int, int]:
     return min(tm, rows), min(tk, k), min(tn, n)
 
 
-@jax.custom_vjp
-def _take_rows(x: jax.Array, index: jax.Array, takers: jax.Array) -> jax.Array:
-    """``x[index]`` whose transpose is a gather too: ``takers[m]`` lists the
-    output rows that took source row ``m`` (every row the same number), so
-    the gradient of row ``m`` is the sum of theirs, and no scatter-add runs."""
-    return x[index]
+def _moves(order, place, n_held, rows: int, impl):
+    """What the two row moves of a buffer of ``rows`` rows go by. ``order``
+    (rows,): the slot (token * k + j) a buffer row belongs to; ``place``
+    (N, k): the row a slot reads back, a stranger's clamped to the spare
+    last row; ``held`` (N, k): the slots that have a row of their own in
+    this buffer; and, for the kernel, those slots listed in token order."""
+    N, k = place.shape
+    moves = {"order": order, "n_held": n_held,
+             "held": place < jnp.minimum(n_held, rows),
+             "place": jnp.minimum(place, rows - 1)}
+    if impl == "pallas":
+        moves["lists"] = row_move.token_lists(order, n_held, N, k)
+    return moves
 
 
-def _take_rows_fwd(x, index, takers):
-    return x[index], takers
+def _row_move_impl(x) -> Optional[str]:
+    """What moves the rows of a layer whose tokens are ``x`` (N, D), chosen
+    at trace time from what the code sees and nothing else: ``"pallas"``,
+    the kernels of ``ops/pallas/row_move.py``, where a row makes whole
+    128-lane words; ``"xla"``, XLA's gather, where it does not; None, the
+    same gather, under ``MIN_ROWS`` tokens (a model's 8-token init, a toy
+    layer), where a kernel's set-up buys nothing and there is nothing to
+    report."""
+    N, D = x.shape
+    if N < row_move.MIN_ROWS:
+        return None
+    return "pallas" if row_move.row_move_shapes_ok(D, x.dtype) else "xla"
 
 
-def _take_rows_bwd(takers, g):
-    return g[takers].sum(axis=1), None, None
+def _count(use: str, impl) -> None:
+    """``fedml_moe_row_move_total{impl, use}``: once a call site a trace,
+    as ``fedml_attention_dispatch_total`` is counted."""
+    if impl:
+        get_registry().counter("fedml_moe_row_move_total", impl=impl,
+                               use=use).inc()
+
+
+def _rows_of_tokens(impl, src, moves):
+    """Row r its token's row of ``src`` (N, D), bit for bit, over the rows
+    held; zero rows after: (rows, D)."""
+    _count("rows", impl)
+    index = moves["order"] // moves["place"].shape[1]
+    if impl == "pallas":
+        return row_move.rows_from_tokens(src, index, moves["n_held"])
+    held = (jnp.arange(index.shape[0]) < moves["n_held"])[:, None]
+    return jnp.where(held, src[index], 0)
+
+
+def _tokens_of_rows(impl, src, moves, w=None):
+    """Each token's ``sum_j w[n, j] * src[place[n, j]]`` over its held
+    slots (``w`` absent: ones), in float32, rounded once: ``src`` (rows, D)
+    -> (N, D)."""
+    _count("tokens", impl)
+    held = moves["held"]
+    w = held.astype(jnp.float32) if w is None else jnp.where(held, w, 0)
+    if impl == "pallas":
+        return row_move.tokens_from_rows(src, moves["lists"], w)
+    taken = jnp.where(held[..., None], src[moves["place"]], 0)
+    return jnp.einsum("nkd,nk->nd", taken, w,
+                      preferred_element_type=jnp.float32).astype(src.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _take_rows(use: str, impl, src, w, moves):
+    """The expert layer's two row moves (``moves``: ``_moves``; ``impl``:
+    ``_row_move_impl``), each the other's transpose, so that both run as
+    gathers forward and backward and no scatter-add runs. ``use ==
+    "rows"``: the tokens' rows (N, D) into the buffer, ``_rows_of_tokens``
+    (``w`` is None). ``use == "tokens"``: the buffer's rows back, each
+    token its k weighted: ``_tokens_of_rows``."""
+    return _take_rows_fwd(use, impl, src, w, moves)[0]
+
+
+def _take_rows_fwd(use, impl, src, w, moves):
+    if use == "rows":
+        return _rows_of_tokens(impl, src, moves), moves
+    return _tokens_of_rows(impl, src, moves, w), (src, w, moves)
+
+
+def _take_rows_bwd(use, impl, res, g):
+    # (the caller's scope does not reach a backward body: named here, so
+    # that the benchmark's readers find the shuffle's time both ways)
+    if use == "rows":
+        with jax.named_scope("moe.shuffle.dispatch"):
+            return _tokens_of_rows(impl, g, res), None, None
+    src, w, moves = res
+    with jax.named_scope("moe.shuffle.combine"):
+        # a row's cotangent is its token's, weighted; the weight's is the
+        # row against its token's cotangent, read back slot by slot (a
+        # stranger's slot reads the spare row, whatever lies there, and
+        # keeps none of it)
+        taken = _rows_of_tokens(impl, g, moves).astype(jnp.float32)
+        scale = w.reshape(-1)[moves["order"]][:, None]
+        dots = jnp.sum(taken * src.astype(jnp.float32), axis=-1)
+        g_w = jnp.where(moves["held"], dots[moves["place"]], 0)
+    return (taken * scale).astype(src.dtype), g_w.astype(w.dtype), None
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
@@ -222,6 +330,7 @@ def route_top_k(x, gate, bias, top_k: int):
                 w / (w.sum(axis=-1, keepdims=True) + 1e-6))
 
 
+@functools.partial(jax.jit, static_argnames=("top_k", "experts_held"))
 def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
                  experts_held: Tuple[int, int]):
     """The held experts' part of a routed SwiGLU layer. x: (N, D); gate: (D,
@@ -288,13 +397,16 @@ def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
     interpret = jax.default_backend() != "tpu"
     valid = (jnp.arange(rows) < n_held)[:, None]
     order = order[:rows]
-    place = jnp.minimum(place, rows - 1)  # strangers: a masked row
     with jax.named_scope("moe.shuffle.dispatch"):
+        impl = _row_move_impl(x)
+        moves = _moves(order, place.reshape(N, top_k), n_held, rows, impl)
+        place = moves["place"].reshape(-1)  # strangers: the spare last row
         placed = jnp.sum(is_held & valid[place, 0]
                          & (order[place] == jnp.arange(N * top_k)),
                          dtype=jnp.int32)
-        xs = _take_rows(x, order // top_k, place.reshape(N, top_k))
-        xs = jnp.where(valid, xs, 0)  # and keeps gmm's unwritten rows' gradient out
+        # rows past n_held come back zero, and their gradient (gmm leaves
+        # it unwritten) is never read
+        xs = _take_rows("rows", impl, x, None, moves)
     with jax.named_scope("moe.experts"):
         product = lambda a, b: gmm(  # noqa: E731
             a, b, group_sizes, a.dtype,
@@ -304,10 +416,7 @@ def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
             xs, w3.astype(x.dtype))
         ys = product(jnp.where(valid, act, 0), w2.astype(x.dtype))
     with jax.named_scope("moe.shuffle.combine"):
-        ys = jnp.where(valid, ys, 0)  # strangers' rows add nothing
-        back = _take_rows(ys, place, order[:, None])
-        out = jnp.einsum("nkd,nk->nd", back.reshape(N, top_k, D), w,
-                         preferred_element_type=jnp.float32).astype(x.dtype)
+        out = _take_rows("tokens", impl, ys, w, moves)
     return out, placed
 
 

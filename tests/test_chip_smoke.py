@@ -149,3 +149,10 @@ def test_facade_shrinks_the_client_mesh_only_where_the_engine_cannot_pad(
         TINY_FL, client_num_per_round=10, comm_round=1, backend="TPU",
         **extra))
     assert dict(SimulatorTPU(args).mesh.shape) == {"client": axis}
+
+
+def test_row_moves_check_tiny_interpret():
+    out = chip_smoke.check_row_moves(64, 4, 16, 4, 256)
+    assert out["slots"] == 256 and out["rows"] == 512
+    assert 0 < out["rows_held"] < out["rows"]
+    assert out["mosaic_calls_lowered"] == 0     # interpreted on the CPU

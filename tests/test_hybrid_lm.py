@@ -215,6 +215,66 @@ def test_one_compiled_program_whatever_the_seed_or_the_routing(layer):
     assert len(loads) == 3 and step._cache_size() == 1
 
 
+@pytest.mark.parametrize("tokens,width,impl", [
+    (256, 2048, "pallas"), (256, 2000, "xla"), (32, 2048, None)],
+    ids=["whole_words", "no_whole_words", "too_few_rows"])
+def test_the_row_moves_are_counted_by_what_the_shapes_allow(tokens, width, impl):
+    """``fedml_moe_row_move_total{impl, use}`` counts, at trace time, each
+    site where the layer moves rows: by the Pallas kernel at a width of
+    whole 128-lane words (the LFM2 cell's 2048), by XLA's gather at one
+    that is not; both uses, forward and backward; nothing else chooses.
+    Under ``MIN_ROWS`` tokens (a model's 8-token init) XLA's gather runs
+    and nothing is counted: no kernel could have paid there."""
+    registry = telemetry.get_registry()
+    count = lambda: {  # noqa: E731
+        (i, u): registry.counter("fedml_moe_row_move_total", impl=i, use=u).value
+        for i in ("pallas", "xla") for u in ("rows", "tokens")}
+    before = count()
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    jax.eval_shape(
+        jax.grad(lambda x, *a: moe.dropless_moe(
+            x, *a, top_k=4, experts_held=(4, 4))[0].sum()),
+        s(tokens, width).update(dtype=jnp.bfloat16), s(width, 16), s(16),
+        s(4, width, 48), s(4, width, 48), s(4, 48, width))
+    added = {key: after - before[key] for key, after in count().items()}
+    for (i, use), n in added.items():
+        assert (n > 0) == (i == impl), added
+
+
+def test_the_row_move_kernels_run_under_the_data_parallel_shard_map():
+    """512 tokens a device on a data axis of 2: each device's share takes
+    the kernels (interpreted here) inside ``RoutedExperts``' ``shard_map``,
+    and loss, counts and gradients equal the unsharded layer's to bf16's
+    order of summation."""
+    from jax.sharding import Mesh
+
+    from fedml_tpu.ops.moe import RoutedExperts
+    from fedml_tpu.parallel.mesh import AXIS_DATA
+
+    build = lambda mesh: RoutedExperts(  # noqa: E731
+        dim=256, width=64, num_experts=16, top_k=4, experts_held=(4, 8),
+        dtype=jnp.bfloat16, mesh=mesh)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal((2, 512, 256)),
+                    jnp.float32)
+    whole = build(None)
+    variables = whole.init(jax.random.PRNGKey(0), x)
+
+    def run(layer):
+        def loss(params):
+            out, stats = layer.apply({**variables, "params": params}, x)
+            return jnp.sum(out.astype(jnp.float32) ** 2), stats
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(
+            variables["params"])
+
+    (want, stats), grads = run(whole)
+    (got, stats_dp), grads_dp = run(
+        build(Mesh(np.array(jax.devices()[:2]), (AXIS_DATA,))))
+    assert stats_dp[:2].tolist() == stats[:2].tolist() and stats_dp[3] == 0
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    for g, g_dp in zip(jax.tree.leaves(grads), jax.tree.leaves(grads_dp)):
+        assert jnp.abs(g - g_dp).max() <= 2.0 ** -7 * jnp.abs(g).max()
+
+
 # --- the whole decoder, and the trainer that takes it ----------------------
 
 @pytest.fixture(scope="module")

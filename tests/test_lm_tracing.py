@@ -217,20 +217,22 @@ def test_fed_round_stages_are_scoped_and_its_host_phases_are_spans():
 
 def test_span_group_of_a_step_costs_under_100us_without_a_session():
     """Four spans and two counters, as one ``step()`` makes them: the best of
-    three thousands, so that a loaded machine does not decide it."""
+    twelve batches of 250, so that a loaded machine does not decide it (the
+    best of three thousands read 119 us once beside five busy workers, 38
+    to 65 alone)."""
     telemetry.configure(enabled=True, reset=True)
     tracer, reg = telemetry.get_tracer(), telemetry.get_registry()
     best = float("inf")
-    for _ in range(3):
+    for _ in range(12):
         tracer.clear()
         t0 = time.perf_counter()
-        for _ in range(1000):
+        for _ in range(250):
             with tracer.span("lm.step"):
                 for name in STEP_CHILDREN:
                     with tracer.span(name):
                         pass
             reg.counter("fedml_lm_steps_total").inc()
             reg.counter("fedml_lm_tokens_total").inc(8192)
-        best = min(best, (time.perf_counter() - t0) / 1000)
+        best = min(best, (time.perf_counter() - t0) / 250)
     telemetry.configure(enabled=True, reset=True)
     assert best < 100e-6, f"{best * 1e6:.1f} us a step"

@@ -61,3 +61,54 @@ def test_head_and_loss_compile_without_a_reduce_window(topo, tp, V):
     # the row max, the two sums and the hidden states' gradient cross the
     # chips; the logits never do
     assert ("all-reduce" in text) == (tp > 1) and "all-gather" not in text
+
+
+def test_the_expert_layer_compiles_with_no_slot_wide_array_and_no_row_gather(
+        topo, monkeypatch):
+    """The routed experts at the LFM2 cell's widths (D = 2048, 8 of 64
+    experts held, top-4), forward and backward, at a small N. Before PR 33
+    the combine gathered a row for every one of the N * k slots into an
+    (N * k, D) array, its backward wrote that array's cotangent, and XLA:TPU
+    ran such row gathers at a fifth of HBM's rate (PERF.md section 6). Now
+    the rows move inside ``ops/pallas/row_move.py``'s kernels: what XLA
+    still gathers is scalars (indices, weights), and every Mosaic call of
+    the layer names its scope, the shuffle's in the backward too: the
+    benchmark's ``moe_shuffle_ms`` and ``moe_ms`` find them by it."""
+    from fedml_tpu.ops.moe import dropless_moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, D, F, E, held, k = 1024, 2048, 1536, 64, 8, 4
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+    def loss(x, gate, bias, w1, w3, w2):
+        out, stats = dropless_moe(x, gate, bias, w1, w3, w2, top_k=k,
+                                  experts_held=(0, held))
+        return out.astype(jnp.float32).sum(), stats
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 3, 4, 5), has_aux=True)).lower(
+        sds((N, D), jnp.bfloat16), sds((D, E), jnp.float32),
+        sds((E,), jnp.float32), sds((held, D, F), jnp.float32),
+        sds((held, D, F), jnp.float32), sds((held, F, D), jnp.float32),
+    ).compile().as_text()
+    # (the larger of the two buffers has N * k + 256 rows, so N * k rows by
+    # D, in the layer's layout or the kernel's slabs, is only a slot's array)
+    assert f"[{N},{D}]" in text
+    assert not re.findall(rf"\[{N * k},{D}\]|\[{N},{k},{D}\]|\[{N * k},\d+,128\]",
+                          text)
+    gathers = re.findall(r"= (\S+) gather\(", text)
+    assert gathers and not [g for g in gathers if f"{D}]" in g or ",128]" in g]
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    shuffle = [name for name in kernels if re.search(
+        r"jit\(_(rows_from_tokens|tokens_from_rows)\)", name)]
+    # two buffer sizes x (the forward's two moves; the first of them again
+    # under that size's checkpoint, where the second's result is not needed;
+    # the backward's two); beside three grouped products each time and their
+    # three transposes by weights
+    assert len(shuffle) == 2 * 5 and len(kernels) == 2 * (5 + 12)
+    assert all("moe.shuffle." in name for name in shuffle)
+    assert sum("transpose(jvp" in name and "rematted" not in name
+               for name in shuffle) == 2 * 2
+    assert all("moe.experts" in name for name in kernels if name not in shuffle)
