@@ -15,7 +15,11 @@ One process, the normal entry points, no bench-only switch:
                rotary attention, dropless routed experts on a share of the
                experts: models/hybrid_lm.py) through the same trainer, and
                that layer's row-move kernels against x[index] at the LFM2
-               cell's shape (16,640 buffer rows for 65,536 slots, 2048 wide)
+               cell's shape (16,640 buffer rows for 65,536 slots, 2048 wide);
+               a second small decoder of one-mixer blocks (Mamba-2, attention
+               without positions, relu2 experts with a shared expert, an
+               untied head), and the chunked state-space scan against the
+               sequential one at the Nemotron cell's widths
 4. kernels   — every other pallas_call against its in-repo reference:
                fused_gram (alone at a 1000-row cohort, and inside
                fused_sanitize_krum on a flagship cohort of ResNet-56
@@ -63,6 +67,20 @@ HYBRID_LM = dict(
     moe_intermediate_size=256, num_experts=16, num_experts_per_tok=4,
     experts_held=(4, 8), num_attention_heads=8, num_key_value_heads=2)
 HYBRID_SEQ, HYBRID_BATCH = 1024, 2
+# a second small decoder, of one-mixer blocks: every kind of
+# models/hybrid_lm.py's MIXER_KINDS once and the Mamba-2 mixer twice, 8 of
+# 16 relu2 experts held, a shared expert, an untied head
+HYBRID_MIXERS = dict(
+    vocab_size=4096, hidden_size=512, num_dense_layers=0,
+    layer_types=("mamba", "moe", "mamba", "attention"), intermediate_size=256,
+    moe_intermediate_size=256, num_experts=16, num_experts_per_tok=6,
+    experts_held=(4, 8), num_attention_heads=8, num_key_value_heads=2,
+    mamba_num_heads=16, mamba_head_dim=64, ssm_state_size=128, n_groups=8,
+    chunk_size=128, moe_shared_expert_intermediate_size=512,
+    routed_scaling_factor=2.5, mlp_hidden_act="relu2",
+    tie_word_embeddings=False)
+# the Nemotron cell's scan: (batch, T, heads, head width, groups, state, chunk)
+SSD_SHAPE = (1, 8192, 64, 64, 8, 128, 128)
 # the LFM2 cell's expert layer: 16,384 tokens x top-4 over 64 experts of
 # which 8 are held, so 16,640 buffer rows for 65,536 slots; rows 2048 wide
 ROW_MOVES = (16384, 4, 64, 8, 2048)
@@ -304,10 +322,11 @@ def stage_lm(model: dict, seq: int, batch: int, steps: int,
 
 
 def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
-    """The layer_types decoder (short convolution, grouped rotary attention,
-    dropless routed experts on a share of the experts) through the trainer:
-    finite falling loss, no assignment dropped, and how many Mosaic calls
-    the step lowers to (flash forward and backward, the grouped products)."""
+    """A layer_types decoder (``HYBRID_LM``: short convolution, grouped
+    rotary attention, dropless routed experts on a share of the experts;
+    ``HYBRID_MIXERS``: the one-mixer blocks) through the trainer: finite
+    falling loss, no assignment dropped, and how many Mosaic calls the step
+    lowers to (flash forward and backward, the grouped products)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -341,8 +360,7 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
             f"hybrid LM loss not finite: {losses}")
     require(losses[-1] < losses[0], f"hybrid LM loss did not fall: {losses}")
     routed = registry.counter_total("fedml_moe_assignments_total") - before
-    expert_layers = len(cfg.layer_types) - cfg.num_dense_layers
-    require(routed == steps * expert_layers * batch * seq
+    require(routed == steps * cfg.expert_layers * batch * seq
             * cfg.num_experts_per_tok,
             f"the expert layers counted {routed} assignments")
     dropped = registry.counter_total("fedml_moe_dropped_total")
@@ -350,6 +368,79 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
     return {"attention_impl": impl, "mosaic_calls_lowered": n_mosaic,
             "batch": batch, "seq": seq, "loss": [round(v, 4) for v in losses],
             "assignments": int(routed), "memory": memory_stats()}
+
+
+def check_ssd_vs_sequential(batch: int, seq: int, heads: int, head_dim: int,
+                            groups: int, state: int, chunk: int) -> dict:
+    """The chunked state-space scan (ops/ssd.py) in bfloat16 against the
+    sequential recurrence in float32 (written here), on seeded inputs with the steps and
+    decays a seeded Mamba-2 mixer has (dt about 0.001 to 0.1, A in -16..-1),
+    values and the gradients of x, B and C.
+
+    Tolerance 2e-2 of the largest element: x, B, C, the masked scores and
+    the state handed to a chunk are each rounded to bfloat16 (2^-9 = 2e-3
+    an operand) before a product that sums 128 terms in float32, and a
+    position's output adds its own chunk's part and the carried state's;
+    measured 2.7e-3 (y, dx) and 3.8e-3 (dB, dC) on the chip at the cell's
+    widths (PERF.md section 6). A scan that dropped its carried state reads 0.3 and more."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from fedml_tpu.ops import ssd
+
+    rng = np.random.default_rng(5)
+    n = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    x, B, C = n(batch, seq, heads, head_dim), n(batch, seq, groups, state), n(
+        batch, seq, groups, state)
+    dt = jnp.exp(jnp.asarray(rng.uniform(
+        np.log(0.001), np.log(0.1), (batch, seq, heads)), jnp.float32))
+    A = -jnp.asarray(rng.uniform(1, 16, heads), jnp.float32)
+    D = jnp.ones(heads, jnp.float32)
+    w = n(batch, seq, heads, head_dim)
+    low = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
+
+    def recurrence(x, dt, A, B, C):
+        """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t; y_t = S_t C_t``,
+        one position at a time in float32; in blocks of ``chunk`` steps
+        under ``jax.checkpoint``, so that its backward pass keeps one state
+        a block (every state of 8,192 positions is 16 GB)."""
+        rep = heads // groups
+        by_block = [jnp.moveaxis(a, 1, 0).reshape(-1, chunk, *a.shape[:1],
+                                                  *a.shape[2:])
+                    for a in (x, dt, jnp.repeat(B, rep, 2), jnp.repeat(C, rep, 2))]
+
+        def step(S, at_t):
+            x_t, dt_t, B_t, C_t = at_t
+            S = (jnp.exp(dt_t * A)[..., None, None] * S
+                 + (dt_t[..., None] * x_t)[..., None] * B_t[:, :, None])
+            return S, jnp.sum(S * C_t[:, :, None], -1)
+
+        _, y = jax.lax.scan(
+            jax.checkpoint(lambda S, at: jax.lax.scan(step, S, at)),
+            jnp.zeros((batch, heads, head_dim, state), jnp.float32), by_block)
+        return jnp.moveaxis(y.reshape(-1, *y.shape[2:]), 0, 1)
+
+    def value_and_grads(scan, cast):
+        def loss(x, B, C):
+            y = scan(cast(x), dt, A, cast(B), cast(C)).astype(jnp.float32)
+            return jnp.sum(y * w), y
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            loss, (0, 1, 2), has_aux=True))(x, B, C)
+        return (y,) + grads
+
+    impl = ssd.scan_impl(seq, chunk)
+    got = value_and_grads(
+        lambda *a: ssd.ssd_scan(*a, D, chunk=chunk), low)
+    want = value_and_grads(
+        lambda x, dt, A, B, C: recurrence(x, dt, A, B, C) + x * D[:, None],
+        lambda a: a)
+    errs = {name: float(f"{rel_err(g, t):.3e}")
+            for name, g, t in zip(("y", "dx", "dB", "dC"), got, want)}
+    require(all(np.isfinite(e) and e <= 2e-2 for e in errs.values()),
+            f"chunked scan vs the sequential recurrence: {errs}")
+    return {"impl": impl, "shape": [batch, seq, heads, head_dim, groups, state],
+            "chunk": chunk, "rel_err": errs}
 
 
 def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
@@ -794,6 +885,15 @@ def main() -> int:
     require(hybrid["attention_impl"] == "flash"
             and hybrid["mosaic_calls_lowered"] >= 2 + 6 + 2,
             f"the hybrid LM's kernels did not engage compiled: {hybrid}")
+    mixers = run("hybrid_mixers", stage_hybrid_lm, HYBRID_MIXERS, HYBRID_SEQ,
+                 HYBRID_BATCH, LM_STEPS)
+    # flash forward and backward (2); the two grouped products of a relu2
+    # expert, each one's transpose by rows and by weights (6)
+    require(mixers["attention_impl"] == "flash"
+            and mixers["mosaic_calls_lowered"] >= 2 + 6,
+            f"the one-mixer decoder's kernels did not engage compiled: {mixers}")
+    scan = run("ssd_vs_sequential", check_ssd_vs_sequential, *SSD_SHAPE)
+    require(scan["impl"] == "chunked", f"the scan took {scan['impl']}")
     moves = run("row_moves", check_row_moves, *ROW_MOVES)
     require(moves["mosaic_calls_lowered"] == 2,  # rows out, rows back
             f"the row moves did not run as compiled Mosaic calls: {moves}")

@@ -1,23 +1,35 @@
 """A decoder built from a ``layer_types`` list: the hybrid LMs whose layers
-differ in kind by position.
+differ in kind by position. Two families of kinds, by what a layer holds.
 
-Every layer is ``h += Op(RMSNorm(h)); h += FF(RMSNorm(h))``. ``Op`` is, by
-``layer_types[i]``, a gated short convolution (``"conv"``, ops/short_conv.py)
-or causal grouped-query attention with per-head RMS-normalised, rotary q and
-k (``"full_attention"``, ops/rotary.py, ops/attention.py: the same dispatch
+**Two-part layers**, ``h += Op(RMSNorm(h)); h += FF(RMSNorm(h))`` (LFM2-MoE's).
+``Op`` is a gated short convolution (``"conv"``, ops/short_conv.py) or causal
+grouped-query attention with per-head RMS-normalised, rotary q and k
+(``"full_attention"``, ops/rotary.py, ops/attention.py: the same dispatch
 rule and flash kernels as ``TransformerLM``). ``FF`` is a dense SwiGLU in the
 first ``num_dense_layers`` layers and a dropless routed-expert layer after
 them (ops/moe.py ``RoutedExperts``: sigmoid scores, a selection bias,
 normalised top-k weights), which may hold a chip's share of the experts.
-No bias anywhere, no position table, the head tied to the embedding.
-``DecoderConfig`` carries the published key names of such a model's
-``config.json`` (LFM2-MoE's), so a configuration file maps onto it key by
-key.
+
+**One-mixer blocks**, ``h += Mixer(RMSNorm(h))`` (Nemotron-H's; the kinds are
+its ``layers_block_type`` names, and ``layer_types_of_pattern`` reads its
+``hybrid_override_pattern``). ``Mixer`` is a Mamba-2 mixer (``"mamba"``,
+ops/ssd.py: a chunked state-space scan between two projections), causal
+grouped-query attention with no position embedding and no QK-norm
+(``"attention"``), or an expert block (``"moe"``: the same ``RoutedExperts``
+with ``mlp_hidden_act``'s expert form, ``routed_scaling_factor`` and a
+shared expert ``moe_shared_expert_intermediate_size`` wide).
+
+No bias in any projection, no position table; the head is the embedding's
+transpose, or with ``tie_word_embeddings`` false a matrix of its own.
+``DecoderConfig`` carries the published key names of such models'
+``config.json`` (LFM2-MoE's, Nemotron-H's), so a configuration file maps
+onto it key by key.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple, Union
 
 import flax.linen as nn
@@ -26,7 +38,21 @@ import jax.numpy as jnp
 
 from ..ops.moe import MOE_STATS, RoutedExperts
 
-LAYER_KINDS = ("conv", "full_attention")
+TWO_PART_KINDS = ("conv", "full_attention")
+MIXER_KINDS = ("mamba", "attention", "moe")
+LAYER_KINDS = TWO_PART_KINDS + MIXER_KINDS
+# ``mlp_hidden_act`` -> an expert's form (ops/moe.py ``EXPERT_FORMS``)
+EXPERT_FORM_OF_ACT = {"silu": "swiglu", "relu2": "relu2"}
+PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
+
+
+def layer_types_of_pattern(pattern: str) -> Tuple[str, ...]:
+    """``hybrid_override_pattern`` (a letter a block) as ``layer_types``."""
+    unknown = sorted(set(pattern) - set(PATTERN_KINDS))
+    if unknown:
+        raise ValueError(f"hybrid_override_pattern holds {unknown}; this "
+                         f"decoder reads {sorted(PATTERN_KINDS)}")
+    return tuple(PATTERN_KINDS[letter] for letter in pattern)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +72,20 @@ class DecoderConfig:
     conv_L_cache: int = 3
     norm_eps: float = 1e-5
     rope_theta: float = 1e6
+    # the one-mixer blocks' (Nemotron-H's keys)
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    n_groups: int = 1
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    mlp_hidden_act: str = "silu"
+    tie_word_embeddings: bool = True
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -55,6 +95,16 @@ class DecoderConfig:
         if unknown:
             raise ValueError(f"layer_types holds {sorted(unknown)}; this "
                              f"decoder has {LAYER_KINDS}")
+        if self.mlp_hidden_act not in EXPERT_FORM_OF_ACT:
+            raise ValueError(f"mlp_hidden_act {self.mlp_hidden_act!r}; this "
+                             f"decoder has {sorted(EXPERT_FORM_OF_ACT)}")
+
+    @property
+    def expert_layers(self) -> int:
+        """The layers that hold routed experts."""
+        two_part = sum(kind in TWO_PART_KINDS for kind in self.layer_types)
+        return (max(0, two_part - self.num_dense_layers)
+                + self.layer_types.count("moe"))
 
 
 class RMSNorm(nn.Module):
@@ -95,7 +145,8 @@ class ShortConv(nn.Module):
 
 class GroupedQueryAttention(nn.Module):
     """Causal attention, ``kv_heads`` KV heads serving ``heads`` query
-    heads; q and k RMS-normalised per head, then rotary."""
+    heads; q and k RMS-normalised per head, then rotary, or with
+    ``positions`` false neither (no position embedding at all)."""
 
     dim: int
     heads: int
@@ -106,6 +157,7 @@ class GroupedQueryAttention(nn.Module):
     dtype: jnp.dtype = jnp.float32
     mesh: Optional[object] = None
     attn_impl: Optional[str] = None
+    positions: bool = True
 
     @nn.compact
     def __call__(self, u):
@@ -116,10 +168,11 @@ class GroupedQueryAttention(nn.Module):
         q = _dense(H * Dh, self.dtype, "q_proj")(u).reshape(B, T, H, Dh)
         k = _dense(Hkv * Dh, self.dtype, "k_proj")(u).reshape(B, T, Hkv, Dh)
         v = _dense(Hkv * Dh, self.dtype, "v_proj")(u).reshape(B, T, Hkv, Dh)
-        q = apply_rotary(RMSNorm(self.eps, self.dtype, name="q_norm")(q),
-                         self.rope_theta)
-        k = apply_rotary(RMSNorm(self.eps, self.dtype, name="k_norm")(k),
-                         self.rope_theta)
+        if self.positions:
+            q = apply_rotary(RMSNorm(self.eps, self.dtype, name="q_norm")(q),
+                             self.rope_theta)
+            k = apply_rotary(RMSNorm(self.eps, self.dtype, name="k_norm")(k),
+                             self.rope_theta)
         out = self._local_attention(q, k, v).reshape(B, T, H * Dh)
         return _dense(self.dim, self.dtype, "o_proj")(out)
 
@@ -144,8 +197,90 @@ class SwiGLU(nn.Module):
             gate * _dense(self.width, self.dtype, "w3")(u))
 
 
+def _dt_bias_init(c: DecoderConfig):
+    """``dt_bias`` such that ``softplus(dt_bias)`` is log-uniform over
+    [``time_step_min``, ``time_step_max``], floored at ``time_step_floor``."""
+    def init(key, shape, dtype=jnp.float32):
+        lo, hi = jnp.log(c.time_step_min), jnp.log(c.time_step_max)
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, dtype, lo, hi)),
+                         c.time_step_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus's inverse
+    return init
+
+
+class Mamba2Mixer(nn.Module):
+    """``mamba2_core(u W_in) W_out`` (ops/ssd.py): a causal convolution, the
+    state-space scan over ``mamba_num_heads`` heads of ``mamba_head_dim``
+    with a state ``ssm_state_size`` wide, and a gated group norm."""
+
+    cfg: DecoderConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.ssd import mamba2_core
+
+        c = self.cfg
+        heads = c.mamba_num_heads
+        inner = heads * c.mamba_head_dim
+        conv_dim = inner + 2 * c.n_groups * c.ssm_state_size
+        bound = c.conv_kernel ** -0.5  # torch's conv1d default
+        uniform = lambda lo, hi: (  # noqa: E731
+            lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+                key, shape, dtype, lo, hi))
+        param = lambda name, init, *shape: self.param(  # noqa: E731
+            name, init, shape, jnp.float32)
+        zxbcdt = _dense(inner + conv_dim + heads, self.dtype, "in_proj")(u)
+        y = mamba2_core(
+            zxbcdt, param("conv_weight", uniform(-bound, bound), conv_dim,
+                          c.conv_kernel),
+            param("conv_bias", uniform(-bound, bound), conv_dim),
+            param("dt_bias", _dt_bias_init(c), heads),
+            param("A_log", lambda *a: jnp.log(uniform(1.0, 16.0)(*a)), heads),
+            param("D", nn.initializers.ones, heads),
+            param("norm", nn.initializers.ones, inner),
+            heads=heads, head_dim=c.mamba_head_dim, state=c.ssm_state_size,
+            groups=c.n_groups, chunk=c.chunk_size, eps=c.norm_eps)
+        return _dense(c.hidden_size, self.dtype, "out_proj")(y)
+
+
+class MixerBlock(nn.Module):
+    """One block of kind ``kind``: ``h + Mixer(RMSNorm(h))``. Returns (h,
+    the expert block's stats or zeros)."""
+
+    cfg: DecoderConfig
+    kind: str
+    dtype: jnp.dtype = jnp.float32
+    mesh: Optional[object] = None
+    attn_impl: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, h):
+        c = self.cfg
+        u = RMSNorm(c.norm_eps, self.dtype, name="norm")(h)
+        stats = jnp.zeros(len(MOE_STATS), jnp.int32)
+        if self.kind == "mamba":
+            out = Mamba2Mixer(c, self.dtype, name="mamba")(u)
+        elif self.kind == "attention":
+            out = GroupedQueryAttention(
+                c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
+                c.head_dim or c.hidden_size // c.num_attention_heads,
+                c.rope_theta, c.norm_eps, self.dtype, mesh=self.mesh,
+                attn_impl=self.attn_impl, positions=False, name="attn")(u)
+        else:
+            out, stats = RoutedExperts(
+                c.hidden_size, c.moe_intermediate_size, c.num_experts,
+                c.num_experts_per_tok, experts_held=c.experts_held,
+                dtype=self.dtype, mesh=self.mesh,
+                form=EXPERT_FORM_OF_ACT[c.mlp_hidden_act],
+                scale=c.routed_scaling_factor,
+                shared_width=c.moe_shared_expert_intermediate_size,
+                name="moe")(u)
+        return h + out, stats
+
+
 class DecoderLayer(nn.Module):
-    """One layer of kind ``kind``; ``dense`` picks its feed-forward.
+    """One two-part layer of kind ``kind``; ``dense`` picks its feed-forward.
     Returns (h, the expert layer's stats or zeros)."""
 
     cfg: DecoderConfig
@@ -203,41 +338,54 @@ class HybridLM(nn.Module):
                          name="embed")
         h = embed(tokens)
         if self.remat == "dots":
-            layer_cls = nn.remat(
-                DecoderLayer, policy=jax.checkpoint_policies.checkpoint_dots)
+            wrap = functools.partial(
+                nn.remat, policy=jax.checkpoint_policies.checkpoint_dots)
         elif self.remat in (True, "full"):
-            layer_cls = nn.remat(DecoderLayer)
+            wrap = nn.remat
         elif not self.remat:
-            layer_cls = DecoderLayer
+            wrap = lambda cls: cls  # noqa: E731
         else:
             raise ValueError(
                 f"unknown remat policy {self.remat!r}; use False, True, "
                 "'full', or 'dots'")
+        layer_cls, block_cls = wrap(DecoderLayer), wrap(MixerBlock)
         stats = jnp.zeros(len(MOE_STATS), jnp.int32)
         peak = MOE_STATS.index("held_load_max")
+        two_part = 0
         for i, kind in enumerate(c.layer_types):
-            h, s = layer_cls(c, kind, i < c.num_dense_layers, self.dtype,
-                             mesh=self.mesh, attn_impl=self.attn_impl,
-                             name=f"layer_{i}")(h)
+            kw = dict(mesh=self.mesh, attn_impl=self.attn_impl,
+                      name=f"layer_{i}")
+            if kind in MIXER_KINDS:
+                h, s = block_cls(c, kind, self.dtype, **kw)(h)
+            else:
+                h, s = layer_cls(c, kind, two_part < c.num_dense_layers,
+                                 self.dtype, **kw)(h)
+                two_part += 1
             stats = (stats + s).at[peak].set(jnp.maximum(stats[peak], s[peak]))
         h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
         if not return_hidden:
+            untied = None if c.tie_word_embeddings else self.param(
+                "lm_head", nn.initializers.normal(0.02),
+                (c.hidden_size, c.vocab_size), jnp.float32)
             with jax.named_scope("head"):
                 # as one (B T, D) x (D, V) product: XLA:TPU then keeps the
                 # vocabulary the logits' minor axis, which the loss reduces
                 # over ("btd,vd->btv" made T minor at T = V = 8192; head and
                 # loss alone ran 65.9 ms against 58.9: PERF.md section 6)
                 B, T = tokens.shape
-                h = jnp.dot(h.reshape(B * T, c.hidden_size),
-                            embed.embedding.astype(self.dtype).T,
-                            preferred_element_type=jnp.float32
+                rows = h.reshape(B * T, c.hidden_size)
+                head = (embed.embedding.astype(self.dtype).T if untied is None
+                        else untied.astype(self.dtype))
+                h = jnp.dot(rows, head, preferred_element_type=jnp.float32
                             ).reshape(B, T, c.vocab_size)
         return (h, stats) if return_stats else h
 
     @staticmethod
     def head_kernel(params):
-        """The tied output head, (D, V), from the parameter tree."""
-        return params["params"]["embed"]["embedding"].T
+        """The output head, (D, V), from the parameter tree: a matrix of
+        its own, or the embedding's transpose where the two are tied."""
+        p = params["params"]
+        return p["lm_head"] if "lm_head" in p else p["embed"]["embedding"].T
 
     def count_step_stats(self, registry, stats: dict, dp: int = 1) -> None:
         """A step's ``STEP_STATS`` into the registry: assignments held here
@@ -251,7 +399,7 @@ class HybridLM(nn.Module):
             stats["total"] - stats["held"])
         counter("fedml_moe_dropped_total").inc(stats["dropped"])
         held = (c.experts_held or (0, c.num_experts))[1]
-        layers = len(c.layer_types) - c.num_dense_layers
         if stats["held"]:
             registry.gauge("fedml_moe_held_load_max_over_mean").set(
-                stats["held_load_max"] * held * layers * dp / stats["held"])
+                stats["held_load_max"] * held * c.expert_layers * dp
+                / stats["held"])

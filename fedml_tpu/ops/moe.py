@@ -20,11 +20,16 @@ over all ``num_experts``; the layer is told which experts it holds,
 what expert parallelism asks of it. The ``N * k`` assignments are sorted by
 expert, strangers last; the tokens' rows are gathered in that order into a
 buffer (of ``N * k`` rows where it must be: every assignment may land here;
-of twice a uniform router's mean load where that holds them); three grouped
+of twice a uniform router's mean load where that holds them); grouped
 products over the held experts (the bundled megablox ``gmm``, whose grid
 ends with the last held row: work follows the rows held, not the buffer)
-make the SwiGLU; the rows go back by the inverse permutation and each token
-sums its k weighted rows. Gathers both ways, forward and backward (each
+make the expert, in one of two forms: the gated ``W2 (silu(W1 x) * W3 x)``
+(three products) or ``W2 relu(W1 x)^2`` (two, no ``W3``); the rows go back
+by the inverse permutation and each token sums its k rows, weighted by its
+normalised scores times the layer's scaling factor. ``RoutedExperts`` may
+add a shared expert of the same form that every token passes through,
+whole on every chip (scope ``shared_expert``): across the shares of a layer
+it counts once. Gathers both ways, forward and backward (each
 permutation's transpose is the other's gather): no scatter-add runs.
 
 What moves the rows (PR 33): one Pallas kernel pair
@@ -201,6 +206,12 @@ def expert_param_shardings(mesh, params):
 MOE_STATS = ("held", "total", "held_load_max", "dropped")
 # gmm's (rows, k, n) tile: see ``_gmm_tiling``
 GMM_TILE = (256, 2048, 768)
+# (k, n) -> the tile at widths that are no multiples of ``GMM_TILE``, fitted on
+# the chip over the product and its two transposes, which megablox hands the
+# same tile (PERF.md section 6 has the table of what was tried): as deep as k
+# rounded up to whole lanes, so that an expert's weight block stays in VMEM,
+# and 384 columns, which divides 2688 and pads 1856 by 3%
+GMM_TILE_AT = {(2688, 1856): (256, 2688, 384), (1856, 2688): (256, 1920, 384)}
 # the usual row buffer, over the mean load of a uniform router
 BUFFER_OVER_MEAN = 2
 
@@ -208,6 +219,9 @@ BUFFER_OVER_MEAN = 2
 def _gmm_tiling(rows: int, k: int, n: int) -> Tuple[int, int, int]:
     """The grouped product's tile, cut to the problem: a tile as deep as k
     keeps an expert's weight block in VMEM while its rows stream past."""
+    if (k, n) in GMM_TILE_AT:  # whole: its k tile may pad k up to whole lanes
+        tm, tk, tn = GMM_TILE_AT[(k, n)]
+        return min(tm, rows), tk, tn
     tm, tk, tn = GMM_TILE
     return min(tm, rows), min(tk, k), min(tn, n)
 
@@ -330,13 +344,16 @@ def route_top_k(x, gate, bias, top_k: int):
                 w / (w.sum(axis=-1, keepdims=True) + 1e-6))
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "experts_held"))
+@functools.partial(jax.jit,
+                   static_argnames=("top_k", "experts_held", "scale"))
 def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
-                 experts_held: Tuple[int, int]):
-    """The held experts' part of a routed SwiGLU layer. x: (N, D); gate: (D,
+                 experts_held: Tuple[int, int], scale: float = 1.0):
+    """The held experts' part of a routed expert layer. x: (N, D); gate: (D,
     E) over all E experts; bias: (E,); w1, w3: (held, D, F); w2: (held, F,
-    D), the experts ``offset .. offset + held - 1``. Returns ((N, D), stats
-    (4,) int32 as MOE_STATS names them).
+    D), the experts ``offset .. offset + held - 1``. An expert is ``W2
+    (silu(W1 x) * W3 x)``, or with ``w3`` None ``W2 relu(W1 x)^2``; a
+    token's k rows are weighed by its normalised scores times ``scale``.
+    Returns ((N, D), stats (4,) int32 as MOE_STATS names them).
 
     The row buffer has a static size, and gathers and elementwise passes
     cost by the buffer, not by the rows in it. So there are two sizes in
@@ -348,6 +365,8 @@ def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
     N, D = x.shape
     offset, count = experts_held
     chosen, w = route_top_k(x, gate, bias, top_k)
+    if scale != 1.0:
+        w = w * scale
     A = N * top_k
     tm = _gmm_tiling(A, D, D)[0]
     # whole row tiles, and one spare: a stranger's clamped row index then
@@ -385,9 +404,10 @@ def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
 
 def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
                n_held, top_k: int, rows: int):
-    """The held experts' SwiGLU over a buffer of ``rows`` rows (at least
-    ``n_held + 1``): the tokens' rows gathered in expert order, the three
-    grouped products, the rows taken back and each token's k weighted.
+    """The held experts over a buffer of ``rows`` rows (at least ``n_held +
+    1``): the tokens' rows gathered in expert order, the grouped products
+    (three, or two with ``w3`` None), the rows taken back and each token's
+    k weighted.
     Returns ((N, D), the count of held assignments that found, at the row
     they read back, a computed row of their own: ``n_held`` unless the
     buffer was too small for them)."""
@@ -410,22 +430,31 @@ def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
     with jax.named_scope("moe.experts"):
         product = lambda a, b: gmm(  # noqa: E731
             a, b, group_sizes, a.dtype,
-            _gmm_tiling(rows, b.shape[1], b.shape[2]), None, None, False,
-            interpret)
-        act = jax.nn.silu(product(xs, w1.astype(x.dtype))) * product(
-            xs, w3.astype(x.dtype))
+            _gmm_tiling(min(rows, N * top_k), b.shape[1], b.shape[2]), None,
+            None, False, interpret)  # (the row tile the buffer was cut to)
+        up = product(xs, w1.astype(x.dtype))
+        act = (jnp.square(jax.nn.relu(up)) if w3 is None
+               else jax.nn.silu(up) * product(xs, w3.astype(x.dtype)))
         ys = product(jnp.where(valid, act, 0), w2.astype(x.dtype))
     with jax.named_scope("moe.shuffle.combine"):
         out = _take_rows("tokens", impl, ys, w, moves)
     return out, placed
 
 
+EXPERT_FORMS = ("swiglu", "relu2")
+
+
 class RoutedExperts(nn.Module):
     """A chip's share of a dropless top-k expert layer (``dropless_moe``).
     (B, T, D) -> ((B, T, D), stats (4,) int32). ``num_experts`` is the
     router's width; ``experts_held = (offset, count)`` the experts whose
-    weights live here (all of them by default). The selection bias lives in
-    the collection ``buffers``, outside the optimizer: the layer reads it
+    weights live here (all of them by default). ``form`` is an expert's:
+    ``"swiglu"`` (``w1``, ``w3``, ``w2``) or ``"relu2"`` (``w1``, ``w2``);
+    ``scale`` multiplies the routed sum; ``shared_width`` > 0 adds a shared
+    expert of the same form and that width, which every token passes
+    through: computed here whole, whatever the share (scope
+    ``shared_expert``). The selection bias lives in the
+    collection ``buffers``, outside the optimizer: the layer reads it
     and never moves it (how it is balanced is the training recipe's).
     Under a mesh with a data axis each device routes its own rows (the
     grouped product is a Mosaic kernel GSPMD cannot partition): counts are
@@ -438,6 +467,9 @@ class RoutedExperts(nn.Module):
     experts_held: Optional[Tuple[int, int]] = None
     dtype: jnp.dtype = jnp.float32
     mesh: Optional[object] = None
+    form: str = "swiglu"
+    scale: float = 1.0
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -447,18 +479,24 @@ class RoutedExperts(nn.Module):
             raise ValueError(
                 f"experts_held {(offset, count)} lies outside the router's "
                 f"{self.num_experts} experts")
+        if self.form not in EXPERT_FORMS:
+            raise ValueError(f"an expert's form is one of {EXPERT_FORMS}, "
+                             f"not {self.form!r}")
+        gated = self.form == "swiglu"
         init = nn.initializers.normal(0.02)
         gate = self.param("gate", init, (D, self.num_experts), jnp.float32)
         bias = self.variable("buffers", "expert_bias", jnp.zeros,
                              (self.num_experts,), jnp.float32).value
         w1 = self.param("w1", init, (count, D, self.width), jnp.float32)
-        w3 = self.param("w3", init, (count, D, self.width), jnp.float32)
+        w3 = (self.param("w3", init, (count, D, self.width), jnp.float32)
+              if gated else None)
         w2 = self.param("w2", init, (count, self.width, D), jnp.float32)
 
         def held_part(x, gate, bias, w1, w3, w2, data_axis=None):
             out, stats = dropless_moe(
                 x.reshape(-1, D).astype(self.dtype), gate, bias, w1, w3, w2,
-                top_k=self.top_k, experts_held=(offset, count))
+                top_k=self.top_k, experts_held=(offset, count),
+                scale=self.scale)
             if data_axis:
                 sums = jax.lax.psum(stats, data_axis)
                 stats = sums.at[2].set(jax.lax.pmax(stats[2], data_axis))
@@ -477,4 +515,27 @@ class RoutedExperts(nn.Module):
                 functools.partial(held_part, data_axis=AXIS_DATA), mesh=mesh,
                 in_specs=(P(AXIS_DATA), rep, rep, rep, rep, rep),
                 out_specs=(P(AXIS_DATA), rep), check_vma=False)
-        return held_part(x, gate, bias, w1, w3, w2)
+        if not self.shared_width:
+            return held_part(x, gate, bias, w1, w3, w2)
+        # (plain matmuls that GSPMD partitions by itself, outside the routed
+        # part's ``shard_map``; not a method of the module: flax would put
+        # ``moe._shared_expert`` in every op's name, and the routed experts'
+        # scopes are found by ``moe.``)
+        shared = lambda name, *shape: self.param(  # noqa: E731
+            "shared_" + name, init, shape, jnp.float32).astype(self.dtype)
+        D, F = x.shape[-1], self.shared_width
+        always = _shared_expert(
+            x.astype(self.dtype), shared("w1", D, F),
+            shared("w3", D, F) if gated else None, shared("w2", F, D))
+        out, stats = held_part(x, gate, bias, w1, w3, w2)
+        return always + out, stats
+
+
+def _shared_expert(x, w1, w3, w2):
+    """The expert every token passes through, of the routed experts' form
+    (``w3`` None: ``relu²``)."""
+    with jax.named_scope("shared_expert"):
+        up = x @ w1
+        act = (jnp.square(jax.nn.relu(up)) if w3 is None
+               else jax.nn.silu(up) * (x @ w3))
+        return act @ w2

@@ -16,16 +16,21 @@ import jax
 import jax.numpy as jnp
 
 
+def causal_taps(z: jax.Array, taps: jax.Array) -> jax.Array:
+    """``y_t = sum_j taps[:, j] * z_{t-j}``, ``z_{<0} = 0``: a depthwise
+    causal convolution as shifted adds. z: (B, T, D); taps: (D, L)."""
+    T = z.shape[1]
+    taps = taps.astype(z.dtype)
+    y = z * taps[:, 0]
+    for j in range(1, taps.shape[1]):
+        back = jnp.pad(z, ((0, 0), (j, 0), (0, 0)))[:, :T]
+        y = y + back * taps[:, j]
+    return y
+
+
 def gated_short_conv(bcx: jax.Array, taps: jax.Array) -> jax.Array:
     """bcx: (B, T, 3D), the input projection; taps: (D, L), tap ``j`` weighs
     the position ``j`` back. Returns ``c * conv(b * x)``, (B, T, D)."""
     with jax.named_scope("short_conv.core"):
-        T = bcx.shape[1]
         b, c, x = jnp.split(bcx, 3, axis=-1)
-        z = b * x
-        taps = taps.astype(z.dtype)
-        y = z * taps[:, 0]
-        for j in range(1, taps.shape[1]):
-            back = jnp.pad(z, ((0, 0), (j, 0), (0, 0)))[:, :T]
-            y = y + back * taps[:, j]
-        return c * y
+        return c * causal_taps(b * x, taps)

@@ -63,6 +63,20 @@ def test_stage_hybrid_lm_tiny():
     assert out["assignments"] == 3 * 2 * 2 * 16 * 4
 
 
+def test_stage_hybrid_mixers_and_the_scan_check_tiny():
+    tiny = dict(chip_smoke.HYBRID_MIXERS, vocab_size=64, hidden_size=32,
+                moe_intermediate_size=16, num_attention_heads=4,
+                mamba_num_heads=8, mamba_head_dim=4, ssm_state_size=8,
+                n_groups=2, chunk_size=8, moe_shared_expert_intermediate_size=24)
+    out = chip_smoke.stage_hybrid_lm(tiny, seq=16, batch=2, steps=3)
+    assert out["attention_impl"] == "dense" and out["mosaic_calls_lowered"] == 0
+    assert out["assignments"] == 3 * 1 * 2 * 16 * 6  # one expert block
+    scan = chip_smoke.check_ssd_vs_sequential(2, 32, 8, 4, 2, 8, 8)
+    assert scan["impl"] == "chunked" and max(scan["rel_err"].values()) <= 2e-2
+    with pytest.raises(ValueError, match="multiple of the chunk"):
+        chip_smoke.check_ssd_vs_sequential(2, 36, 8, 4, 2, 8, 8)
+
+
 def test_kernel_checks_tiny_interpret(monkeypatch):
     from jax.experimental import pallas as pl
 

@@ -2,7 +2,9 @@
 brought (gated short convolution, rotary + QK-norm + grouped KV heads,
 dropless routed experts), each against a plain float32 reference on seeded
 weights at a tiny size; the share test that ties a chip's share of an expert
-layer to the whole layer; and the trainer that now takes a model.
+layer to the whole layer; and the trainer that now takes a model. The
+one-mixer blocks are in ``tests/test_hybrid_mixers.py`` (another worker's
+file: this one is the suite's longest).
 
 The plain reference is the benchmark's (``benchmark/reference/lfm2.py``,
 which imports nothing of the program), found as its runner finds it; the
@@ -31,7 +33,10 @@ from reference import lfm2 as ref  # noqa: E402
 from runners import lfm2_step as runner  # noqa: E402
 
 from fedml_tpu.core import telemetry  # noqa: E402
-from fedml_tpu.models.hybrid_lm import HybridLM  # noqa: E402
+from fedml_tpu.models.hybrid_lm import (  # noqa: E402
+    HybridLM,
+    layer_types_of_pattern,
+)
 from fedml_tpu.ops import moe  # noqa: E402
 from fedml_tpu.ops.attention import multihead_attention  # noqa: E402
 from fedml_tpu.ops.rotary import apply_rotary, rms_norm  # noqa: E402
@@ -492,3 +497,43 @@ def test_chunked_cross_entropy_reads_the_tied_head(seeded, stepped):
     b = tokens(10)
     np.testing.assert_allclose(t.step(b[:, :-1], b[:, 1:]), stepped[1][0][0],
                                rtol=2e-6)
+
+
+def test_lfm2_tree_is_unchanged_by_the_new_kinds():
+    """The two-part layers keep their leaves, name for name: what
+    ``runners/lfm2_step.py`` maps onto and its cell's compiled step holds."""
+    cfg = runner.decoder_config(TINY)
+    assert cfg.tie_word_embeddings and cfg.expert_layers == 4
+    shapes = jax.eval_shape(HybridLM(cfg).init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 8), jnp.int32))
+    paths = lambda tree: sorted(  # noqa: E731
+        "/".join(str(k.key) for k in path)
+        for path, _ in jax.tree_util.tree_leaves_with_path(tree))
+    conv = ["conv/in_proj/kernel", "conv/out_proj/kernel", "conv/taps"]
+    attn = [f"attn/{n}" for n in (
+        "k_norm/scale", "k_proj/kernel", "o_proj/kernel", "q_norm/scale",
+        "q_proj/kernel", "v_proj/kernel")]
+    norms = ["ffn_norm/scale", "operator_norm/scale"]
+    dense = [f"mlp/{n}/kernel" for n in ("w1", "w2", "w3")]
+    moe = [f"moe/{n}" for n in ("gate", "w1", "w2", "w3")]
+    want = ["embed/embedding", "final_norm/scale"]
+    for i, kind in enumerate(TINY["layer_types"]):
+        want += [f"layer_{i}/{leaf}" for leaf in
+                 (conv if kind == "conv" else attn) + norms
+                 + (dense if i < TINY["num_dense_layers"] else moe)]
+    assert paths(shapes["params"]) == sorted(want)
+    assert paths(shapes["buffers"]) == [
+        f"layer_{i}/moe/expert_bias" for i in range(2, 6)]
+
+
+def test_kinds_and_pattern_letters_are_refused_by_name():
+    import dataclasses
+
+    cfg = runner.decoder_config(TINY)
+    with pytest.raises(ValueError, match=r"\['sliding_attention'\].*mamba"):
+        dataclasses.replace(cfg, layer_types=("conv", "sliding_attention"))
+    with pytest.raises(ValueError, match="gelu"):
+        dataclasses.replace(cfg, mlp_hidden_act="gelu")
+    with pytest.raises(ValueError, match=r"\['-'\]"):
+        layer_types_of_pattern("ME-*")
+    assert layer_types_of_pattern("ME*") == ("mamba", "moe", "attention")

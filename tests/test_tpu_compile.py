@@ -112,3 +112,77 @@ def test_the_expert_layer_compiles_with_no_slot_wide_array_and_no_row_gather(
     assert sum("transpose(jvp" in name and "rematted" not in name
                for name in shuffle) == 2 * 2
     assert all("moe.experts" in name for name in kernels if name not in shuffle)
+
+
+def test_the_relu2_expert_block_compiles_at_widths_that_are_no_tile_multiples(
+        topo, monkeypatch):
+    """The routed experts at the Nemotron cell's widths (D = 2688, F = 1856 =
+    14.5 x 128, 8 of 128 experts held, top-6, no ``w3``), forward and
+    backward, at a small N. megablox hands one tile to the product, to its
+    transpose by rows (where k and n change places) and to its transpose by
+    weights: a k or n tile that is neither a multiple of 128 nor that
+    dimension whole in all three is refused by Mosaic only here, at the
+    backward's compile (PERF.md section 6, PR 34). A bf16 row of 2688 is
+    10.5 words of 128 lanes, so XLA's gather moves the rows: no row-move
+    kernel, and every Mosaic call is a grouped product under its scope."""
+    from fedml_tpu.ops.moe import GMM_TILE_AT, _gmm_tiling, dropless_moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    N, D, F, E, held, k = 1024, 2688, 1856, 128, 8, 6
+    assert {(D, F), (F, D)} <= set(GMM_TILE_AT)
+    for shape in ((D, F), (F, D)):
+        tile = _gmm_tiling(4096, *shape)
+        assert tile[0] == 256 and all(t % 128 == 0 for t in tile[1:]), tile
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+
+    def loss(x, gate, bias, w1, w2):
+        out, stats = dropless_moe(x, gate, bias, w1, None, w2, top_k=k,
+                                  experts_held=(0, held), scale=2.5)
+        return out.astype(jnp.float32).sum(), stats
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, (0, 1, 3, 4), has_aux=True)).lower(
+        sds((N, D), jnp.bfloat16), sds((D, E), jnp.float32),
+        sds((E,), jnp.float32), sds((held, D, F), jnp.float32),
+        sds((held, F, D), jnp.float32)).compile().as_text()
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines() if "tpu_custom_call" in line]
+    # two buffer sizes x (two products, both again under that size's
+    # checkpoint, and each one's two transposes)
+    assert len(kernels) == 2 * (2 + 2 + 4)
+    assert all("moe.experts" in name for name in kernels)
+    assert not [name for name in kernels if "rows_from_tokens" in name
+                or "tokens_from_rows" in name]
+
+
+def test_the_mamba2_core_compiles_with_its_scopes_forward_and_backward(topo):
+    """``ops/ssd.py mamba2_core`` at the Nemotron cell's widths (64 heads x
+    64, state 128, 8 groups, chunk 128) over 1,024 positions, forward and
+    backward: the chunked scan is plain ``jax.numpy`` that XLA:TPU compiles
+    (no Mosaic call), and the three scopes name its ops in both passes, which
+    is what ``ssd_ms`` and ``ssd_roofline`` read."""
+    from fedml_tpu.ops.ssd import mamba2_core
+
+    heads, head_dim, state, groups, T = 64, 64, 128, 8, 1024
+    inner, conv_dim = heads * head_dim, heads * head_dim + 2 * groups * state
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+    sds = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+
+    def loss(*args):
+        return mamba2_core(*args, heads=heads, head_dim=head_dim, state=state,
+                           groups=groups, chunk=128, eps=1e-5
+                           ).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+        sds(1, T, inner + conv_dim + heads, dtype=jnp.bfloat16),
+        sds(conv_dim, 4), sds(conv_dim), sds(heads), sds(heads), sds(heads),
+        sds(inner)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("ssd.conv", "ssd.core", "ssd.gate_norm"):
+        assert [n for n in names if scope in n and "transpose(" in n], scope
+        assert [n for n in names if scope in n and "transpose(" not in n], scope
+    # the carried states: one loop over the 8 chunks each way, under the scope
+    assert [n for n in names if "ssd.core" in n and "while" in n]
