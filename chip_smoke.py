@@ -372,17 +372,24 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
 
 def check_ssd_vs_sequential(batch: int, seq: int, heads: int, head_dim: int,
                             groups: int, state: int, chunk: int) -> dict:
-    """The chunked state-space scan (ops/ssd.py) in bfloat16 against the
-    sequential recurrence in float32 (written here), on seeded inputs with the steps and
-    decays a seeded Mamba-2 mixer has (dt about 0.001 to 0.1, A in -16..-1),
-    values and the gradients of x, B and C.
+    """The state-space scan (ops/ssd.py) in bfloat16, as ``ssd_scan``
+    dispatches it (the kernel pair of ops/pallas/ssd.py at the Nemotron
+    cell's widths, XLA's chunked form at widths that fill no tile), against
+    the sequential recurrence in float32 (written here), on seeded inputs
+    with the steps and decays a seeded Mamba-2 mixer has (dt about 0.001 to
+    0.1, A in -16..-1): values and the gradient of every input. Where the
+    kernel was taken, XLA's chunked form is held to the same recurrence
+    beside it, and both are timed (ms a call on the host's clock, the median
+    of five: forward, and forward + backward).
 
     Tolerance 2e-2 of the largest element: x, B, C, the masked scores and
     the state handed to a chunk are each rounded to bfloat16 (2^-9 = 2e-3
     an operand) before a product that sums 128 terms in float32, and a
     position's output adds its own chunk's part and the carried state's;
-    measured 2.7e-3 (y, dx) and 3.8e-3 (dB, dC) on the chip at the cell's
-    widths (PERF.md section 6). A scan that dropped its carried state reads 0.3 and more."""
+    measured on the chip at the cell's widths (PERF.md section 6, PR 35),
+    the kernels | XLA's chunked form: y 3.2e-3 | 2.7e-3, dx 3.0e-3 | 2.9e-3,
+    ddt 3.7e-3 | 4.0e-3, dA 5.3e-3 | 5.3e-3, dB and dC 4.1e-3 | 4.1e-3, dD
+    2.3e-3 | 2.3e-3. A scan that dropped its carried state reads 0.3 and more."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -400,11 +407,11 @@ def check_ssd_vs_sequential(batch: int, seq: int, heads: int, head_dim: int,
     w = n(batch, seq, heads, head_dim)
     low = lambda a: a.astype(jnp.bfloat16)  # noqa: E731
 
-    def recurrence(x, dt, A, B, C):
-        """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t; y_t = S_t C_t``,
-        one position at a time in float32; in blocks of ``chunk`` steps
-        under ``jax.checkpoint``, so that its backward pass keeps one state
-        a block (every state of 8,192 positions is 16 GB)."""
+    def recurrence(x, dt, A, B, C, D):
+        """``S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t; y_t = S_t C_t +
+        D x_t``, one position at a time in float32; in blocks of ``chunk``
+        steps under ``jax.checkpoint``, so that its backward pass keeps one
+        state a block (every state of 8,192 positions is 16 GB)."""
         rep = heads // groups
         by_block = [jnp.moveaxis(a, 1, 0).reshape(-1, chunk, *a.shape[:1],
                                                   *a.shape[2:])
@@ -419,28 +426,54 @@ def check_ssd_vs_sequential(batch: int, seq: int, heads: int, head_dim: int,
         _, y = jax.lax.scan(
             jax.checkpoint(lambda S, at: jax.lax.scan(step, S, at)),
             jnp.zeros((batch, heads, head_dim, state), jnp.float32), by_block)
-        return jnp.moveaxis(y.reshape(-1, *y.shape[2:]), 0, 1)
+        return jnp.moveaxis(y.reshape(-1, *y.shape[2:]), 0, 1) + x * D[:, None]
+
+    def forward(scan, cast):
+        return jax.jit(lambda x, dt, A, B, C, D: scan(
+            cast(x), dt, A, cast(B), cast(C), D))
 
     def value_and_grads(scan, cast):
-        def loss(x, B, C):
-            y = scan(cast(x), dt, A, cast(B), cast(C)).astype(jnp.float32)
+        def loss(x, dt, A, B, C, D):
+            y = scan(cast(x), dt, A, cast(B), cast(C), D).astype(jnp.float32)
             return jnp.sum(y * w), y
-        (_, y), grads = jax.jit(jax.value_and_grad(
-            loss, (0, 1, 2), has_aux=True))(x, B, C)
-        return (y,) + grads
+        return jax.jit(jax.value_and_grad(loss, tuple(range(6)), has_aux=True))
 
-    impl = ssd.scan_impl(seq, chunk)
-    got = value_and_grads(
-        lambda *a: ssd.ssd_scan(*a, D, chunk=chunk), low)
-    want = value_and_grads(
-        lambda x, dt, A, B, C: recurrence(x, dt, A, B, C) + x * D[:, None],
-        lambda a: a)
-    errs = {name: float(f"{rel_err(g, t):.3e}")
-            for name, g, t in zip(("y", "dx", "dB", "dC"), got, want)}
+    def ms(f) -> float:
+        """A call's wall time, the median of five after the first."""
+        jax.block_until_ready(f(x, dt, A, B, C, D))
+        times = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(x, dt, A, B, C, D))
+            times.append(time.perf_counter() - t0)
+        return round(1e3 * float(np.median(times)), 3)
+
+    def against_recurrence(scan) -> dict:
+        (_, y), grads = value_and_grads(scan, low)(x, dt, A, B, C, D)
+        return {name: float(f"{rel_err(g, t):.3e}") for name, g, t in zip(
+            ("y", "dx", "ddt", "dA", "dB", "dC", "dD"), (y,) + grads, want)}
+
+    def chunked_by_xla(x, dt, A, B, C, D):
+        y = ssd._chunked(x, dt, A, B, C, chunk)
+        return (y + x.astype(jnp.float32) * D[:, None]).astype(x.dtype)
+
+    impl, kernel = ssd.scan_impl(seq, chunk), ssd.scan_kernel(low(x), B, chunk)
+    dispatched = lambda *a: ssd.ssd_scan(*a, chunk=chunk)  # noqa: E731
+    (_, y), grads = value_and_grads(recurrence, lambda a: a)(x, dt, A, B, C, D)
+    want = (y,) + grads
+    errs = against_recurrence(dispatched)
     require(all(np.isfinite(e) and e <= 2e-2 for e in errs.values()),
-            f"chunked scan vs the sequential recurrence: {errs}")
-    return {"impl": impl, "shape": [batch, seq, heads, head_dim, groups, state],
-            "chunk": chunk, "rel_err": errs}
+            f"the scan ({kernel}) vs the sequential recurrence: {errs}")
+    out = {"impl": impl, "kernel": kernel, "chunk": chunk, "rel_err": errs,
+           "shape": [batch, seq, heads, head_dim, groups, state]}
+    if kernel == "pallas":
+        out["rel_err_xla"] = against_recurrence(chunked_by_xla)
+        out["ms_fwd"], out["ms_fwd_bwd"] = (
+            ms(forward(dispatched, low)), ms(value_and_grads(dispatched, low)))
+        out["ms_fwd_xla"], out["ms_fwd_bwd_xla"] = (
+            ms(forward(chunked_by_xla, low)),
+            ms(value_and_grads(chunked_by_xla, low)))
+    return out
 
 
 def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
@@ -893,7 +926,8 @@ def main() -> int:
             and mixers["mosaic_calls_lowered"] >= 2 + 6,
             f"the one-mixer decoder's kernels did not engage compiled: {mixers}")
     scan = run("ssd_vs_sequential", check_ssd_vs_sequential, *SSD_SHAPE)
-    require(scan["impl"] == "chunked", f"the scan took {scan['impl']}")
+    require((scan["impl"], scan["kernel"]) == ("chunked", "pallas"),
+            f"the scan took {scan['impl']} by {scan['kernel']}")
     moves = run("row_moves", check_row_moves, *ROW_MOVES)
     require(moves["mosaic_calls_lowered"] == 2,  # rows out, rows back
             f"the row moves did not run as compiled Mosaic calls: {moves}")

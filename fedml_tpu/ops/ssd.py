@@ -14,24 +14,36 @@ so that a device trace can name its time:
 - ``ssd.gate_norm``: ``RMSNorm_groups(y * silu(z)) * w``, the norm over each
   group's channels (the gate before the norm), float32.
 
-**The scan's two forms** (``ssd_scan`` chooses at trace time from the length
-and the chunk, and counts the choice: ``fedml_ssd_dispatch_total{impl,
-seq_len, chunk}``, once a call site a trace):
+**The scan's three forms.** ``ssd_scan`` chooses at trace time, from what
+it sees in its input and nothing else, and counts both choices once a call
+site a trace: the algorithm by the length and the chunk
+(``fedml_ssd_dispatch_total{impl="chunked"|"sequential", seq_len, chunk}``),
+what runs it by the shapes (``scan_kernel``:
+``fedml_ssd_kernel_total{impl="pallas"|"xla", seq_len, chunk}``).
 
 - ``"chunked"``, T a multiple of the chunk L: the state-space dual. With
   ``a_t = dt_t A`` and ``cum`` its running sum inside a chunk, a chunk's
   output is ``(mask(exp(cum_l - cum_s)) * C_l.B_s) (dt_s x_s)`` from its own
   positions plus ``exp(cum_l) C_l . S_in`` from the state it was handed; its
   closing state is ``sum_s exp(cum_L - cum_s) dt_s x_s (x) B_s``, and the
-  states are carried over the T / L chunks by a sequential ``lax.scan``
-  (``S_in' = exp(cum_L) S_in + closing``). Decays and running sums in
-  float32 (every exponent is <= 0, so nothing overflows); the four products
-  (``C B^T``, the masked scores by ``x``, the closing state, ``C`` by the
-  state handed in) are batched matmuls in the inputs' dtype with float32
-  accumulation. Plain ``jax.numpy`` that XLA compiles and autodiff
-  differentiates: no kernel.
+  states are carried over the T / L chunks (``S_in' = exp(cum_L) S_in +
+  closing``). Decays and running sums in float32 (every exponent is <= 0, so
+  nothing overflows); the four products (``C B^T``, the masked scores by
+  ``x``, the closing state, ``C`` by the state handed in) take operands in
+  the inputs' dtype and accumulate float32. Two things run it:
+
+  - ``"pallas"``: the kernel pair of ``ops/pallas/ssd.py`` behind a custom
+    VJP, where the shapes fill its tiles (the chunk and the state multiples
+    of 128, a group's heads whole 128-lane tiles, two chunks or more: the
+    Nemotron cell's 128 / 128 / 8 x 64). The (L, L) decays and masked
+    scores, the closing states and the states handed on stay in VMEM; only
+    the running sum (and, backward, its reverse) is XLA's. Imported here
+    only where a scan takes it.
+  - ``"xla"``: ``_chunked``, plain ``jax.numpy`` that XLA compiles and
+    autodiff differentiates, with a ``lax.scan`` over the chunks; every
+    shape the kernels' rule refuses (a chunk of 8, a state of 16).
 - ``"sequential"``, T under one chunk (a model's 8-token init): the
-  recurrence itself, ``lax.scan`` over t in float32.
+  recurrence itself, ``lax.scan`` over t in float32 (``"xla"``).
 
 A T of one chunk or more that is no multiple of the chunk is refused: a
 padded tail would be silent work.
@@ -68,17 +80,39 @@ def scan_impl(seq_len: int, chunk: int) -> str:
     return "chunked"
 
 
+def scan_kernel(x, B, chunk: int) -> str:
+    """What runs a scan of these arrays: ``"pallas"``, the kernel pair of
+    ``ops/pallas/ssd.py``, where the chunked form's shapes fill its tiles
+    (``kernel_shapes_ok``: the chunk and the state whole 128-lane tiles, a
+    group's heads whole lane tiles, two chunks or more, a step's working
+    set inside scoped VMEM), else ``"xla"``. From the shapes and the dtype
+    alone."""
+    (_, T, H, P), (G, N) = x.shape, B.shape[2:]
+    if scan_impl(T, chunk) != "chunked":
+        return "xla"
+    from .pallas.ssd import kernel_shapes_ok
+    return "pallas" if kernel_shapes_ok(
+        T, chunk, H, P, G, N, jnp.dtype(x.dtype).itemsize) else "xla"
+
+
 def ssd_scan(x, dt, A, B, C, D, *, chunk: int) -> jax.Array:
     """The scan of the module's docstring. x: (b, T, H, P); dt: (b, T, H),
     after its softplus, float32; A: (H,), negative, float32; B, C: (b, T, G,
     N) with G dividing H; D: (H,). Returns y: (b, T, H, P) in x's dtype."""
-    T = x.shape[1]
-    impl = scan_impl(T, chunk)
+    b, T, H, _ = x.shape
+    impl, kernel = scan_impl(T, chunk), scan_kernel(x, B, chunk)
     if isinstance(x, jax.core.Tracer):
-        get_registry().counter("fedml_ssd_dispatch_total", impl=impl,
-                               seq_len=T, chunk=chunk).inc()
+        for family, by in (("fedml_ssd_dispatch_total", impl),
+                           ("fedml_ssd_kernel_total", kernel)):
+            get_registry().counter(family, impl=by, seq_len=T,
+                                   chunk=chunk).inc()
+    dt, A = dt.astype(jnp.float32), A.astype(jnp.float32)
+    if kernel == "pallas":
+        from .pallas.ssd import ssd_chunked
+        cum = jnp.cumsum((dt * A).reshape(b, T // chunk, chunk, H), axis=2)
+        return ssd_chunked(x, dt, cum.reshape(b, T, H), B, C, D, chunk)
     scan = _chunked if impl == "chunked" else _sequential
-    y = scan(x, dt.astype(jnp.float32), A.astype(jnp.float32), B, C, chunk)
+    y = scan(x, dt, A, B, C, chunk)
     skip = x.astype(jnp.float32) * D.astype(jnp.float32)[:, None]
     return (y + skip).astype(x.dtype)
 

@@ -156,15 +156,22 @@ def test_the_relu2_expert_block_compiles_at_widths_that_are_no_tile_multiples(
                 or "tokens_from_rows" in name]
 
 
-def test_the_mamba2_core_compiles_with_its_scopes_forward_and_backward(topo):
+@pytest.mark.parametrize("batch,T", [(2, 1024), (1, 8192)],
+                         ids=["b2_t1024", "the_cell_b1_t8192"])
+def test_the_mamba2_core_compiles_with_its_scopes_forward_and_backward(
+        topo, monkeypatch, batch, T):
     """``ops/ssd.py mamba2_core`` at the Nemotron cell's widths (64 heads x
-    64, state 128, 8 groups, chunk 128) over 1,024 positions, forward and
-    backward: the chunked scan is plain ``jax.numpy`` that XLA:TPU compiles
-    (no Mosaic call), and the three scopes name its ops in both passes, which
-    is what ``ssd_ms`` and ``ssd_roofline`` read."""
+    64, state 128, 8 groups, chunk 128), forward and backward, at two batch
+    rows of 8 chunks and at the cell's one row of 64: the scan is the kernel
+    pair of ``ops/pallas/ssd.py`` (one Mosaic call each way, both inside
+    scoped VMEM), no array of chunks x heads x L x L elements is left (the
+    decays and masked scores XLA's chunked form kept in HBM), and the three
+    scopes name the ops in both passes, the kernels' calls too, which is what
+    ``ssd_ms`` and ``ssd_roofline`` read."""
     from fedml_tpu.ops.ssd import mamba2_core
 
-    heads, head_dim, state, groups, T = 64, 64, 128, 8, 1024
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    heads, head_dim, state, groups, chunk = 64, 64, 128, 8, 128
     inner, conv_dim = heads * head_dim, heads * head_dim + 2 * groups * state
     one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
     sds = lambda *shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
@@ -172,17 +179,26 @@ def test_the_mamba2_core_compiles_with_its_scopes_forward_and_backward(topo):
 
     def loss(*args):
         return mamba2_core(*args, heads=heads, head_dim=head_dim, state=state,
-                           groups=groups, chunk=128, eps=1e-5
+                           groups=groups, chunk=chunk, eps=1e-5
                            ).astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
-        sds(1, T, inner + conv_dim + heads, dtype=jnp.bfloat16),
+        sds(batch, T, inner + conv_dim + heads, dtype=jnp.bfloat16),
         sds(conv_dim, 4), sds(conv_dim), sds(heads), sds(heads), sds(heads),
         sds(inner)).compile().as_text()
-    assert "tpu_custom_call" not in text
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line
+             and "custom-call(" in line]
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in calls]
+    assert len(names) == 2 and all("ssd.core" in n for n in names), names
+    assert sorted("transpose(" in n for n in names) == [False, True], names
     names = re.findall(r'op_name="([^"]*)"', text)
     for scope in ("ssd.conv", "ssd.core", "ssd.gate_norm"):
         assert [n for n in names if scope in n and "transpose(" in n], scope
         assert [n for n in names if scope in n and "transpose(" not in n], scope
-    # the carried states: one loop over the 8 chunks each way, under the scope
-    assert [n for n in names if "ssd.core" in n and "while" in n]
+    # 8,388,608 elements a row at T = 1024, 67,108,864 at the cell's; nor
+    # a group's (L, L) scores, the other array the dual form kept in HBM
+    shapes = [tuple(int(d) for d in dims.split(",")) for dims in re.findall(
+        r"\b(?:f32|bf16|pred|s32)\[([0-9,]+)\]", text)]
+    scores = batch * (T // chunk) * heads * chunk * chunk
+    assert scores not in {int(np.prod(shape)) for shape in shapes}
+    assert not [shape for shape in shapes if shape[-2:] == (chunk, chunk)]
