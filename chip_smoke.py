@@ -19,7 +19,10 @@ One process, the normal entry points, no bench-only switch:
                a second small decoder of one-mixer blocks (Mamba-2, attention
                without positions, relu2 experts with a shared expert, an
                untied head), and the chunked state-space scan against the
-               sequential one at the Nemotron cell's widths
+               sequential one at the Nemotron cell's widths; a looped decoder
+               at the Ouro cell's widths (two sandwich-norm layers of rotary
+               attention 16 x 128 over SwiGLU 5632, applied four times, the
+               exit gate and the objective over the passes)
 4. kernels   — every other pallas_call against its in-repo reference:
                fused_gram (alone at a 1000-row cohort, and inside
                fused_sanitize_krum on a flagship cohort of ResNet-56
@@ -79,6 +82,15 @@ HYBRID_MIXERS = dict(
     chunk_size=128, moe_shared_expert_intermediate_size=512,
     routed_scaling_factor=2.5, mlp_hidden_act="relu2",
     tie_word_embeddings=False)
+# a looped decoder at the Ouro cell's widths: two layers applied four times
+# over one set of weights, an exit gate, the expected loss over the passes
+LOOPED_LM = dict(
+    vocab_size=4096, hidden_size=2048, num_dense_layers=2,
+    layer_types=("full_attention", "full_attention"), intermediate_size=5632,
+    moe_intermediate_size=0, num_experts=0, num_experts_per_tok=0,
+    num_attention_heads=16, num_key_value_heads=16, head_dim=128,
+    norm_eps=1e-6, tie_word_embeddings=False, qk_norm=False,
+    sandwich_norm=True, total_ut_steps=4)
 # the Nemotron cell's scan: (batch, T, heads, head width, groups, state, chunk)
 SSD_SHAPE = (1, 8192, 64, 64, 8, 128, 128)
 # the LFM2 cell's expert layer: 16,384 tokens x top-4 over 64 experts of
@@ -324,9 +336,10 @@ def stage_lm(model: dict, seq: int, batch: int, steps: int,
 def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
     """A layer_types decoder (``HYBRID_LM``: short convolution, grouped
     rotary attention, dropless routed experts on a share of the experts;
-    ``HYBRID_MIXERS``: the one-mixer blocks) through the trainer: finite
-    falling loss, no assignment dropped, and how many Mosaic calls the step
-    lowers to (flash forward and backward, the grouped products)."""
+    ``HYBRID_MIXERS``: the one-mixer blocks; ``LOOPED_LM``: a stack applied
+    several times) through the trainer: finite falling loss, no assignment
+    dropped, every pass counted, and how many Mosaic calls the step lowers
+    to (flash forward and backward, the grouped products)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -341,8 +354,8 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
 
     cfg = DecoderConfig(**model)
     heads = cfg.num_attention_heads
-    impl = auto_attention_impl(batch, heads, seq, cfg.hidden_size // heads,
-                               itemsize=2)
+    impl = auto_attention_impl(
+        batch, heads, seq, cfg.head_dim or cfg.hidden_size // heads, itemsize=2)
     trainer = DistributedLMTrainer(DistTrainConfig(), dtype=jnp.bfloat16,
                                    seed=0, model=cfg)
     rng = np.random.default_rng(0)
@@ -355,6 +368,7 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
     ).as_text().count("tpu_custom_call")
     registry = get_registry()
     before = registry.counter_total("fedml_moe_assignments_total")
+    passes_before = registry.counter_total("fedml_lm_ut_passes_total")
     losses = [trainer.step(x, y) for _ in range(steps)]
     require(bool(np.all(np.isfinite(losses))),
             f"hybrid LM loss not finite: {losses}")
@@ -365,6 +379,10 @@ def stage_hybrid_lm(model: dict, seq: int, batch: int, steps: int) -> dict:
             f"the expert layers counted {routed} assignments")
     dropped = registry.counter_total("fedml_moe_dropped_total")
     require(dropped == 0, f"{dropped} assignments were dropped")
+    passes = (registry.counter_total("fedml_lm_ut_passes_total")
+              - passes_before)
+    require(passes == (steps * cfg.total_ut_steps if cfg.total_ut_steps > 1
+                       else 0), f"the decoder counted {passes} passes")
     return {"attention_impl": impl, "mosaic_calls_lowered": n_mosaic,
             "batch": batch, "seq": seq, "loss": [round(v, 4) for v in losses],
             "assignments": int(routed), "memory": memory_stats()}
@@ -925,6 +943,12 @@ def main() -> int:
     require(mixers["attention_impl"] == "flash"
             and mixers["mosaic_calls_lowered"] >= 2 + 6,
             f"the one-mixer decoder's kernels did not engage compiled: {mixers}")
+    looped = run("looped_lm", stage_hybrid_lm, LOOPED_LM, HYBRID_SEQ,
+                 HYBRID_BATCH, LM_STEPS)
+    # flash forward and backward, at head width 128 with no K/V repeat
+    require(looped["attention_impl"] == "flash"
+            and looped["mosaic_calls_lowered"] >= 2,
+            f"the looped decoder's kernels did not engage compiled: {looped}")
     scan = run("ssd_vs_sequential", check_ssd_vs_sequential, *SSD_SHAPE)
     require((scan["impl"], scan["kernel"]) == ("chunked", "pallas"),
             f"the scan took {scan['impl']} by {scan['kernel']}")

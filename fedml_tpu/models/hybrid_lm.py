@@ -1,11 +1,15 @@
 """A decoder built from a ``layer_types`` list: the hybrid LMs whose layers
-differ in kind by position. Two families of kinds, by what a layer holds.
+differ in kind by position, and the looped ones that run one stack of layers
+several times. Two families of kinds, by what a layer holds.
 
 **Two-part layers**, ``h += Op(RMSNorm(h)); h += FF(RMSNorm(h))`` (LFM2-MoE's).
 ``Op`` is a gated short convolution (``"conv"``, ops/short_conv.py) or causal
 grouped-query attention with per-head RMS-normalised, rotary q and k
 (``"full_attention"``, ops/rotary.py, ops/attention.py: the same dispatch
-rule and flash kernels as ``TransformerLM``). ``FF`` is a dense SwiGLU in the
+rule and flash kernels as ``TransformerLM``; ``qk_norm`` false leaves the
+normalisation out). With ``sandwich_norm`` each sub-layer's output is
+normalised too, inside the residual: ``h += RMSNorm(Op(RMSNorm(h)))``
+(Ouro's layers). ``FF`` is a dense SwiGLU in the
 first ``num_dense_layers`` layers and a dropless routed-expert layer after
 them (ops/moe.py ``RoutedExperts``: sigmoid scores, a selection bias,
 normalised top-k weights), which may hold a chip's share of the experts.
@@ -19,15 +23,24 @@ grouped-query attention with no position embedding and no QK-norm
 with ``mlp_hidden_act``'s expert form, ``routed_scaling_factor`` and a
 shared expert ``moe_shared_expert_intermediate_size`` wide).
 
+**The looped family** (``total_ut_steps`` = S > 1; Ouro's): the whole stack
+is applied S times over one set of weights, ``h_t = final_norm(stack(h_{t-1}))``
+with the final norm inside the loop, and an exit gate ``g_t = h_t w + b``
+reads each pass's output. ``__call__`` gives the last pass's logits;
+``return_passes`` every pass's hidden state and gate logit, which the
+training objective takes (ops/losses.py ``expected_exit_loss``: the
+expectation of the passes' losses under the gate's exit distribution).
+
 No bias in any projection, no position table; the head is the embedding's
 transpose, or with ``tie_word_embeddings`` false a matrix of its own.
 ``DecoderConfig`` carries the published key names of such models'
-``config.json`` (LFM2-MoE's, Nemotron-H's), so a configuration file maps
-onto it key by key.
+``config.json`` (LFM2-MoE's, Nemotron-H's, Ouro's), so a configuration file
+maps onto it key by key.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Optional, Tuple, Union
@@ -44,6 +57,15 @@ LAYER_KINDS = TWO_PART_KINDS + MIXER_KINDS
 # ``mlp_hidden_act`` -> an expert's form (ops/moe.py ``EXPERT_FORMS``)
 EXPERT_FORM_OF_ACT = {"silu": "swiglu", "relu2": "relu2"}
 PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
+
+
+def ut_stats(passes: int) -> Tuple[str, ...]:
+    """The names of a looped decoder's statistics of a step: each pass's
+    mean token loss, then each pass's exit mass (its exit probability summed
+    over the step's tokens)."""
+    steps = range(1, passes + 1)
+    return (tuple(f"ut_nll_{t}" for t in steps)
+            + tuple(f"ut_mass_{t}" for t in steps))
 
 
 def layer_types_of_pattern(pattern: str) -> Tuple[str, ...]:
@@ -86,6 +108,11 @@ class DecoderConfig:
     routed_scaling_factor: float = 1.0
     mlp_hidden_act: str = "silu"
     tie_word_embeddings: bool = True
+    # two facts of a two-part layer that LFM2 and Ouro state differently
+    qk_norm: bool = True            # RMSNorm q and k per head before rotary
+    sandwich_norm: bool = False     # a norm after each sub-layer as well
+    # the looped family's (Ouro's key): the stack is applied this many times
+    total_ut_steps: int = 1
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -98,6 +125,9 @@ class DecoderConfig:
         if self.mlp_hidden_act not in EXPERT_FORM_OF_ACT:
             raise ValueError(f"mlp_hidden_act {self.mlp_hidden_act!r}; this "
                              f"decoder has {sorted(EXPERT_FORM_OF_ACT)}")
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps}: the "
+                             "stack is applied once at least")
 
     @property
     def expert_layers(self) -> int:
@@ -145,8 +175,9 @@ class ShortConv(nn.Module):
 
 class GroupedQueryAttention(nn.Module):
     """Causal attention, ``kv_heads`` KV heads serving ``heads`` query
-    heads; q and k RMS-normalised per head, then rotary, or with
-    ``positions`` false neither (no position embedding at all)."""
+    heads. Two facts a layer states: ``qk_norm``, q and k RMS-normalised per
+    head; ``rotary``, q and k rotated by position (after the norm, where
+    both). With neither there is no position embedding at all."""
 
     dim: int
     heads: int
@@ -157,7 +188,8 @@ class GroupedQueryAttention(nn.Module):
     dtype: jnp.dtype = jnp.float32
     mesh: Optional[object] = None
     attn_impl: Optional[str] = None
-    positions: bool = True
+    rotary: bool = True
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, u):
@@ -168,11 +200,13 @@ class GroupedQueryAttention(nn.Module):
         q = _dense(H * Dh, self.dtype, "q_proj")(u).reshape(B, T, H, Dh)
         k = _dense(Hkv * Dh, self.dtype, "k_proj")(u).reshape(B, T, Hkv, Dh)
         v = _dense(Hkv * Dh, self.dtype, "v_proj")(u).reshape(B, T, Hkv, Dh)
-        if self.positions:
-            q = apply_rotary(RMSNorm(self.eps, self.dtype, name="q_norm")(q),
-                             self.rope_theta)
-            k = apply_rotary(RMSNorm(self.eps, self.dtype, name="k_norm")(k),
-                             self.rope_theta)
+
+        def placed(x, norm_name):  # q or k on its way into the scores
+            if self.qk_norm:
+                x = RMSNorm(self.eps, self.dtype, name=norm_name)(x)
+            return apply_rotary(x, self.rope_theta) if self.rotary else x
+
+        q, k = placed(q, "q_norm"), placed(k, "k_norm")
         out = self._local_attention(q, k, v).reshape(B, T, H * Dh)
         return _dense(self.dim, self.dtype, "o_proj")(out)
 
@@ -266,7 +300,8 @@ class MixerBlock(nn.Module):
                 c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
                 c.head_dim or c.hidden_size // c.num_attention_heads,
                 c.rope_theta, c.norm_eps, self.dtype, mesh=self.mesh,
-                attn_impl=self.attn_impl, positions=False, name="attn")(u)
+                attn_impl=self.attn_impl, rotary=False, qk_norm=False,
+                name="attn")(u)
         else:
             out, stats = RoutedExperts(
                 c.hidden_size, c.moe_intermediate_size, c.num_experts,
@@ -281,7 +316,9 @@ class MixerBlock(nn.Module):
 
 class DecoderLayer(nn.Module):
     """One two-part layer of kind ``kind``; ``dense`` picks its feed-forward.
-    Returns (h, the expert layer's stats or zeros)."""
+    With ``cfg.sandwich_norm`` each sub-layer's output passes a norm of its
+    own before it joins the residual. Returns (h, the expert layer's stats
+    or zeros)."""
 
     cfg: DecoderConfig
     kind: str
@@ -293,32 +330,56 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, h):
         c = self.cfg
+
+        def joins(out, name):  # a sub-layer's output on its way to h
+            if not c.sandwich_norm:
+                return out
+            return RMSNorm(c.norm_eps, self.dtype, name=name)(out)
+
         u = RMSNorm(c.norm_eps, self.dtype, name="operator_norm")(h)
         if self.kind == "conv":
-            h = h + ShortConv(c.hidden_size, c.conv_L_cache, self.dtype,
-                              name="conv")(u)
+            out = ShortConv(c.hidden_size, c.conv_L_cache, self.dtype,
+                            name="conv")(u)
         else:
-            h = h + GroupedQueryAttention(
+            out = GroupedQueryAttention(
                 c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
                 c.head_dim or c.hidden_size // c.num_attention_heads,
                 c.rope_theta, c.norm_eps, self.dtype, mesh=self.mesh,
-                attn_impl=self.attn_impl, name="attn")(u)
+                attn_impl=self.attn_impl, rotary=True, qk_norm=c.qk_norm,
+                name="attn")(u)
+        h = h + joins(out, "operator_out_norm")
         u = RMSNorm(c.norm_eps, self.dtype, name="ffn_norm")(h)
         if self.dense:
-            return h + SwiGLU(c.hidden_size, c.intermediate_size, self.dtype,
-                              name="mlp")(u), jnp.zeros(len(MOE_STATS), jnp.int32)
+            h = h + joins(SwiGLU(c.hidden_size, c.intermediate_size,
+                                 self.dtype, name="mlp")(u), "ffn_out_norm")
+            return h, jnp.zeros(len(MOE_STATS), jnp.int32)
         out, stats = RoutedExperts(
             c.hidden_size, c.moe_intermediate_size, c.num_experts,
             c.num_experts_per_tok, experts_held=c.experts_held,
             dtype=self.dtype, mesh=self.mesh, name="moe")(u)
-        return h + out, stats
+        return h + joins(out, "ffn_out_norm"), stats
+
+
+class ExitGate(nn.Module):
+    """A looped decoder's exit gate: one logit a position, ``h w + b`` in
+    float32 (the exit distribution is made from it in float32)."""
+
+    @nn.compact
+    def __call__(self, h):
+        w = self.param("kernel", nn.initializers.normal(0.02),
+                       (h.shape[-1],), jnp.float32)
+        b = self.param("bias", nn.initializers.zeros, (), jnp.float32)
+        return jnp.dot(h.astype(jnp.float32), w) + b
 
 
 class HybridLM(nn.Module):
-    """Decoder-only causal LM over ``cfg.layer_types``. ``__call__`` gives
-    float32 logits (B, T, V), or the final hidden states with
-    ``return_hidden``; with ``return_stats`` also the step's routing
-    statistics ``STEP_STATS`` (counts summed over the expert layers, the
+    """Decoder-only causal LM over ``cfg.layer_types``, the stack applied
+    ``cfg.total_ut_steps`` times over one set of weights. ``__call__`` gives
+    float32 logits (B, T, V) of the last pass, or the final hidden states
+    with ``return_hidden``; a looped decoder gives with ``return_passes``
+    (every pass's final hidden state (S, B, T, D), every pass's gate logit
+    (S, B, T) in float32). With ``return_stats`` also the routing statistics
+    ``MOE_STATS`` (counts summed over the expert layers and the passes, the
     largest load of any held expert in any layer)."""
 
     cfg: DecoderConfig
@@ -327,12 +388,23 @@ class HybridLM(nn.Module):
     attn_impl: Optional[str] = None
     remat: Union[bool, str] = False  # as TransformerLM: per layer
 
-    STEP_STATS = MOE_STATS
+    @property
+    def STEP_STATS(self) -> Tuple[str, ...]:
+        """What a training step hands back beside the loss: the routing
+        statistics ``apply(..., return_stats=True)`` gives, and for a looped
+        decoder ``ut_stats``, which the objective over its passes gives."""
+        passes = self.cfg.total_ut_steps
+        return MOE_STATS + (ut_stats(passes) if passes > 1 else ())
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,
-                 return_hidden: bool = False, return_stats: bool = False):
+                 return_hidden: bool = False, return_stats: bool = False,
+                 return_passes: bool = False):
         c = self.cfg
+        looped = c.total_ut_steps > 1
+        if return_passes and not looped:
+            raise ValueError("return_passes: this decoder applies its stack "
+                             "once (total_ut_steps = 1)")
         embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                          embedding_init=nn.initializers.normal(0.02),
                          name="embed")
@@ -349,21 +421,40 @@ class HybridLM(nn.Module):
                 f"unknown remat policy {self.remat!r}; use False, True, "
                 "'full', or 'dots'")
         layer_cls, block_cls = wrap(DecoderLayer), wrap(MixerBlock)
-        stats = jnp.zeros(len(MOE_STATS), jnp.int32)
-        peak = MOE_STATS.index("held_load_max")
-        two_part = 0
+        # the layers are built once: a module called again uses its
+        # parameters again, which is all a looped decoder's reuse is
+        layers, two_part = [], 0
         for i, kind in enumerate(c.layer_types):
             kw = dict(mesh=self.mesh, attn_impl=self.attn_impl,
                       name=f"layer_{i}")
             if kind in MIXER_KINDS:
-                h, s = block_cls(c, kind, self.dtype, **kw)(h)
+                layers.append(block_cls(c, kind, self.dtype, **kw))
             else:
-                h, s = layer_cls(c, kind, two_part < c.num_dense_layers,
-                                 self.dtype, **kw)(h)
+                layers.append(layer_cls(c, kind, two_part < c.num_dense_layers,
+                                        self.dtype, **kw))
                 two_part += 1
-            stats = (stats + s).at[peak].set(jnp.maximum(stats[peak], s[peak]))
-        h = RMSNorm(c.norm_eps, self.dtype, name="final_norm")(h)
-        if not return_hidden:
+        final_norm = RMSNorm(c.norm_eps, self.dtype, name="final_norm")
+        gate = ExitGate(name="exit_gate") if looped else None
+        stats = jnp.zeros(len(MOE_STATS), jnp.int32)
+        peak = MOE_STATS.index("held_load_max")
+        hidden, gate_logits = [], []
+        for _ in range(c.total_ut_steps):
+            # (a scope only where there are passes to tell apart: the
+            # one-pass decoders' op names stay as they were)
+            with (jax.named_scope("ut.pass") if looped
+                  else contextlib.nullcontext()):
+                for layer in layers:
+                    h, s = layer(h)
+                    stats = (stats + s).at[peak].set(
+                        jnp.maximum(stats[peak], s[peak]))
+                h = final_norm(h)  # inside the loop: h_t feeds pass t + 1
+            if looped:
+                hidden.append(h)
+                with jax.named_scope("ut.exit"):  # with the objective's ops
+                    gate_logits.append(gate(h))
+        if return_passes:
+            h = (jnp.stack(hidden), jnp.stack(gate_logits))
+        elif not return_hidden:
             untied = None if c.tie_word_embeddings else self.param(
                 "lm_head", nn.initializers.normal(0.02),
                 (c.hidden_size, c.vocab_size), jnp.float32)
@@ -391,15 +482,25 @@ class HybridLM(nn.Module):
         """A step's ``STEP_STATS`` into the registry: assignments held here
         and elsewhere, those dropped (a dropless layer's stays 0), and the
         fullest held expert's load over the mean held load (on any one
-        device over a device's mean, under ``dp`` data-parallel devices)."""
+        device over a device's mean, under ``dp`` data-parallel devices);
+        for a looped decoder the passes run, each pass's exit mass and its
+        mean token loss."""
         c = self.cfg
         counter = registry.counter
-        counter("fedml_moe_assignments_total", held="yes").inc(stats["held"])
-        counter("fedml_moe_assignments_total", held="no").inc(
-            stats["total"] - stats["held"])
-        counter("fedml_moe_dropped_total").inc(stats["dropped"])
-        held = (c.experts_held or (0, c.num_experts))[1]
-        if stats["held"]:
-            registry.gauge("fedml_moe_held_load_max_over_mean").set(
-                stats["held_load_max"] * held * c.expert_layers * dp
-                / stats["held"])
+        if c.expert_layers:
+            counter("fedml_moe_assignments_total", held="yes").inc(stats["held"])
+            counter("fedml_moe_assignments_total", held="no").inc(
+                stats["total"] - stats["held"])
+            counter("fedml_moe_dropped_total").inc(stats["dropped"])
+            held = (c.experts_held or (0, c.num_experts))[1]
+            if stats["held"]:
+                registry.gauge("fedml_moe_held_load_max_over_mean").set(
+                    stats["held_load_max"] * held * c.expert_layers * dp
+                    / stats["held"])
+        if c.total_ut_steps > 1:
+            counter("fedml_lm_ut_passes_total").inc(c.total_ut_steps)
+            for t in range(1, c.total_ut_steps + 1):
+                counter("fedml_lm_exit_mass_total", ut=t).inc(
+                    stats[f"ut_mass_{t}"])
+                registry.gauge("fedml_lm_ut_nll", ut=t).set(
+                    stats[f"ut_nll_{t}"])

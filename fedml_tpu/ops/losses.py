@@ -132,6 +132,56 @@ def chunked_lm_cross_entropy(hidden: jax.Array, head_kernel: jax.Array,
     return jnp.mean(jax.lax.map(one, (hc, tc)))
 
 
+def exit_distribution(gate_logits: jax.Array) -> jax.Array:
+    """A looped decoder's exit distribution over its S passes, float32, from
+    the gate's logits (S, ...): with ``lam_t = sigmoid(g_t)``, ``p_1 =
+    lam_1``, ``p_t = lam_t prod_{j<t} (1 - lam_j)`` for 1 < t < S, and the
+    last pass takes what is left, ``p_S = prod_{j<S} (1 - lam_j)``; the last
+    gate's logit enters nothing. Sums to 1 over the passes."""
+    g = gate_logits.astype(jnp.float32)[:-1]
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-g), axis=0)  # log prod_{j<=t}(1-lam_j)
+    before = jnp.concatenate([jnp.zeros_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate(
+        [jnp.exp(jax.nn.log_sigmoid(g) + before), jnp.exp(stay[-1:])])
+
+
+def expected_exit_loss(hidden: jax.Array, gate_logits: jax.Array,
+                       head_kernel: jax.Array, targets: jax.Array,
+                       beta: float):
+    """A looped decoder's training objective: the mean over tokens of
+    ``sum_t p_t nll_t - beta H(p)``, the expectation of the S passes' token
+    losses under the gate's exit distribution ``p`` less ``beta`` times that
+    distribution's entropy. hidden (S, B, T, D): each pass's final hidden
+    state; gate_logits (S, B, T); head_kernel (D, V), shared by the passes;
+    targets (B, T) int. Returns (loss, each pass's mean token loss (S,),
+    each pass's exit mass (S,): its probability summed over the tokens).
+
+    Each pass's head product and ``lm_token_nll`` run under
+    ``jax.checkpoint``: a pass keeps its hidden state and its (B, T) losses
+    for the backward, never its (B, T, V) logits, so the S logit arrays do
+    not live together. Scopes: ``ut.head`` the head products, ``lm.loss``
+    the token losses, ``ut.exit`` the distribution, expectation and
+    entropy."""
+    S, B, T, D = hidden.shape
+
+    @jax.checkpoint
+    def pass_nll(h):
+        with jax.named_scope("ut.head"):
+            # one (B T, D) x (D, V) product, float32 out: as HybridLM's head
+            logits = jnp.dot(h.reshape(B * T, D), head_kernel,
+                             preferred_element_type=jnp.float32
+                             ).reshape(B, T, -1)
+        with jax.named_scope("lm.loss"):
+            return lm_token_nll(logits, targets)
+
+    nll = jnp.stack([pass_nll(hidden[t]) for t in range(S)])
+    with jax.named_scope("ut.exit"):
+        p = exit_distribution(gate_logits)
+        entropy = -(p * jnp.log(jnp.maximum(p, 1e-30))).sum(0)
+        loss = ((p * nll).sum(0) - beta * entropy).mean()
+        return loss, nll.mean((1, 2)), p.sum((1, 2))
+
+
 def _bce_with_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Per-cell numerically-stable BCE-with-logits (log-sigmoid form).
     The ONE implementation shared by the training loss and the per-sample

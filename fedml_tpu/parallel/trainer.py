@@ -35,7 +35,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core.telemetry import get_registry, get_tracer
 from ..models.hybrid_lm import DecoderConfig, HybridLM
 from ..models.transformer import TransformerLM
-from ..ops.losses import chunked_lm_cross_entropy, lm_cross_entropy
+from ..ops.losses import (
+    chunked_lm_cross_entropy,
+    expected_exit_loss,
+    lm_cross_entropy,
+)
 from .mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ, MeshConfig, create_mesh
 from .sharding import replicated_specs, transformer_param_specs, tree_shardings
 
@@ -72,6 +76,9 @@ class DistTrainConfig:
     # linear learning-rate warm-up: step t (from 1) runs at lr * t /
     # warmup_steps until it reaches lr; 0 is a constant lr
     warmup_steps: int = 0
+    # a looped decoder's objective (ops/losses.expected_exit_loss): the
+    # weight of the exit distribution's entropy, taken from the expected loss
+    exit_entropy_weight: float = 0.1
 
 
 def make_lm_mesh(cfg: DistTrainConfig, devices=None) -> Mesh:
@@ -104,10 +111,11 @@ class DistributedLMTrainer:
     each step reads and no step moves (a router's selection bias).
 
     What the trainer asks of a model beside ``init`` and ``apply``:
-    ``head_kernel(params)``, the (D, V) output head (for ``ce_chunk``), and
-    ``STEP_STATS``, the names of the statistics ``apply(...,
-    return_stats=True)`` gives beside its output (none: no such keyword).
-    A step hands them back in one vector with the loss, and the model's
+    ``head_kernel(params)``, the (D, V) output head (for ``ce_chunk`` and
+    for a looped decoder's objective), and ``STEP_STATS``, the names of the
+    statistics a step hands back in one vector with the loss: those
+    ``apply(..., return_stats=True)`` gives beside its output (none: no such
+    keyword), then those of a looped decoder's passes. The model's
     ``count_step_stats(registry, stats, dp)`` files them."""
 
     def __init__(
@@ -198,6 +206,8 @@ class DistributedLMTrainer:
         model = self.model
         opt = self.opt
         ce_chunk = self.cfg.ce_chunk
+        beta = self.cfg.exit_entropy_weight
+        looped = isinstance(model, HybridLM) and model.cfg.total_ut_steps > 1
         # a model that routes hands back, in one vector with the loss, the
         # statistics its STEP_STATS names
         stats_kw = {"return_stats": True} if self.step_stats else {}
@@ -211,6 +221,15 @@ class DistributedLMTrainer:
                                   **stats_kw)
                 return out if stats_kw else (out, None)
 
+            if looped:
+                # every pass's hidden state and gate logit; the objective
+                # runs the head once a pass and adds its own statistics
+                (hid, gates), stats = apply(return_passes=True)
+                head = model.head_kernel(params).astype(hid.dtype)
+                loss, nll, mass = expected_exit_loss(hid, gates, head,
+                                                     targets, beta)
+                return loss, jnp.concatenate(
+                    [stats.astype(jnp.float32), nll, mass])
             if ce_chunk:
                 hid, stats = apply(return_hidden=True)
                 with jax.named_scope("lm.loss"):

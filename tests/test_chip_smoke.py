@@ -63,6 +63,15 @@ def test_stage_hybrid_lm_tiny():
     assert out["assignments"] == 3 * 2 * 2 * 16 * 4
 
 
+def test_stage_looped_lm_tiny():
+    tiny = dict(chip_smoke.LOOPED_LM, vocab_size=64, hidden_size=32,
+                intermediate_size=64, num_attention_heads=4,
+                num_key_value_heads=4, head_dim=8)
+    out = chip_smoke.stage_hybrid_lm(tiny, seq=16, batch=2, steps=3)
+    assert out["attention_impl"] == "dense" and out["mosaic_calls_lowered"] == 0
+    assert out["assignments"] == 0  # nothing routes; 3 steps x 4 passes counted
+
+
 def test_stage_hybrid_mixers_and_the_scan_check_tiny():
     tiny = dict(chip_smoke.HYBRID_MIXERS, vocab_size=64, hidden_size=32,
                 moe_intermediate_size=16, num_attention_heads=4,

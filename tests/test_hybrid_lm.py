@@ -34,6 +34,7 @@ from runners import lfm2_step as runner  # noqa: E402
 
 from fedml_tpu.core import telemetry  # noqa: E402
 from fedml_tpu.models.hybrid_lm import (  # noqa: E402
+    GroupedQueryAttention,
     HybridLM,
     layer_types_of_pattern,
 )
@@ -126,6 +127,35 @@ def test_grouped_rotary_normed_attention_matches_dense_float32(impl):
         close(g, w)
     with pytest.raises(ValueError, match="divide the query heads"):
         multihead_attention(q, k[:, :, :1].repeat(3, 2), v, impl="dense")
+
+
+@pytest.mark.parametrize("rotary,qk_norm", [
+    (True, True), (False, False), (True, False), (False, True)],
+    ids=["lfm2s_layer", "nemotrons_block", "ouros_layer", "norm_alone"])
+def test_attention_states_rotary_and_qk_norm_apart(rotary, qk_norm):
+    """``GroupedQueryAttention``'s two plain facts, each alone and both:
+    the leaves a layer holds (QK-norm's two scales or none) and its output
+    against the reference's pieces put together the same way."""
+    rng = np.random.default_rng(12)
+    u = jnp.asarray(rng.standard_normal((2, 24, 32)), jnp.float32)
+    attn = GroupedQueryAttention(32, 4, 2, 16, 1e6, 1e-5, rotary=rotary,
+                                 qk_norm=qk_norm)
+    variables = attn.init(jax.random.PRNGKey(1), u)
+    p = variables["params"]
+    assert ("q_norm" in p, "k_norm" in p) == (qk_norm, qk_norm)
+    if qk_norm:  # scales off 1, so that the norm's weight shows
+        p = dict(p, q_norm={"scale": 1 + 0.1 * jnp.arange(16.0)},
+                 k_norm={"scale": 1 - 0.02 * jnp.arange(16.0)})
+    proj = lambda name, heads: (u @ p[name]["kernel"]).reshape(2, 24, heads, 16)  # noqa: E731
+    q, k, v = proj("q_proj", 4), proj("k_proj", 2), proj("v_proj", 2)
+    if qk_norm:
+        q = ref._rms(q, p["q_norm"]["scale"], 1e-5)
+        k = ref._rms(k, p["k_norm"]["scale"], 1e-5)
+    if rotary:
+        q, k = ref._rotary(q, 1e6), ref._rotary(k, 1e6)
+    with jax.default_matmul_precision("highest"):
+        want = ref._attention(q, k, v, "f32") @ p["o_proj"]["kernel"]
+        close(attn.apply({"params": p}, u), want)
 
 
 @pytest.fixture(scope="module")

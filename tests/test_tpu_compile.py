@@ -202,3 +202,75 @@ def test_the_mamba2_core_compiles_with_its_scopes_forward_and_backward(
     scores = batch * (T // chunk) * heads * chunk * chunk
     assert scores not in {int(np.prod(shape)) for shape in shapes}
     assert not [shape for shape in shapes if shape[-2:] == (chunk, chunk)]
+
+def test_the_looped_step_compiles_at_the_cells_size_inside_the_chip(
+        topo, monkeypatch):
+    """The whole training step of the Ouro cell (``benchmark/configs/
+    ouro_2_6b.json`` at B = 1, T = 4096: eight layers applied four times,
+    four head products of 49152 columns under the objective's checkpoint,
+    AdamW over 612 M parameters) compiled for one described v5e: it fits the
+    chip's 16 GB, the flash kernels are in it, and the scopes ``ut.pass``,
+    ``ut.head`` and ``ut.exit`` name ops of the forward and of the backward
+    pass, which is what ``ut_stack_ms``, ``ut_head_ms`` and ``ut_exit_ms``
+    read. Nothing is placed or run: the parameters are shapes."""
+    import json
+    import os
+
+    import optax
+
+    from fedml_tpu.models.hybrid_lm import HybridLM
+    from fedml_tpu.parallel.mesh import AXIS_DATA, AXIS_MODEL, AXIS_SEQ
+    from fedml_tpu.parallel.trainer import DistributedLMTrainer, DistTrainConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    monkeypatch.syspath_prepend(bench)
+    from runners import ouro_step  # the cell's own mapping onto DecoderConfig
+
+    with open(os.path.join(bench, "configs", "ouro_2_6b.json")) as f:
+        cfg = json.load(f)
+    B, T = 1, 4096
+    model = ouro_step.decoder_config(cfg)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1, 1),
+                (AXIS_DATA, AXIS_SEQ, AXIS_MODEL))
+    rep = NamedSharding(mesh, P())
+    t = DistributedLMTrainer.__new__(DistributedLMTrainer)
+    t.cfg = DistTrainConfig(exit_entropy_weight=cfg["exit_entropy_beta"])
+    t.mesh = mesh
+    t.model = HybridLM(model, dtype=jnp.bfloat16, mesh=mesh, remat=True)
+    t.step_stats = t.model.STEP_STATS
+    t.opt = optax.adamw(t.cfg.lr, weight_decay=t.cfg.weight_decay)
+    on_chip = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep), tree)
+    params = on_chip(jax.eval_shape(
+        t.model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    assert sum(a.size for a in jax.tree.leaves(params)) == 612_438_017
+    t.param_shardings = jax.tree.map(lambda a: a.sharding, params)
+    t.constants = {}
+    t.batch_sharding = NamedSharding(mesh, P(AXIS_DATA, AXIS_SEQ))
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=t.batch_sharding)
+    compiled = t._build_train_step().lower(
+        params, on_chip(jax.eval_shape(t.opt.init, params)), {}, tokens, tokens
+    ).compile()
+    memory = compiled.memory_analysis()
+    peak = (memory.argument_size_in_bytes + memory.output_size_in_bytes
+            - memory.alias_size_in_bytes + memory.temp_size_in_bytes)
+    # 9.80 GB of parameters, gradients' sums and moments; under the chip's
+    # 16 GB with room for the allocator (PERF.md section 6 has the reading)
+    assert 9.8e9 < peak < 15.0e9, peak
+    text = compiled.as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
+               for line in text.splitlines()
+               if "tpu_custom_call" in line and "custom-call(" in line]
+    assert kernels and all("_local_attention" in n and "ut.pass" in n
+                           for n in kernels), kernels[:3]
+    for scope in ("ut.pass", "ut.head", "ut.exit"):
+        assert [n for n in names if scope in n and "transpose(" in n], scope
+        assert [n for n in names if scope in n and "transpose(" not in n], scope
+    # each scope's name finds its own ops only: the passes hold no op of the
+    # objective, and neither of its two scopes holds the other's
+    assert not [n for n in names if "ut.pass" in n
+                and ("ut.head" in n or "ut.exit" in n or "lm.loss" in n)]
+    assert not [n for n in names if "ut.head" in n and "ut.exit" in n]
