@@ -94,8 +94,10 @@ LOOPED_LM = dict(
 # the Nemotron cell's scan: (batch, T, heads, head width, groups, state, chunk)
 SSD_SHAPE = (1, 8192, 64, 64, 8, 128, 128)
 # the LFM2 cell's expert layer: 16,384 tokens x top-4 over 64 experts of
-# which 8 are held, so 16,640 buffer rows for 65,536 slots; rows 2048 wide
-ROW_MOVES = (16384, 4, 64, 8, 2048)
+# which 8 are held, so 16,640 buffer rows for 65,536 slots; rows 2048 wide,
+# a slab as they lie. And the Nemotron cell's: 8,192 x top-6 over 128, 8
+# held, 6,400 rows for 49,152 slots; rows 2688 wide, in a padded slab
+ROW_MOVES = ((16384, 4, 64, 8, 2048), (8192, 6, 128, 8, 2688))
 GRAM_SHAPE = (1000, 4096)     # cohort rows x flattened update width
 GRAM_REFUSED = (10, 1_000_000)  # wider than full-row tiles fit in VMEM
 QUANT_SHAPE = (1000, 65536)
@@ -952,9 +954,10 @@ def main() -> int:
     scan = run("ssd_vs_sequential", check_ssd_vs_sequential, *SSD_SHAPE)
     require((scan["impl"], scan["kernel"]) == ("chunked", "pallas"),
             f"the scan took {scan['impl']} by {scan['kernel']}")
-    moves = run("row_moves", check_row_moves, *ROW_MOVES)
-    require(moves["mosaic_calls_lowered"] == 2,  # rows out, rows back
-            f"the row moves did not run as compiled Mosaic calls: {moves}")
+    for shape in ROW_MOVES:
+        moves = run("row_moves", check_row_moves, *shape)
+        require(moves["mosaic_calls_lowered"] == 2,  # rows out, rows back
+                f"the row moves did not run as compiled Mosaic calls: {moves}")
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
     require(fvd["mosaic_calls_lowered"] >= 2, "flash check ran interpreted")

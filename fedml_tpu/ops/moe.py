@@ -39,14 +39,16 @@ transpose. They copy a row by one DMA from indices they are handed, do work
 by the rows held and read nothing for a stranger, so no array of ``N * k``
 rows exists, forward or backward (XLA:TPU ran such row gathers at a fifth of
 HBM's rate and the combine ran one over all ``N * k`` slots: PERF.md section
-6). What XLA still does here: the reshape that makes a row one slab, the
+6). What XLA still does here: the reshape that makes a row one slab (a pad
+before it where the row fills none: PR 37, a bf16 row of 2688), the
 backward's scale and dot of the rows moved, and scalar gathers (indices,
 weights, the count of rows placed). **The rule**, applied at trace time to
 what the code sees and to nothing else (``_row_move_impl``): the kernels
-where a row makes whole 128-lane 32-bit words in its dtype and the layer
+where a row is whole 128-lane sublanes of bf16 or float32 and the layer
 has at least ``row_move.MIN_ROWS`` tokens; XLA's gather (the same algorithm,
-its own fallback) at any other width, and under that many tokens, where a
-kernel's set-up buys nothing (a model's 8-token init, a toy layer).
+its own fallback) at a width with a lane to spare, and under that many
+tokens, where a kernel's set-up buys nothing (a model's 8-token init, a toy
+layer).
 ``fedml_moe_row_move_total{impl="pallas"|"xla", use="rows"|"tokens"}``
 reports the choice, once a call site a trace, for layers of ``MIN_ROWS``
 tokens and more. ``dropless_moe`` is jitted, so a model's equal layers, and
@@ -244,8 +246,8 @@ def _moves(order, place, n_held, rows: int, impl):
 def _row_move_impl(x) -> Optional[str]:
     """What moves the rows of a layer whose tokens are ``x`` (N, D), chosen
     at trace time from what the code sees and nothing else: ``"pallas"``,
-    the kernels of ``ops/pallas/row_move.py``, where a row makes whole
-    128-lane words; ``"xla"``, XLA's gather, where it does not; None, the
+    the kernels of ``ops/pallas/row_move.py``, where a row is whole
+    128-lane sublanes; ``"xla"``, XLA's gather, where it is not; None, the
     same gather, under ``MIN_ROWS`` tokens (a model's 8-token init, a toy
     layer), where a kernel's set-up buys nothing and there is nothing to
     report."""
@@ -344,8 +346,24 @@ def route_top_k(x, gate, bias, top_k: int):
                 w / (w.sum(axis=-1, keepdims=True) + 1e-6))
 
 
+def _one_mesh_context(f):
+    """``f`` under the abstract mesh there is, set by value. jax keys a
+    jitted function's trace on the ambient mesh context, and where no mesh
+    is set the first trace of a body sees none while every later pass over
+    its jaxpr (the jvp, the transposes) sees the empty mesh: equal in
+    meaning, another key, so the launchers and ``gmm`` under the layer were
+    traced once a pass (PR 37: four traces of the tokens kernel's body for
+    two sizes, 0.15 s each on a CPU core). Inside, all passes share one."""
+    @functools.wraps(f)
+    def in_context(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+            return f(*args, **kwargs)
+    return in_context
+
+
 @functools.partial(jax.jit,
                    static_argnames=("top_k", "experts_held", "scale"))
+@_one_mesh_context
 def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
                  experts_held: Tuple[int, int], scale: float = 1.0):
     """The held experts' part of a routed expert layer. x: (N, D); gate: (D,

@@ -16,7 +16,11 @@ tiles of its last two dims, and a row of a (rows, D) array is one sublane
 of D / 128 such tiles. So the source is first laid out as *slabs*
 (``to_slabs``: a reshape to (rows, D / 128, 128), which XLA:TPU runs as one
 streaming copy and Mosaic never sees), a row the block of its own tiles,
-contiguous in HBM. A grid step starts one DMA for each row it needs
+contiguous in HBM. A row whose D / 128 sublanes are no height both compilers
+take (``_slab_sublanes``: a bf16 row of 2688 is 21, 10.5 words) lies in the
+next taller slab, zero sublanes after it (PR 37: a pad before the reshape,
+still XLA's), and the kernels read back the row's own chunks and no more. A
+grid step starts one DMA for each row it needs
 (indices and counts arrive by scalar prefetch), all in flight at once on one
 semaphore, waits for them, and reads the landed slabs back a 128-lane
 column at a time (a strided sublane load) into the ordinary lane-dense
@@ -35,7 +39,10 @@ only, as the flash launchers are: equal shapes share one lowering.
 
 What the chip said (PR 32): the kernels are bound by the scalar core issuing
 the copies (about 40 ns a row), not by the copies' latency: starting a
-tile's copies while the tile before was written out changed nothing.
+tile's copies while the tile before was written out changed nothing. And
+(PR 37, rows of 2688 in bf16) the bytes of a copy do count a little: the
+rows kernel took 0.128 ms over 6,400 rows in slabs of 32 sublanes and 0.093
+in slabs of 24, the tokens kernel 0.62 in both.
 
 Off the TPU the kernels run in interpret mode, as every kernel of the
 package.
@@ -61,31 +68,42 @@ MIN_ROWS = 256
 
 
 def _slab_sublanes(D: int, dtype) -> int | None:
-    """Sublanes D / 128 of a row's slab, or None where a row makes no slab
-    the kernels can read: whole 128-lane sublanes that pair up into 32-bit
-    words, 1, 2, 4 or a multiple of 8 of them (under 8 word-sublanes XLA
-    and Mosaic agree on a tile as tall as the slab only at powers of two)."""
+    """Sublanes of a row's slab, or None where a row makes no slab the
+    kernels can read (a width with a lane to spare, a dtype they do not
+    unpack). A row is D / 128 sublanes of 128 lanes; its slab is as tall as
+    the next height both compilers take: 1, 2 or 4 32-bit words, or a
+    multiple of a tile's 8 sublanes (under 8 XLA and Mosaic agree on a tile
+    as tall as the slab only at powers of two). So a bf16 row of 2048 is its
+    own 16 sublanes, and one of 2688, 21 sublanes = 10.5 words, lies in a
+    slab of 24 whose last three the kernels never read."""
     dtype = jnp.dtype(dtype)
     if dtype not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return None
-    if D % (LANES * 4 // dtype.itemsize):
+    if D <= 0 or D % LANES:
         return None
-    words = D // LANES * dtype.itemsize // 4
-    return D // LANES if words in (1, 2, 4) or words % 8 == 0 else None
+    sublanes, per_word = D // LANES, 4 // dtype.itemsize
+    for words in (1, 2, 4):
+        if sublanes <= words * per_word:
+            return words * per_word
+    return -(-sublanes // 8) * 8
 
 
 def row_move_shapes_ok(D: int, dtype) -> bool:
     """Whether rows of this width and dtype make slabs the kernels can
-    move: whole 128-lane words (D % 128 == 0 in float32, D % 256 == 0 in
-    bf16)."""
+    move: whole 128-lane sublanes (D % 128 == 0) in bf16 or float32."""
     return _slab_sublanes(D, dtype) is not None
 
 
 def to_slabs(x: jax.Array) -> jax.Array:
-    """(rows, D) -> (rows, D / 128, 128), a row one contiguous slab. Plain
-    XLA: one copy at the rate of HBM, no kernel to set up."""
+    """(rows, D) -> (rows, S, 128), a row one contiguous slab of S =
+    ``_slab_sublanes`` sublanes: a plain reshape where the row fills its
+    slab, the row padded with zero sublanes first where it does not. Plain
+    XLA either way: one copy at the rate of HBM, no kernel to set up."""
     rows, D = x.shape
-    return x.reshape(rows, _slab_sublanes(D, x.dtype), LANES)
+    sublanes = _slab_sublanes(D, x.dtype)
+    if sublanes * LANES != D:
+        x = jnp.pad(x, ((0, 0), (0, sublanes * LANES - D)))
+    return x.reshape(rows, sublanes, LANES)
 
 
 # The kernels' bodies are written in ``lax`` primitives: every ``jnp`` call
@@ -98,17 +116,27 @@ def _word_view(buf):
     return buf if buf.dtype == jnp.float32 else buf.bitcast(jnp.uint32)
 
 
-def _columns(words, s: int, *at):
+def _row_words(words, chunks: int) -> int:
+    """The word-sublanes of a slab in ``words`` (``_word_view``) that hold
+    the row's ``chunks`` 128-lane chunks: the slab may be taller."""
+    return chunks if words.dtype == jnp.float32 else -(-chunks // 2)
+
+
+def _columns(words, s: int, chunks: int, *at):
     """The float32 128-lane columns that sublane ``s`` of the slabs in
     ``words`` (``_word_view``), at leading index ``at``, holds: [(chunk of
     the row, (tile, 128) float32)]. A float32 sublane is chunk ``s``; a
-    uint32 one holds chunk 2s low and 2s + 1 high, widened exactly."""
+    uint32 one holds chunk 2s low and 2s + 1 high, widened exactly (the
+    high half of the last word is no chunk of a row of an odd ``chunks``)."""
     w = words[(*at, slice(None), s, slice(None))]
     if w.dtype == jnp.float32:
         return [(s, w)]
     f32 = lambda u: lax.bitcast_convert_type(u, jnp.float32)  # noqa: E731
-    return [(2 * s, f32(lax.shift_left(w, lax.full_like(w, 16)))),
-            (2 * s + 1, f32(lax.bitwise_and(w, lax.full_like(w, 0xFFFF0000))))]
+    columns = [(2 * s, f32(lax.shift_left(w, lax.full_like(w, 16))))]
+    if 2 * s + 1 < chunks:
+        columns.append((2 * s + 1, f32(lax.bitwise_and(
+            w, lax.full_like(w, 0xFFFF0000)))))
+    return columns
 
 
 def _rows_kernel(index_ref, n_ref, src_ref, out_ref, buf, sem, *, tile: int,
@@ -143,9 +171,9 @@ def _rows_kernel(index_ref, n_ref, src_ref, out_ref, buf, sem, *, tile: int,
         held = lax.lt(lax.broadcasted_iota(jnp.int32, (tile, LANES), 0),
                       lax.broadcast_in_dim(n, (tile, LANES), ()))
         zeros = jnp.zeros((tile, LANES), jnp.float32)
-        words = _word_view(buf)
-        for s in range(words.shape[-2]):
-            for c, part in _columns(words, s):
+        words, chunks = _word_view(buf), out_ref.shape[1] // LANES
+        for s in range(_row_words(words, chunks)):
+            for c, part in _columns(words, s, chunks):
                 # rows past n hold what an earlier step left: selected away
                 out_ref[:, c * LANES:(c + 1) * LANES] = (
                     lax.convert_element_type(lax.select(held, part, zeros),
@@ -172,8 +200,8 @@ def _tokens_kernel(list_ref, starts_ref, src_ref, w_ref, out_ref, buf, sem,
     jax.lax.fori_loop(first, last, start, None)
     jax.lax.fori_loop(first, last, wait, None)
     zeros = jnp.zeros((tile, LANES), jnp.float32)
-    words = _word_view(buf)
-    for s in range(words.shape[-2]):
+    words, chunks = _word_view(buf), out_ref.shape[1] // LANES
+    for s in range(_row_words(words, chunks)):
         acc = {}
         for j in range(k):
             # a slot's weight is 0 where it is not held: nothing landed
@@ -182,7 +210,7 @@ def _tokens_kernel(list_ref, starts_ref, src_ref, w_ref, out_ref, buf, sem,
             # vregs, cost 0.43 ms a call in spills: PR 32's chip runs.)
             w = lax.broadcast_in_dim(w_ref[:, j:j + 1], (tile, LANES), (0, 1))
             keep = lax.ne(w, zeros)
-            for c, part in _columns(words, s, j):
+            for c, part in _columns(words, s, chunks, j):
                 part = lax.select(keep, lax.mul(w, part), zeros)
                 acc[c] = part if j == 0 else lax.add(acc[c], part)
         for c, total in acc.items():

@@ -174,8 +174,10 @@ def test_facade_shrinks_the_client_mesh_only_where_the_engine_cannot_pad(
     assert dict(SimulatorTPU(args).mesh.shape) == {"client": axis}
 
 
-def test_row_moves_check_tiny_interpret():
-    out = chip_smoke.check_row_moves(64, 4, 16, 4, 256)
-    assert out["slots"] == 256 and out["rows"] == 512
+@pytest.mark.parametrize("width", [256, 2688], ids=["a_slab", "a_padded_slab"])
+def test_row_moves_check_tiny_interpret(width):
+    assert {shape[-1] for shape in chip_smoke.ROW_MOVES} == {2048, 2688}
+    out = chip_smoke.check_row_moves(64, 4, 16, 4, width)
+    assert out["slots"] == 256 and out["rows"] == 512 and out["width"] == width
     assert 0 < out["rows_held"] < out["rows"]
     assert out["mosaic_calls_lowered"] == 0     # interpreted on the CPU

@@ -251,13 +251,15 @@ def test_one_compiled_program_whatever_the_seed_or_the_routing(layer):
 
 
 @pytest.mark.parametrize("tokens,width,impl", [
-    (256, 2048, "pallas"), (256, 2000, "xla"), (32, 2048, None)],
-    ids=["whole_words", "no_whole_words", "too_few_rows"])
+    (256, 2048, "pallas"), (256, 2688, "pallas"), (256, 2000, "xla"),
+    (32, 2048, None)],
+    ids=["a_slab", "a_padded_slab", "a_lane_to_spare", "too_few_rows"])
 def test_the_row_moves_are_counted_by_what_the_shapes_allow(tokens, width, impl):
     """``fedml_moe_row_move_total{impl, use}`` counts, at trace time, each
     site where the layer moves rows: by the Pallas kernel at a width of
-    whole 128-lane words (the LFM2 cell's 2048), by XLA's gather at one
-    that is not; both uses, forward and backward; nothing else chooses.
+    whole 128-lane sublanes (the LFM2 cell's 2048, a slab as it lies; the
+    Nemotron cell's 2688, in a padded slab), by XLA's gather at one that
+    is not; both uses, forward and backward; nothing else chooses.
     Under ``MIN_ROWS`` tokens (a model's 8-token init) XLA's gather runs
     and nothing is counted: no kernel could have paid there."""
     registry = telemetry.get_registry()

@@ -122,14 +122,21 @@ def test_the_relu2_expert_block_compiles_at_widths_that_are_no_tile_multiples(
     transpose by rows (where k and n change places) and to its transpose by
     weights: a k or n tile that is neither a multiple of 128 nor that
     dimension whole in all three is refused by Mosaic only here, at the
-    backward's compile (PERF.md section 6, PR 34). A bf16 row of 2688 is
-    10.5 words of 128 lanes, so XLA's gather moves the rows: no row-move
-    kernel, and every Mosaic call is a grouped product under its scope."""
+    backward's compile (PERF.md section 6, PR 34). A bf16 row of 2688 is 21
+    sublanes, 10.5 words, and fills no slab the row-move kernels read: since
+    PR 37 it lies in one padded up to a height they do, so this is where
+    Mosaic's verdict on the padded slab is had without a chip. As at the
+    LFM2 cell's widths: the rows move inside the kernels at both buffer
+    sizes, no array of N * k rows exists in the layer's layout or the
+    slabs', what XLA still gathers is scalars, and every Mosaic call names
+    its scope, the backward's row moves too."""
     from fedml_tpu.ops.moe import GMM_TILE_AT, _gmm_tiling, dropless_moe
+    from fedml_tpu.ops.pallas.row_move import _slab_sublanes
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     N, D, F, E, held, k = 1024, 2688, 1856, 128, 8, 6
     assert {(D, F), (F, D)} <= set(GMM_TILE_AT)
+    assert _slab_sublanes(D, jnp.bfloat16) > D // 128   # a padded slab
     for shape in ((D, F), (F, D)):
         tile = _gmm_tiling(4096, *shape)
         assert tile[0] == 256 and all(t % 128 == 0 for t in tile[1:]), tile
@@ -146,14 +153,23 @@ def test_the_relu2_expert_block_compiles_at_widths_that_are_no_tile_multiples(
         sds((N, D), jnp.bfloat16), sds((D, E), jnp.float32),
         sds((E,), jnp.float32), sds((held, D, F), jnp.float32),
         sds((held, F, D), jnp.float32)).compile().as_text()
+    assert f"[{N},{D}]" in text
+    assert not re.findall(rf"\[{N * k},{D}\]|\[{N},{k},{D}\]|\[{N * k},\d+,128\]",
+                          text)
+    gathers = re.findall(r"= (\S+) gather\(", text)
+    assert gathers and not [g for g in gathers if f"{D}]" in g or ",128]" in g]
     kernels = [re.search(r'op_name="([^"]*)"', line).group(1)
                for line in text.splitlines() if "tpu_custom_call" in line]
-    # two buffer sizes x (two products, both again under that size's
-    # checkpoint, and each one's two transposes)
-    assert len(kernels) == 2 * (2 + 2 + 4)
-    assert all("moe.experts" in name for name in kernels)
-    assert not [name for name in kernels if "rows_from_tokens" in name
-                or "tokens_from_rows" in name]
+    shuffle = [name for name in kernels if re.search(
+        r"jit\(_(rows_from_tokens|tokens_from_rows)\)", name)]
+    # two buffer sizes x (the five row moves of the LFM2 test above; two
+    # products, both again under that size's checkpoint, and each one's two
+    # transposes)
+    assert len(shuffle) == 2 * 5 and len(kernels) == 2 * (5 + 8)
+    assert all("moe.shuffle." in name for name in shuffle)
+    assert sum("transpose(jvp" in name and "rematted" not in name
+               for name in shuffle) == 2 * 2
+    assert all("moe.experts" in name for name in kernels if name not in shuffle)
 
 
 @pytest.mark.parametrize("batch,T", [(2, 1024), (1, 8192)],
