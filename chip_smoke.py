@@ -145,50 +145,30 @@ def memory_stats() -> list:
     return out
 
 
-class CompileMeter:
-    """What compiling cost, from jax's own monitoring events: programs
-    requested, persistent-cache hits, seconds in XLA (``xla_s``, with the
-    count of compiles that took a second or more — the ones jax persists)
-    and seconds loading cached executables. A warm run shows
-    ``xla_over_1s == 0``: nothing the cache could hold was compiled again;
-    what remains are programs under jax's one-second persistence threshold.
-    Compiles happen on the calling thread, so a hit event is followed by
-    its own duration event."""
+def compiles_since(seen: dict, t0: float) -> dict:
+    """What compiling cost since ``t0`` (wall clock), from the program's own
+    telemetry: programs XLA compiled and programs loaded from the persistent
+    cache (``fedml_jax_programs_total``, less the counts in ``seen``), and
+    from the ``jax.compile`` spans since ``t0`` the seconds in XLA
+    (``xla_s``, with the count of compiles that took a second or more — the
+    ones jax persists) and the seconds loading cached executables. A warm
+    run shows ``xla_over_1s == 0``: nothing the cache could hold was
+    compiled again; what remains are programs under jax's one-second
+    persistence threshold. A compile is a span only inside an open span:
+    ``main`` runs every stage inside one."""
+    from fedml_tpu.core.telemetry import get_registry, get_tracer
 
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
-    _BACKEND = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self):
-        import jax
-
-        self.totals = {"requests": 0, "cache_hits": 0, "xla_s": 0.0,
-                       "xla_over_1s": 0, "cache_load_s": 0.0}
-        self._hit_pending = False
-        jax.monitoring.register_event_listener(self._on_event)
-        jax.monitoring.register_event_duration_secs_listener(
-            self._on_duration)
-
-    def _on_event(self, event: str, **_):
-        if event == self._REQUEST:
-            self.totals["requests"] += 1
-        elif event == self._HIT:
-            self.totals["cache_hits"] += 1
-            self._hit_pending = True
-
-    def _on_duration(self, event: str, secs: float, **_):
-        if event != self._BACKEND:
-            return
-        if self._hit_pending:
-            self._hit_pending = False
-            self.totals["cache_load_s"] += secs
-        else:
-            self.totals["xla_s"] += secs
-            self.totals["xla_over_1s"] += secs >= 1.0
-
-    def since(self, seen: dict) -> dict:
-        return {k: round(v - seen.get(k, 0), 2)
-                for k, v in self.totals.items()}
+    counters = get_registry().snapshot()["counters"]
+    out = {source: counters.get(
+        f"fedml_jax_programs_total{{source={source}}}", 0) - seen.get(source, 0)
+        for source in ("compiled", "cache")}
+    compiles = [s for s in get_tracer().finished_spans()
+                if s["name"] == "jax.compile" and s["start"] >= t0]
+    xla = [s["duration"] for s in compiles if not s["cached"]]
+    out.update(xla_s=round(sum(xla), 2), xla_over_1s=sum(d >= 1.0 for d in xla),
+               cache_load_s=round(sum(s["duration"] for s in compiles
+                                      if s["cached"]), 2))
+    return out
 
 
 class LogTap(logging.Handler):
@@ -876,17 +856,22 @@ def main() -> int:
         print(f"chip_smoke: the fedml_tpu package is not importable from "
               f"here ({e}); run from the root of a checkout", file=sys.stderr)
         return 2
+    from fedml_tpu.core.telemetry import get_tracer, install_jax_collectors
+
     cache_dir = configure_compile_cache()   # before the first compile
-    meter = CompileMeter()
+    install_jax_collectors()
     logging.basicConfig(level=logging.WARNING, stream=sys.stderr)
-    t_start = time.perf_counter()
+    t_start, totals = time.perf_counter(), {}
 
     def run(name, fn, *args, **kwargs):
-        seen = dict(meter.totals)
-        t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
+        seen = compiles_since({}, time.time())
+        wall0, t0 = time.time(), time.perf_counter()
+        with get_tracer().span("chip_smoke." + name):
+            out = fn(*args, **kwargs)
         out = {**out, "wall_s": round(time.perf_counter() - t0, 2),
-               "compile": meter.since(seen)}
+               "compile": compiles_since(seen, wall0)}
+        for key, value in out["compile"].items():
+            totals[key] = round(totals.get(key, 0) + value, 2)
         print(f"[chip_smoke] {name}: {json.dumps(out)}", flush=True)
         return out
 
@@ -981,7 +966,7 @@ def main() -> int:
                 f"kernel did not run as a compiled Mosaic call: {res}")
 
     print(f"[chip_smoke] total: wall {time.perf_counter() - t_start:.1f}s, "
-          f"compile {json.dumps(meter.since({}))}", flush=True)
+          f"compile {json.dumps(totals)}", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev["platform"], "kind": dev["device_kind"],
         "count": dev["device_count"]}}), flush=True)

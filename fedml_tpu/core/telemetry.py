@@ -16,8 +16,10 @@ fields into a subsystem all layers report through:
 - **Exporters** — JSONL (``MetricsSink``), a Prometheus textfile writer
   (node-exporter textfile-collector format), and the
   ``python -m fedml_tpu.cli telemetry summary`` pretty-printer.
-- **Collectors** — JAX compilation-event listeners (``jax.monitoring``)
-  and a daemon-thread sampler for ``SysStats`` + ``device.memory_stats()``.
+- **Collectors** — jax's compile phases (``jax.monitoring``) as spans
+  ``jax.trace`` / ``jax.lower`` / ``jax.compile`` and the counter
+  ``fedml_jax_programs_total{source}``, and a daemon-thread sampler for
+  ``SysStats`` + ``device.memory_stats()``.
 
 The defining constraint is overhead (<1% of round wall-clock): when
 disabled, every accessor returns a shared null metric whose methods are
@@ -474,6 +476,17 @@ def _trace_annotation():
     return _annotation_cls
 
 
+def _child_context(parent: Optional[TraceContext],
+                   round_idx: Optional[int]) -> TraceContext:
+    """A new span's context: its parent's trace and round, or a new trace."""
+    return TraceContext(
+        trace_id=parent.trace_id if parent else _new_id(),
+        span_id=_new_id(),
+        round_idx=(int(round_idx) if round_idx is not None
+                   else (parent.round_idx if parent else None)),
+    )
+
+
 class Tracer:
     """Span recorder. Finished spans land in a bounded ring (inspection /
     tests), the JSONL sink when configured, and the
@@ -494,12 +507,7 @@ class Tracer:
             yield None
             return
         parent = _current.get()
-        ctx = TraceContext(
-            trace_id=parent.trace_id if parent else _new_id(),
-            span_id=_new_id(),
-            round_idx=(int(round_idx) if round_idx is not None
-                       else (parent.round_idx if parent else None)),
-        )
+        ctx = _child_context(parent, round_idx)
         token = _current.set(ctx)
         annotation_cls = _trace_annotation()
         annotation = (annotation_cls(PROFILE_PREFIX + name)
@@ -519,33 +527,51 @@ class Tracer:
             if annotation is not None:
                 annotation.__exit__(None, None, None)
             _current.reset(token)
-            rec = {
-                "kind": "span",
-                "name": name,
-                "trace_id": ctx.trace_id,
-                "span_id": ctx.span_id,
-                "parent_span_id": parent.span_id if parent else None,
-                "round_idx": ctx.round_idx,
-                "start": wall0,
-                "duration": duration,
-                "status": status,
-            }
-            if attrs:
-                rec.update(attrs)
-            tenant = _tenant_var.get()
-            if tenant is not None:
-                rec["tenant"] = tenant
-            if len(self._finished) == self._finished.maxlen:
-                self.dropped += 1
-                self.registry.counter("fedml_spans_dropped_total").inc()
-            self._finished.append(rec)
-            if self.sink is not None:
-                try:
-                    self.sink.emit(rec)
-                except Exception:  # a full disk must not fail the traced op
-                    logging.exception("telemetry: span sink emit failed")
-            self.registry.histogram(
-                "fedml_span_seconds", span=name).observe(rec["duration"])
+            self._finish(name, ctx, parent, wall0, duration, status, attrs)
+
+    def record(self, name: str, start: float, end: float, **attrs) -> None:
+        """Record a span after the fact: ``start`` and ``end`` are wall-clock
+        seconds (``time.time()``) of an interval that has already passed,
+        under the current context as its parent. It lands where a span does
+        (ring, sink, ``fedml_span_seconds``) but leaves no profiler
+        annotation: the interval is over before the record is made."""
+        if not _state.enabled:
+            return
+        parent = _current.get()
+        self._finish(name, _child_context(parent, None), parent, start,
+                     end - start, "ok", attrs)
+
+    def _finish(self, name: str, ctx: TraceContext,
+                parent: Optional[TraceContext], start: float,
+                duration: float, status: str, attrs: Dict[str, Any]) -> None:
+        """One finished span into the ring, the sink and the histogram."""
+        rec = {
+            "kind": "span",
+            "name": name,
+            "trace_id": ctx.trace_id,
+            "span_id": ctx.span_id,
+            "parent_span_id": parent.span_id if parent else None,
+            "round_idx": ctx.round_idx,
+            "start": start,
+            "duration": duration,
+            "status": status,
+        }
+        if attrs:
+            rec.update(attrs)
+        tenant = _tenant_var.get()
+        if tenant is not None:
+            rec["tenant"] = tenant
+        if len(self._finished) == self._finished.maxlen:
+            self.dropped += 1
+            self.registry.counter("fedml_spans_dropped_total").inc()
+        self._finished.append(rec)
+        if self.sink is not None:
+            try:
+                self.sink.emit(rec)
+            except Exception:  # a full disk must not fail the traced op
+                logging.exception("telemetry: span sink emit failed")
+        self.registry.histogram(
+            "fedml_span_seconds", span=name).observe(duration)
 
     def finished_spans(self) -> List[Dict[str, Any]]:
         return list(self._finished)
@@ -783,11 +809,85 @@ def write_prometheus(path: str, registry: Optional[MetricsRegistry] = None) -> N
 
 _jax_collectors_installed = False
 
+# jax's own time spans of a program's three phases (jax 0.9,
+# ``jax._src.dispatch``), each with the ``fun_name`` it was made for
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_PHASES = {
+    _JAX_TRACE: "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+# fired inside the backend phase, on its thread, where the persistent cache
+# hands the executable back instead of XLA compiling it
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+class _JaxThread(threading.local):
+    """Per thread: the cache-hit flag, and a count for each open trace."""
+
+    cache_hit = False
+
+    def __init__(self):
+        self.traces: List[int] = []
+
+
+_jax_thread = _JaxThread()
+
+
+def _jax_fun(fun_name: str) -> str:
+    """``jit(train_step)`` -> ``train_step``: the three phases of one
+    program under one name."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_jax_event(event: str, **kw) -> None:
+    if event == _JAX_CACHE_HIT:
+        _jax_thread.cache_hit = True
+
+
+def _on_jax_phase_start(event: str, value: float, **kw) -> None:
+    if event == _JAX_TRACE:
+        _jax_thread.traces.append(0)
+
+
+def _on_jax_phase(event: str, start: float, end: float, **kw) -> None:
+    """One phase of a program, at its end. Every backend phase is counted in
+    ``fedml_jax_programs_total{source}``. jax reports a trace for every call
+    of a jitted function made while tracing, its trace cache's hits too (a
+    step of 24 layers makes thousands), so only the outermost trace becomes a
+    span, with ``traces`` = the trace phases inside it, itself included. A
+    phase becomes a span (a child of the current one) only while a span is
+    open: the eager ops and small jits run outside the program's spans leave
+    the ring alone."""
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    attrs: Dict[str, Any] = {"fun": _jax_fun(str(kw.get("fun_name", "")))}
+    if name == "jax.trace":
+        stack = _jax_thread.traces
+        traces = 1 + (stack.pop() if stack else 0)
+        if stack:  # counted in the trace that holds it
+            stack[-1] += traces
+            return
+        attrs["traces"] = traces
+    elif name == "jax.compile":
+        attrs["cached"] = _jax_thread.cache_hit
+        _jax_thread.cache_hit = False
+        if _state.enabled:
+            _state.registry.counter(
+                "fedml_jax_programs_total",
+                source="cache" if attrs["cached"] else "compiled").inc()
+    if _state.enabled and _current.get() is not None:
+        _state.tracer.record(name, start, end, **attrs)
+
 
 def install_jax_collectors() -> bool:
-    """Count XLA compilation events via ``jax.monitoring`` listeners.
-    Registration is global and permanent in jax, so this installs once per
-    process; the listeners consult the enabled flag at fire time."""
+    """Turn jax's compile phases into spans and one counter (above) via
+    ``jax.monitoring`` listeners. Registration is global and permanent in
+    jax, so this installs once per process; the listeners consult the
+    enabled flag at fire time."""
     global _jax_collectors_installed
     if _jax_collectors_installed:
         return True
@@ -795,20 +895,10 @@ def install_jax_collectors() -> bool:
         from jax import monitoring
     except Exception:  # jax absent/old — telemetry must not require it
         return False
-
-    def _on_event(event: str, **kw) -> None:
-        if _state.enabled and "compil" in event:
-            _state.registry.counter(
-                "fedml_jax_compilation_events_total", event=event).inc()
-
-    def _on_duration(event: str, duration: float, **kw) -> None:
-        if _state.enabled and "compil" in event:
-            _state.registry.histogram(
-                "fedml_jax_compilation_seconds", event=event).observe(duration)
-
     try:
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_jax_event)
+        monitoring.register_scalar_listener(_on_jax_phase_start)
+        monitoring.register_event_time_span_listener(_on_jax_phase)
     except Exception:
         return False
     _jax_collectors_installed = True

@@ -24,7 +24,7 @@ This module is the forensic layer on top:
   against a rolling in-run baseline (median/MAD, warmup-gated), annotated
   into ``history[i]["phase_anomalies"]`` and counted in
   ``fedml_phase_anomalies_total{phase=}``, plus a recompile detector that
-  flags post-warmup ``jax.monitoring`` compilation events with the round
+  flags post-warmup programs (``fedml_jax_programs_total``) with the round
   that triggered them.
 
 Everything is OFF by default: with the plane disabled every hook is a
@@ -474,10 +474,10 @@ def _detector_key() -> Tuple[str, int]:
 
 
 def _recompile_delta(key: Tuple[str, int]) -> float:
-    """Post-warmup delta of ``fedml_jax_compilation_events_total`` since the
-    last round — a nonzero value names the round that re-triggered XLA."""
-    total = telemetry.get_registry().counter_total(
-        "fedml_jax_compilation_events_total")
+    """Post-warmup delta of ``fedml_jax_programs_total`` (programs XLA
+    compiled or loaded from the cache) since the last round — a nonzero
+    value names the round that re-triggered XLA."""
+    total = telemetry.get_registry().counter_total("fedml_jax_programs_total")
     prev = _plane.compile_baseline.get(key)
     _plane.compile_baseline[key] = total
     return 0.0 if prev is None else max(0.0, total - prev)
@@ -494,8 +494,7 @@ def absorb_planned_compiles(rank: int = 0) -> None:
     shape/donation instability" whether rounds are fused or not."""
     if not _plane.active or not telemetry.enabled():
         return
-    total = telemetry.get_registry().counter_total(
-        "fedml_jax_compilation_events_total")
+    total = telemetry.get_registry().counter_total("fedml_jax_programs_total")
     _plane.compile_baseline[
         (telemetry.current_tenant() or "", int(rank))] = total
 

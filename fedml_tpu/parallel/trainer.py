@@ -32,7 +32,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..core.telemetry import get_registry, get_tracer
+from ..core.telemetry import get_registry, get_tracer, install_jax_collectors
 from ..models.hybrid_lm import DecoderConfig, HybridLM
 from ..models.transformer import TransformerLM
 from ..ops.losses import (
@@ -131,6 +131,9 @@ class DistributedLMTrainer:
         seed: int = 0,
         model: Optional[DecoderConfig] = None,
     ):
+        # jax's compile phases become spans: the first step's trace,
+        # lowering and compile are children of its ``lm.dispatch``
+        install_jax_collectors()
         tracer = get_tracer()
         with tracer.span("lm.trainer_init"):
             self.cfg = cfg

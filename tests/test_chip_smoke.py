@@ -181,3 +181,27 @@ def test_row_moves_check_tiny_interpret(width):
     assert out["slots"] == 256 and out["rows"] == 512 and out["width"] == width
     assert 0 < out["rows_held"] < out["rows"]
     assert out["mosaic_calls_lowered"] == 0     # interpreted on the CPU
+
+
+def test_compiles_since_reads_the_programs_counter_and_compile_spans():
+    import time
+
+    import jax.numpy as jnp
+
+    from fedml_tpu.core import telemetry
+
+    telemetry.configure(enabled=True, reset=True)
+    assert telemetry.install_jax_collectors()
+    seen = chip_smoke.compiles_since({}, time.time())
+    wall0 = time.time()
+    with telemetry.get_tracer().span("chip_smoke.tiny"):
+        jax.jit(lambda x: x * 11.0 - 2.0)(jnp.ones(5)).block_until_ready()
+    got = chip_smoke.compiles_since(seen, wall0)
+    assert got["compiled"] >= 1 and got["cache"] == 0
+    assert got["xla_s"] >= 0 and got["xla_over_1s"] == 0
+    assert got["cache_load_s"] == 0
+    assert chip_smoke.compiles_since(
+        {"compiled": got["compiled"] + seen["compiled"]}, time.time()) == {
+            "compiled": 0, "cache": 0, "xla_s": 0, "xla_over_1s": 0,
+            "cache_load_s": 0}
+    telemetry.configure(enabled=True, reset=True)

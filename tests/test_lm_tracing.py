@@ -36,10 +36,17 @@ def _trainer(**cfg):
 
 @pytest.fixture(scope="module")
 def built():
-    """One tiny trainer, with the spans its construction left."""
+    """One tiny trainer, with the spans its construction left and those of
+    its first three steps (a list a step): every later step is warm."""
     telemetry.configure(enabled=True, reset=True)
     trainer = _trainer()
-    return trainer, telemetry.get_tracer().finished_spans()
+    init = telemetry.get_tracer().finished_spans()
+    steps = []
+    for _ in range(3):
+        telemetry.configure(enabled=True, reset=True)
+        trainer.step(*_batch())
+        steps.append(telemetry.get_tracer().finished_spans())
+    return trainer, init, steps
 
 
 @pytest.fixture
@@ -99,7 +106,35 @@ def test_step_leaves_one_span_with_three_children(trainer):
 
 
 def test_init_leaves_one_span_with_three_children(built):
-    _family(built[1], "lm.trainer_init", INIT_CHILDREN)
+    _family([s for s in built[1] if not s["name"].startswith("jax.")],
+            "lm.trainer_init", INIT_CHILDREN)
+
+
+def _phases_of_dispatch(spans):
+    [dispatch] = [s for s in spans if s["name"] == "lm.dispatch"]
+    return [(s["name"], s["fun"]) for s in spans
+            if s["parent_span_id"] == dispatch["span_id"]], dispatch
+
+
+def test_first_dispatch_holds_the_steps_trace_lowering_and_compile(built):
+    """jax's compile phases as children of the first step's ``lm.dispatch``;
+    the init's jitted and eager programs under the init's spans."""
+    first, dispatch = _phases_of_dispatch(built[2][0])
+    assert first == [("jax.trace", "train_step"), ("jax.lower", "train_step"),
+                     ("jax.compile", "train_step")]
+    phases = [s for s in built[2][0] if s["name"].startswith("jax.")]
+    assert phases[0]["traces"] > 1 and phases[2]["cached"] is False
+    assert sum(s["duration"] for s in phases) <= dispatch["duration"]
+    # the second step's arguments differ from the first's in how the moments'
+    # sharding is spelled (one device: the same placement), so jax looks the
+    # step up once more: a hit of its trace cache, no lowering, no compile
+    second, _ = _phases_of_dispatch(built[2][1])
+    assert second in ([], [("jax.trace", "train_step")])
+    assert _phases_of_dispatch(built[2][2])[0] == []
+    init_ids = {s["span_id"] for s in built[1] if s["name"] in INIT_CHILDREN}
+    assert {s["name"] for s in built[1] if s["name"].startswith("jax.")
+            and s["parent_span_id"] in init_ids} == {
+                "jax.trace", "jax.lower", "jax.compile"}
 
 
 def test_counters_advance_by_one_step_and_its_tokens(trainer):

@@ -393,7 +393,9 @@ def test_simulator_phase_clock_is_the_one_timer(monkeypatch):
     assert {"pack_wait", "round_dispatch", "device", "state_gather",
             "state_scatter", "eval", "host_pack"} <= names
     assert "dispatch" not in names
-    assert set(annotated) == {"fedml:" + n for n in names}
+    # jax's compile phases are recorded after the fact: spans, no annotation
+    assert set(annotated) == {"fedml:" + n for n in names
+                              if not n.startswith("jax.")}
 
 
 # --- exporters ---------------------------------------------------------------
@@ -517,3 +519,142 @@ def test_device_trace_start_failure_leaves_no_dangling_span(monkeypatch):
             pass
     assert len(sink.records) == 0  # start failed BEFORE the started event
     assert ev._open_events == {}
+
+
+# --- jax's compile phases ------------------------------------------------------
+
+
+def _jax_phases():
+    return [s for s in telemetry.get_tracer().finished_spans()
+            if s["name"].startswith("jax.")]
+
+
+def _programs():
+    return {k: v for k, v in telemetry.get_registry().snapshot()[
+        "counters"].items() if k.startswith("fedml_jax_programs_total")}
+
+
+def _fresh_jit(scale):
+    """A jitted function no earlier call has traced, with a jitted inner
+    function traced inside it (each call of this makes new functions)."""
+    import jax
+
+    @jax.jit
+    def inner(x):
+        return x * scale
+
+    def outer_fn(x):
+        return inner(x) + 1.0
+
+    return jax.jit(outer_fn)
+
+
+def test_first_call_under_a_span_leaves_its_three_phases_as_children():
+    import jax.numpy as jnp
+
+    assert telemetry.install_jax_collectors()
+    f = _fresh_jit(3.0)
+    x = jnp.ones((4, 8), jnp.float32)  # made before the span: not its child
+    telemetry.configure(enabled=True, reset=True)
+    tracer = telemetry.get_tracer()
+    with tracer.span("lm.dispatch") as ctx:
+        f(x).block_until_ready()
+    phases = _jax_phases()
+    assert [s["name"] for s in phases if s["fun"] == "outer_fn"] == [
+        "jax.trace", "jax.lower", "jax.compile"]
+    for s in phases:
+        assert s["parent_span_id"] == ctx.span_id
+        assert s["trace_id"] == ctx.trace_id and s["status"] == "ok"
+        assert s["duration"] >= 0
+    trace = next(s for s in phases if s["name"] == "jax.trace")
+    # the inner jit's trace and jnp's own jits are counted, not recorded
+    assert trace["traces"] >= 2
+    assert [s["name"] for s in phases].count("jax.trace") == 1
+    compile_ = next(s for s in phases if s["name"] == "jax.compile")
+    assert compile_["cached"] is False
+    assert _programs() == {"fedml_jax_programs_total{source=compiled}": 1.0}
+    span = tracer.finished_spans()[-1]
+    assert span["name"] == "lm.dispatch"
+    assert sum(s["duration"] for s in phases) <= span["duration"]
+    # the second call finds the program: no phase, no program
+    tracer.clear()
+    with tracer.span("lm.dispatch"):
+        f(x).block_until_ready()
+    assert _jax_phases() == []
+    assert _programs() == {"fedml_jax_programs_total{source=compiled}": 1.0}
+
+
+def test_a_program_from_the_persistent_cache_reads_cached(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    telemetry.install_jax_collectors()
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        x = jnp.arange(16.0)
+        tracer = telemetry.get_tracer()
+        with tracer.span("lm.dispatch"):
+            _fresh_jit(5.0)(x).block_until_ready()
+        jax.clear_caches()
+        tracer.clear()
+        with tracer.span("lm.dispatch"):
+            _fresh_jit(5.0)(x).block_until_ready()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    [compile_] = [s for s in _jax_phases() if s["name"] == "jax.compile"]
+    assert compile_["fun"] == "outer_fn" and compile_["cached"] is True
+    assert _programs()["fedml_jax_programs_total{source=cache}"] == 1.0
+
+
+def test_a_compile_outside_any_span_is_counted_and_not_recorded():
+    import jax.numpy as jnp
+
+    telemetry.install_jax_collectors()
+    assert telemetry.current_context() is None
+    _fresh_jit(7.0)(jnp.ones(3)).block_until_ready()
+    assert _jax_phases() == []
+    assert _programs()["fedml_jax_programs_total{source=compiled}"] >= 1.0
+
+
+def test_disabled_telemetry_records_and_counts_no_phase():
+    import jax.numpy as jnp
+
+    telemetry.install_jax_collectors()
+    telemetry.configure(enabled=False)
+    with telemetry.get_tracer().span("lm.dispatch"):
+        _fresh_jit(9.0)(jnp.ones(3)).block_until_ready()
+    telemetry.configure(enabled=True)
+    assert telemetry.get_tracer().finished_spans() == []
+    assert _programs() == {}
+
+
+def test_record_lands_as_a_child_and_costs_under_20us():
+    """``Tracer.record`` after the fact: the best of twelve batches of 500,
+    so that a loaded machine does not decide it."""
+    tracer = telemetry.get_tracer()
+    with tracer.span("lm.dispatch") as ctx:
+        tracer.record("jax.trace", 100.0, 100.5, fun="f", traces=3)
+    rec = tracer.finished_spans()[0]
+    assert rec["name"] == "jax.trace" and rec["parent_span_id"] == ctx.span_id
+    assert (rec["start"], rec["duration"], rec["fun"], rec["traces"]) == (
+        100.0, 0.5, "f", 3)
+    hist = telemetry.get_registry().snapshot()["histograms"]
+    assert hist["fedml_span_seconds{span=jax.trace}"]["count"] == 1
+    best = float("inf")
+    for _ in range(12):
+        tracer.clear()
+        with tracer.span("lm.dispatch"):
+            t0 = time.perf_counter()
+            for _ in range(500):
+                tracer.record("jax.trace", 100.0, 100.5, fun="f")
+            best = min(best, (time.perf_counter() - t0) / 500)
+    assert best < 20e-6, f"{best * 1e6:.1f} us a record"

@@ -309,12 +309,14 @@ def test_recompile_detector_flags_post_warmup_compiles():
     reg = telemetry.get_registry()
     for i in range(4):
         if i < 2:  # warmup compiles are expected and not flagged
-            reg.counter("fedml_jax_compilation_events_total",
-                        event="jit").inc()
+            reg.counter("fedml_jax_programs_total",
+                        source="compiled").inc()
         rec = {"round": i, "round_time": 0.1, "phases": {"dispatch": 0.1}}
         trace_plane.on_round_record(rec)
         assert "recompile_events" not in rec
-    reg.counter("fedml_jax_compilation_events_total", event="jit").inc(2)
+    # a program loaded from the cache is a program too
+    reg.counter("fedml_jax_programs_total", source="compiled").inc()
+    reg.counter("fedml_jax_programs_total", source="cache").inc()
     rec = {"round": 4, "round_time": 0.1, "phases": {"dispatch": 0.1}}
     trace_plane.on_round_record(rec)
     assert rec["recompile_events"] == 2
@@ -333,13 +335,13 @@ def test_absorb_planned_compiles_quiets_detector():
     for i in range(4):
         rec = {"round": i, "round_time": 0.1, "phases": {"dispatch": 0.1}}
         trace_plane.on_round_record(rec)
-    reg.counter("fedml_jax_compilation_events_total", event="jit").inc(3)
+    reg.counter("fedml_jax_programs_total", source="compiled").inc(3)
     trace_plane.absorb_planned_compiles()
     rec = {"round": 4, "round_time": 0.1, "phases": {"dispatch": 0.1}}
     trace_plane.on_round_record(rec)
     assert "recompile_events" not in rec
     assert reg.counter_total("fedml_recompiles_post_warmup_total") == 0
-    reg.counter("fedml_jax_compilation_events_total", event="jit").inc()
+    reg.counter("fedml_jax_programs_total", source="compiled").inc()
     rec = {"round": 5, "round_time": 0.1, "phases": {"dispatch": 0.1}}
     trace_plane.on_round_record(rec)
     assert rec["recompile_events"] == 1
