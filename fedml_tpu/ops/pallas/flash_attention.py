@@ -26,8 +26,17 @@ at Dh = 64, where it is 1/8.
 
 Causal masking skips key blocks above the diagonal (``pl.when``; their
 index maps repeat the last block that is needed, so nothing is fetched for
-them), applies the row >= col mask only on blocks that straddle the
-diagonal, and runs the blocks under it unmasked.
+them) and runs the blocks under it unmasked (``_causal_cases``, the rule
+for all three kernels). A square block on the diagonal at least two
+STRIPEs tall runs as row stripes (``_stripes``): each STRIPE query rows
+against the prefix of keys they can see, a lane-dense rectangle of whole
+128-lane tiles, only its last STRIPE columns masked, with one max, one sum
+and one state update per row, as a square block has; the masked half is
+no longer formed (it gave exp(-inf) = 0). Every stripe's score products
+are made first, so the MXU runs them back to back while the vector units
+take each stripe's softmax. Any other block that straddles the diagonal
+(unequal or one-stripe blocks) runs as one square, the row >= col mask
+over all of it.
 
 Gradients: custom VJP, probabilities recomputed blockwise from the saved
 per-row logsumexp (FlashAttention-2). Where it fits, one kernel on a
@@ -48,9 +57,15 @@ the kernels are bound by vector loads, stores and lane shuffles, not by the
 MXU (float32 operands ran as fast as bf16 ones), so the softmax state is
 kept lane-replicated, which took a third off the forward; 1024 squares are
 the fastest forward blocks and 512 squares the fastest backward ones at
-T = 1024, 2048 and 4096; the mask on every block costs 0-3%. On non-TPU
-backends the kernels run in interpret mode so tests validate numerics
-everywhere.
+T = 1024, 2048 and 4096; the mask on every block costs 0-3%. Static
+triangular tiles on the diagonal block ran slower then (each tile paid an
+online-softmax update, on the kernel whose state was still ``(rows, 1)``
+columns). PR 39's stripes pay one update a row, as the square did; what
+they save is the masked half's products and result pops on the MXU (at
+T = 1024 Mosaic had already folded the static mask out of the vector work)
+and, with the products issued first, the wait of each stripe's softmax on
+its own products. On non-TPU backends the kernels run in interpret mode so
+tests validate numerics everywhere.
 """
 
 from __future__ import annotations
@@ -62,7 +77,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.telemetry import get_registry
+
 MIN_BLOCK = 128
+# the query rows a stripe of a diagonal block holds (``_causal_cases``)
+STRIPE = 128
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 # the largest square block each pass takes by default: the fastest of
 # {128, 256, 512, 1024} squares and rectangles at T = 1024, 2048, 4096 on the
@@ -130,17 +149,44 @@ _NN = ((1,), (0,))   # a @ b
 _TN = ((0,), (0,))   # a.T @ b
 
 
-def _causal_cases(body, causal, q_start, block_q, k_start, block_k):
-    """Run ``body(masked)`` for this block pair: not at all above the
-    diagonal, masked where the pair straddles it, unmasked under it."""
+def _stripes(causal: bool, block_q: int, block_k: int) -> bool:
+    """Whether a block pair that straddles the diagonal runs as row stripes:
+    a causal square, at least two stripes tall (then the pair that straddles
+    is the one on the diagonal, ``q_start == k_start``)."""
+    return causal and block_q == block_k and block_q >= 2 * STRIPE
+
+
+def _causal_cases(run, causal, q_start, block_q, k_start, block_k):
+    """Run this block pair as ``run(parts)``, each part (rows, keys, mask):
+    ``rows`` a slice of the q block, ``keys`` of the k block, ``mask`` None
+    or what masks their scores. Not at all above the diagonal, one unmasked
+    part under it. A pair that straddles it is one masked part, or, where
+    ``_stripes``, a part for each stripe of STRIPE query rows over the
+    prefix of keys it can see, only its last STRIPE columns masked."""
+    whole = slice(None)
     if not causal:
-        body(False)
+        run([(whole, whole, None)])
         return
     under = k_start + block_k - 1 <= q_start
-    pl.when(under)(lambda: body(False))
-    pl.when(jnp.logical_and(jnp.logical_not(under),
-                            k_start <= q_start + block_q - 1))(
-        lambda: body(True))
+    pl.when(under)(lambda: run([(whole, whole, None)]))
+    straddles = jnp.logical_and(jnp.logical_not(under),
+                                k_start <= q_start + block_q - 1)
+    if not _stripes(causal, block_q, block_k):
+        pl.when(straddles)(lambda: run([(whole, whole, lambda s: jnp.where(
+            _keep(s.shape, q_start, k_start), s, NEG_INF))]))
+        return
+    pl.when(straddles)(lambda: run([
+        (slice(lo, lo + STRIPE), slice(0, lo + STRIPE), _mask_last)
+        for lo in range(0, block_q, STRIPE)]))
+
+
+def _mask_last(s):
+    """s (STRIPE, n) with the causal mask on its last STRIPE columns, the
+    diagonal square a stripe ends with; the columns before it all stay."""
+    tail = jnp.where(_keep((STRIPE, STRIPE), 0, 0), s[:, -STRIPE:], NEG_INF)
+    if s.shape[1] == STRIPE:
+        return tail
+    return jnp.concatenate([s[:, :-STRIPE], tail], axis=1)
 
 
 def _keep(shape, q_start, k_start):
@@ -149,9 +195,9 @@ def _keep(shape, q_start, k_start):
     return rows >= cols
 
 
-def _scores(a, b, keep):
+def _scores(a, b, mask):
     s = _dot(a, b, _NT)
-    return s if keep is None else jnp.where(keep, s, NEG_INF)
+    return s if mask is None else mask(s)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -180,23 +226,32 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def body(masked: bool):
-        k_blk, v_blk = k_ref[0], v_ref[0]
-        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
-        corrs, pvs = [], []
-        for h in range(hp):
-            s = _scores(q_scr[h], k_blk, keep)           # (block_q, block_k)
-            m = m_scr[h]                                 # lane-replicated
-            new_m = jnp.maximum(m, jnp.max(s, axis=1)[:, None])
-            p = jnp.exp(s - pltpu.repeat(new_m, block_k // 128, 1))
-            corr = jnp.exp(m - new_m)
-            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1)[:, None]
-            m_scr[h] = new_m
-            pvs.append(_dot(p.astype(v_blk.dtype), v_blk, _NN))
-            corrs.append(_lanes(corr, W))
-        acc_scr[...] = acc_scr[...] * _by_head(corrs, Dh) + _by_head(pvs, Dh)
+    def run(parts):
+        # several parts (the stripes): every score product first, so that
+        # the MXU runs them back to back while the vector units take each
+        # stripe's softmax; in program order each softmax waited on its own
+        ahead = [[_scores(q_scr[h, rows], k_ref[0, keys], mask)
+                  for h in range(hp)]
+                 for rows, keys, mask in parts] if len(parts) > 1 else None
+        for i, (rows, keys, mask) in enumerate(parts):
+            v_blk = v_ref[0, keys]
+            corrs, pvs = [], []
+            for h in range(hp):
+                s = (ahead[i][h] if ahead else
+                     _scores(q_scr[h, rows], k_ref[0, keys], mask))
+                m = m_scr[h, rows]                       # lane-replicated
+                new_m = jnp.maximum(m, jnp.max(s, axis=1)[:, None])
+                p = jnp.exp(s - pltpu.repeat(new_m, s.shape[1] // 128, 1))
+                corr = jnp.exp(m - new_m)
+                l_scr[h, rows] = (l_scr[h, rows] * corr
+                                  + jnp.sum(p, axis=1)[:, None])
+                m_scr[h, rows] = new_m
+                pvs.append(_dot(p.astype(v_blk.dtype), v_blk, _NN))
+                corrs.append(_lanes(corr, W))
+            acc_scr[rows] = (acc_scr[rows] * _by_head(corrs, Dh)
+                             + _by_head(pvs, Dh))
 
-    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -227,19 +282,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             do_scr[h] = _only_head(do_ref[0], h, hp, Dh)
         dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def body(masked: bool):
-        k_blk, v_blk = k_ref[0], v_ref[0]
-        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
-        dqs = []
-        for h in range(hp):
-            p = jnp.exp(_scores(q_scr[h], k_blk, keep)
-                        - lse_ref[0, 0, h][:, None])
-            dp = _dot(do_scr[h], v_blk, _NT)
-            ds = p * (dp - delta_ref[0, 0, h][:, None])
-            dqs.append(_dot(ds.astype(k_blk.dtype), k_blk, _NN))
-        dq_scr[...] = dq_scr[...] + _by_head(dqs, Dh)
+    def run(parts):
+        # the stripes' two score-shaped products first (see _flash_kernel)
+        ahead = [[(_scores(q_scr[h, rows], k_ref[0, keys], mask),
+                   _dot(do_scr[h, rows], v_ref[0, keys], _NT))
+                  for h in range(hp)]
+                 for rows, keys, mask in parts] if len(parts) > 1 else None
+        for i, (rows, keys, mask) in enumerate(parts):
+            k_blk, v_blk = k_ref[0, keys], v_ref[0, keys]
+            dqs = []
+            for h in range(hp):
+                s = (ahead[i][h][0] if ahead else
+                     _scores(q_scr[h, rows], k_blk, mask))
+                p = jnp.exp(s - lse_ref[0, 0, h, rows][:, None])
+                dp = (ahead[i][h][1] if ahead else
+                      _dot(do_scr[h, rows], v_blk, _NT))
+                ds = p * (dp - delta_ref[0, 0, h, rows][:, None])
+                dqs.append(_dot(ds.astype(k_blk.dtype), k_blk, _NN))
+            dq_scr[rows] = dq_scr[rows] + _by_head(dqs, Dh)
 
-    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -278,27 +340,36 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
         def _init_dq():
             dq_scr[...] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    def body(masked: bool):
-        q, do = q_ref[0], do_ref[0]
-        keep = _keep((block_q, block_k), q_start, k_start) if masked else None
-        dks, dvs, dq = [], [], None
-        for h in range(hp):
-            p = jnp.exp(_scores(q, k_scr[h], keep)      # (block_q, block_k)
-                        - lse_ref[0, 0, h][:, None])
-            dp = _dot(do, v_scr[h], _NT)
-            ds = (p * (dp - delta_ref[0, 0, h][:, None])).astype(q.dtype)
-            dvs.append(_dot(p.astype(do.dtype), do, _TN))
-            dks.append(_dot(ds, q, _TN))
+    def run(parts):
+        # the stripes' two score-shaped products first (see _flash_kernel)
+        ahead = [[(_scores(q_ref[0, rows], k_scr[h, keys], mask),
+                   _dot(do_ref[0, rows], v_scr[h, keys], _NT))
+                  for h in range(hp)]
+                 for rows, keys, mask in parts] if len(parts) > 1 else None
+        for i, (rows, keys, mask) in enumerate(parts):
+            q, do = q_ref[0, rows], do_ref[0, rows]
+            dks, dvs, dq = [], [], None
+            for h in range(hp):
+                s = (ahead[i][h][0] if ahead else
+                     _scores(q, k_scr[h, keys], mask))   # (rows, keys)
+                p = jnp.exp(s - lse_ref[0, 0, h, rows][:, None])
+                dp = (ahead[i][h][1] if ahead else
+                      _dot(do, v_scr[h, keys], _NT))
+                ds = (p * (dp - delta_ref[0, 0, h, rows][:, None])
+                      ).astype(q.dtype)
+                dvs.append(_dot(p.astype(do.dtype), do, _TN))
+                dks.append(_dot(ds, q, _TN))
+                if fused:
+                    part = _dot(ds, k_scr[h, keys], _NN)
+                    dq = part if dq is None else dq + part
+            dv_scr[keys] = dv_scr[keys] + _by_head(dvs, Dh)
+            dk_scr[keys] = dk_scr[keys] + _by_head(dks, Dh)
             if fused:
-                part = _dot(ds, k_scr[h], _NN)
-                dq = part if dq is None else dq + part
-        dv_scr[...] = dv_scr[...] + _by_head(dvs, Dh)
-        dk_scr[...] = dk_scr[...] + _by_head(dks, Dh)
-        if fused:
-            rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
-            dq_scr[rows, :] = dq_scr[rows, :] + dq
+                lo, hi, _ = rows.indices(block_q)
+                at = pl.ds(pl.multiple_of(q_start + lo, MIN_BLOCK), hi - lo)
+                dq_scr[at, :] = dq_scr[at, :] + dq
 
-    _causal_cases(body, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -488,7 +559,6 @@ def _resolve_blocks(q, block_q, block_k, backward: bool):
     return bq, bk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = False,
@@ -500,7 +570,27 @@ def flash_attention(
     under each pass's measured cap (FWD_BLOCK, BWD_BLOCK), and explicit ones
     hold for both passes; requires T % block == 0 (callers fall back to
     dense otherwise)."""
+    # counted here, where a call site is traced once: under remat jax traces
+    # the custom VJP's primal and its forward rule both
+    _count_diagonal("fwd", q, causal,
+                    *_resolve_blocks(q, block_q, block_k, backward=False))
+    return _flash_attention(q, k, v, causal, block_q, block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention(q, k, v, causal, block_q, block_k):
     return _fwd(q, k, v, causal, block_q, block_k)[0]
+
+
+def _count_diagonal(pass_: str, q, causal, block_q, block_k) -> None:
+    """``fedml_flash_diagonal_total{pass, impl, seq_len}``: how a causal
+    call's diagonal blocks run, ``striped`` or ``square``, where the blocks
+    are resolved: once per call site per trace, as
+    ``fedml_attention_dispatch_total`` is counted, nothing at run time."""
+    if causal and isinstance(q, jax.core.Tracer):
+        impl = "striped" if _stripes(causal, block_q, block_k) else "square"
+        get_registry().counter("fedml_flash_diagonal_total", impl=impl,
+                               seq_len=q.shape[1], **{"pass": pass_}).inc()
 
 
 def _fwd(q, k, v, causal, block_q, block_k):
@@ -514,10 +604,11 @@ def _bwd(causal, block_q, block_k, res, g):
     q, k, v, out, lse = res
     interpret = jax.default_backend() != "tpu"
     block_q, block_k = _resolve_blocks(q, block_q, block_k, backward=True)
+    _count_diagonal("bwd", q, causal, block_q, block_k)
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret)
 
 
-flash_attention.defvjp(_fwd, _bwd)
+_flash_attention.defvjp(_fwd, _bwd)
 
 
 def flash_vmem_ok(T: int, Dh: int, itemsize: int = 2,

@@ -15,28 +15,54 @@ def _qkv(B=2, T=256, H=2, Dh=64, seed=0):
     return mk(), mk(), mk()
 
 
+# (T, Dh): T = 128 runs one square block; at T = 256 and 512 the default
+# blocks are T itself, a diagonal block of two and four stripes; Dh = 64
+# puts two heads in a lane tile, Dh = 128 one
+SHAPES = [(128, 64), (256, 64), (512, 64), (256, 128), (512, 128)]
+
+
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_dense(causal):
-    q, k, v = _qkv()
+@pytest.mark.parametrize("T,Dh", SHAPES[1:])
+def test_flash_matches_dense(causal, T, Dh):
+    q, k, v = _qkv(T=T, Dh=Dh, H=256 // Dh)
     dense = multihead_attention(q, k, v, causal=causal, impl="dense")
     flash = flash_attention(q, k, v, causal)
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense), atol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_gradients_match_dense(causal):
-    q, k, v = _qkv(T=128)
+@pytest.mark.parametrize("causal,T,Dh,fused", [
+    *[(causal, 128, 64, True) for causal in (False, True)],
+    *[(True, T, Dh, fused) for T, Dh in SHAPES[1:] for fused in (True, False)],
+    (False, 512, 128, False)])
+def test_flash_gradients_match_dense(causal, T, Dh, fused, monkeypatch):
+    """dq, dk, dv against dense at blocks of T: one square at T = 128, a
+    striped diagonal block beyond, through the fused backward and the split
+    pair (the limit patched as ``test_flash_backward_fused_and_split_agree``
+    patches it)."""
+    import sys
+
+    mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
+    H = 128 // Dh
+    if not fused:  # room for the split pair's tiles, none for the fused dq
+        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+            T, T, T, 128, H, 4, fused=False))
+    jax.clear_caches()
+    q, k, v = _qkv(B=1, T=T, H=H, Dh=Dh)
+    w = jnp.cos(jnp.arange(q.size).reshape(q.shape) * 0.01)
 
     def loss_flash(q, k, v):
         # non-uniform cotangent so dq/dk/dv all get exercised beyond sum()
-        out = flash_attention(q, k, v, causal)
-        return (out * jnp.cos(jnp.arange(out.size).reshape(out.shape) * 0.01)).sum()
+        return (flash_attention(q, k, v, causal, T, T) * w).sum()
 
     def loss_dense(q, k, v):
-        out = multihead_attention(q, k, v, causal=causal, impl="dense")
-        return (out * jnp.cos(jnp.arange(out.size).reshape(out.shape) * 0.01)).sum()
+        return (multihead_attention(q, k, v, causal=causal, impl="dense")
+                * w).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    grad = jax.grad(loss_flash, argnums=(0, 1, 2))
+    assert str(jax.make_jaxpr(grad)(q, k, v)).count("pallas_call") == (
+        2 if fused else 3)
+    gf = grad(q, k, v)
+    jax.clear_caches()
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
@@ -231,6 +257,81 @@ def test_dispatch_counter_advances_once_per_traced_call_site(monkeypatch):
         jax.jit(lambda x: multihead_attention(x, x, x))(short)
         assert count(T) == (before[0] + 1, before[1])
     assert count() == (dense0 + 1, flash0 + 1)
+
+
+def test_diagonal_counter_counts_once_per_traced_call_site():
+    """``fedml_flash_diagonal_total{pass, impl, seq_len}``: ``striped`` where a
+    causal call's diagonal block is two stripes tall or more, ``square``
+    under that; once per call site per trace (the forward where the caller
+    traces it, the backward where its rule is traced), nothing at run time,
+    nothing for a call that masks nothing."""
+    from fedml_tpu.core.telemetry import get_registry
+    from fedml_tpu.ops.pallas.flash_attention import STRIPE
+
+    def count(T):
+        return {(p, impl): get_registry().counter(
+            "fedml_flash_diagonal_total", impl=impl, seq_len=T,
+            **{"pass": p}).value
+            for p in ("fwd", "bwd") for impl in ("striped", "square")}
+
+    def after(T, fn, *args):
+        before = count(T)
+        fn(*args)
+        return {key: n - before[key] for key, n in count(T).items()
+                if n != before[key]}
+
+    q = jnp.zeros((1, 2 * STRIPE, 2, 64), jnp.float32)
+    two_sites = jax.jit(lambda q: flash_attention(
+        flash_attention(q, q, q, True), q, q, True))
+    assert after(2 * STRIPE, two_sites, q) == {("fwd", "striped"): 2}
+    assert after(2 * STRIPE, two_sites, q) == {}          # compiled
+    grad = jax.jit(jax.grad(lambda q: flash_attention(q, q, q, True).sum()))
+    assert after(2 * STRIPE, grad, q) == {("fwd", "striped"): 1,
+                                          ("bwd", "striped"): 1}
+    assert after(2 * STRIPE, jax.jit(lambda q: flash_attention(
+        q, q, q, False)), q) == {}                          # no diagonal
+    assert after(2 * STRIPE, flash_attention, q, q, q, True) == {}  # eager
+    short = jnp.zeros((1, STRIPE, 2, 64), jnp.float32)      # one stripe tall
+    assert after(STRIPE, grad, short) == {("fwd", "square"): 1,
+                                          ("bwd", "square"): 1}
+
+
+def test_flash_striped_share_reads_the_lm_cell(monkeypatch):
+    """``benchmark/layer_metrics/flash_striped_share.lm.json`` through its
+    reader, at the GPT-2 cell's traffic: nothing before a causal call at
+    ``seq_len`` is traced (as on the parent, which has no such counter), 100
+    once the cell's attention is (a forward block of 1024 and backward
+    blocks of 512, all striped); the step is traced, never run."""
+    import importlib.util
+    import json
+    import os
+    import sys
+
+    from fedml_tpu.core import telemetry
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    reg = telemetry.MetricsRegistry(enabled=True)
+    monkeypatch.setattr(telemetry, "get_registry", lambda: reg)
+    monkeypatch.setattr(sys.modules["fedml_tpu.ops.pallas.flash_attention"],
+                        "get_registry", lambda: reg)
+    spec = importlib.util.spec_from_file_location(
+        "bench_program_counter",
+        os.path.join(bench, "readers", "program_counter.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    with open(os.path.join(bench, "layer_metrics",
+                           "flash_striped_share.lm.json")) as f:
+        metric = json.load(f)
+    with open(os.path.join(bench, "traffic", "t1024_b8_pretrain.json")) as f:
+        ctx = {"traffic": json.load(f)}
+    assert metric["reader"] == "program_counter"
+    assert reader.read(ctx, **metric["args"]) is None
+    q = jax.ShapeDtypeStruct((1, ctx["traffic"]["seq_len"], 2, 64),
+                             jnp.bfloat16)
+    jax.jit(jax.grad(lambda q: flash_attention(q, q, q, True).astype(
+        jnp.float32).sum())).trace(q)
+    assert reader.read(ctx, **metric["args"]) == 100.0
 
 
 def test_auto_dispatch_guard():

@@ -290,3 +290,34 @@ def test_the_looped_step_compiles_at_the_cells_size_inside_the_chip(
     assert not [n for n in names if "ut.pass" in n
                 and ("ut.head" in n or "ut.exit" in n or "lm.loss" in n)]
     assert not [n for n in names if "ut.head" in n and "ut.exit" in n]
+
+
+@pytest.mark.parametrize("H,Dh", [(4, 64), (2, 128)], ids=["dh64", "dh128"])
+def test_the_flash_pair_compiles_with_striped_diagonal_blocks(topo, monkeypatch,
+                                                             H, Dh):
+    """The GPT-2 cell's attention (T = 1024: one diagonal forward block of
+    eight stripes, backward blocks of four) at two head groups, and a head
+    width of 128, forward and backward compiled for the v5e: Mosaic takes
+    the striped bodies inside scoped VMEM, and they stay the forward kernel
+    and the fused backward kernel, two call sites (PERF.md section 6, PR 39:
+    the whole GPT-2 step kept its three)."""
+    from fedml_tpu.core.telemetry import get_registry
+    from fedml_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+    sds = jax.ShapeDtypeStruct((2, 1024, H, Dh), jnp.bfloat16,
+                               sharding=one_chip)
+    striped = lambda: get_registry().counter(  # noqa: E731
+        "fedml_flash_diagonal_total", impl="striped", seq_len=1024,
+        **{"pass": "bwd"}).value
+    before = striped()
+    text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True).astype(jnp.float32).sum(), (0, 1, 2))).lower(
+        sds, sds, sds).compile().as_text()
+    assert striped() == before + 1
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(calls) == 2, calls
+    assert sorted("_flash_backward" in n for n in calls) == [False, True]
