@@ -60,6 +60,9 @@ FLAGSHIP_ROUNDS = 3           # eval runs at round 0 and at the last round
 LM_MODEL = dict(vocab_size=32000, dim=1024, num_heads=16, num_layers=12)
 LM_SEQ = 4096                 # long context; "flash" on a TPU from T=1024
 FLASH_SPLIT_SEQ = 12288       # past what one backward kernel holds of dq in VMEM
+# the SmallThinker cell's windowed attention: T, head width and band (at two
+# heads: the dense path it is checked against holds the (T, T) scores)
+FLASH_WINDOW = dict(seq=16384, heads=2, dh=128, window=4096)
 LM_BATCH = 4                  # ~5.5 GB of the v5e's 16 GB (XLA's own estimate)
 LM_STEPS = 4
 # a small layer_types decoder: every operator of models/hybrid_lm.py once,
@@ -476,9 +479,11 @@ def check_ssd_vs_sequential(batch: int, seq: int, heads: int, head_dim: int,
     return out
 
 
-def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
+def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1,
+                         window=None) -> dict:
     """flash forward and backward against multihead_attention(impl='dense')
-    on seeded bf16 inputs, at bf16 tolerance."""
+    on seeded bf16 inputs, at bf16 tolerance; with ``window``, both over
+    that causal band."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -491,7 +496,8 @@ def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1) -> dict:
 
     def loss(impl):
         def f(q, k, v):
-            out = multihead_attention(q, k, v, causal=True, impl=impl)
+            out = multihead_attention(q, k, v, causal=True, impl=impl,
+                                      window=window)
             return (out.astype(jnp.float32) * w.astype(jnp.float32)).sum(), out
         return f
 
@@ -951,6 +957,11 @@ def main() -> int:
                 FLASH_SPLIT_SEQ, 2, 64)
     require(split["mosaic_calls_lowered"] >= 3,
             "the split backward did not engage compiled")
+    # the same pair over a band, at the window cell's length: the key
+    # blocks outside it skipped, the lower edge's masked
+    band = run("flash_vs_dense_window", check_flash_vs_dense, **FLASH_WINDOW)
+    require(band["mosaic_calls_lowered"] >= 3,
+            "the windowed pair did not engage compiled")
 
     run("fused_gram_refuses", check_gram_refuses, GRAM_REFUSED)
     kernels = [run("fused_gram", check_gram, GRAM_SHAPE),

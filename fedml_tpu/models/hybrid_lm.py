@@ -14,6 +14,18 @@ first ``num_dense_layers`` layers and a dropless routed-expert layer after
 them (ops/moe.py ``RoutedExperts``: sigmoid scores, a selection bias,
 normalised top-k weights), which may hold a chip's share of the experts.
 
+**Window and global layers** (SmallThinker's). ``rope_layout`` says which
+attention layers are rotary (1) and which have no position embedding at all
+(0); ``sliding_window_layout`` which see a causal band of
+``sliding_window_size`` keys, ``0 <= i - j < window`` (1), and which the
+whole causal triangle (0): the flash kernels skip the key blocks outside
+the band. The router may be a softmax over all the experts with no bias
+(``moe_primary_router_apply_softmax``), the experts ReGLU (``mlp_hidden_act``
+``"relu"``: ``W2 (relu(W1 u') * W3 u')``), and with ``early_router`` the
+router reads the layer's first norm, the attention's input, and not the
+second: ``u = RMSNorm(h); r = u W_r; h += Attn(u); u' = RMSNorm(h); h +=
+Experts(u'; r)``.
+
 **One-mixer blocks**, ``h += Mixer(RMSNorm(h))`` (Nemotron-H's; the kinds are
 its ``layers_block_type`` names, and ``layer_types_of_pattern`` reads its
 ``hybrid_override_pattern``). ``Mixer`` is a Mamba-2 mixer (``"mamba"``,
@@ -34,8 +46,8 @@ expectation of the passes' losses under the gate's exit distribution).
 No bias in any projection, no position table; the head is the embedding's
 transpose, or with ``tie_word_embeddings`` false a matrix of its own.
 ``DecoderConfig`` carries the published key names of such models'
-``config.json`` (LFM2-MoE's, Nemotron-H's, Ouro's), so a configuration file
-maps onto it key by key.
+``config.json`` (LFM2-MoE's, Nemotron-H's, Ouro's, SmallThinker's), so a
+configuration file maps onto it key by key.
 """
 
 from __future__ import annotations
@@ -55,7 +67,7 @@ TWO_PART_KINDS = ("conv", "full_attention")
 MIXER_KINDS = ("mamba", "attention", "moe")
 LAYER_KINDS = TWO_PART_KINDS + MIXER_KINDS
 # ``mlp_hidden_act`` -> an expert's form (ops/moe.py ``EXPERT_FORMS``)
-EXPERT_FORM_OF_ACT = {"silu": "swiglu", "relu2": "relu2"}
+EXPERT_FORM_OF_ACT = {"silu": "swiglu", "relu2": "relu2", "relu": "reglu"}
 PATTERN_KINDS = {"M": "mamba", "*": "attention", "E": "moe"}
 
 
@@ -113,6 +125,15 @@ class DecoderConfig:
     sandwich_norm: bool = False     # a norm after each sub-layer as well
     # the looped family's (Ouro's key): the stack is applied this many times
     total_ut_steps: int = 1
+    # window and global layers (SmallThinker's keys): a 0/1 a layer, None =
+    # every attention layer rotary and causal over all of T
+    sliding_window_size: Optional[int] = None
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
+    # the routed experts' router: softmax with no bias, or sigmoid + bias;
+    # ``early_router``: it reads the layer's first norm, before attention
+    moe_primary_router_apply_softmax: bool = False
+    early_router: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -128,6 +149,24 @@ class DecoderConfig:
         if self.total_ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.total_ut_steps}: the "
                              "stack is applied once at least")
+        for key in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, key)
+            if layout is None:
+                continue
+            object.__setattr__(self, key, tuple(int(x) for x in layout))
+            if len(layout) != len(self.layer_types) or set(layout) - {0, 1}:
+                raise ValueError(f"{key} holds a 0 or a 1 for each of the "
+                                 f"{len(self.layer_types)} layers: {layout}")
+        if any(self.sliding_window_layout or ()) and not self.sliding_window_size:
+            raise ValueError("sliding_window_layout asks for windowed layers "
+                             "and sliding_window_size gives no window")
+
+    def attention_of(self, i: int) -> Tuple[bool, Optional[int]]:
+        """Layer ``i``'s attention: (rotary, the window or None)."""
+        rotary = self.rope_layout is None or bool(self.rope_layout[i])
+        windowed = bool(self.sliding_window_layout
+                        and self.sliding_window_layout[i])
+        return rotary, (self.sliding_window_size if windowed else None)
 
     @property
     def expert_layers(self) -> int:
@@ -177,7 +216,8 @@ class GroupedQueryAttention(nn.Module):
     """Causal attention, ``kv_heads`` KV heads serving ``heads`` query
     heads. Two facts a layer states: ``qk_norm``, q and k RMS-normalised per
     head; ``rotary``, q and k rotated by position (after the norm, where
-    both). With neither there is no position embedding at all."""
+    both). With neither there is no position embedding at all. ``window``:
+    query i sees key j where ``0 <= i - j < window``."""
 
     dim: int
     heads: int
@@ -190,6 +230,7 @@ class GroupedQueryAttention(nn.Module):
     attn_impl: Optional[str] = None
     rotary: bool = True
     qk_norm: bool = True
+    window: Optional[int] = None  # a causal band of this many keys
 
     @nn.compact
     def __call__(self, u):
@@ -216,7 +257,7 @@ class GroupedQueryAttention(nn.Module):
         from ..ops.attention import local_attention
 
         return local_attention(q, k, v, causal=True, impl=self.attn_impl,
-                               mesh=self.mesh)
+                               mesh=self.mesh, window=self.window)
 
 
 class SwiGLU(nn.Module):
@@ -317,8 +358,9 @@ class MixerBlock(nn.Module):
 class DecoderLayer(nn.Module):
     """One two-part layer of kind ``kind``; ``dense`` picks its feed-forward.
     With ``cfg.sandwich_norm`` each sub-layer's output passes a norm of its
-    own before it joins the residual. Returns (h, the expert layer's stats
-    or zeros)."""
+    own before it joins the residual. ``rotary`` and ``window`` are the
+    layer's attention's (``DecoderConfig.attention_of``). Returns (h, the
+    expert layer's stats or zeros)."""
 
     cfg: DecoderConfig
     kind: str
@@ -326,6 +368,8 @@ class DecoderLayer(nn.Module):
     dtype: jnp.dtype = jnp.float32
     mesh: Optional[object] = None
     attn_impl: Optional[str] = None
+    rotary: bool = True
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, h):
@@ -337,6 +381,7 @@ class DecoderLayer(nn.Module):
             return RMSNorm(c.norm_eps, self.dtype, name=name)(out)
 
         u = RMSNorm(c.norm_eps, self.dtype, name="operator_norm")(h)
+        router_input = u if c.early_router else None
         if self.kind == "conv":
             out = ShortConv(c.hidden_size, c.conv_L_cache, self.dtype,
                             name="conv")(u)
@@ -345,8 +390,8 @@ class DecoderLayer(nn.Module):
                 c.hidden_size, c.num_attention_heads, c.num_key_value_heads,
                 c.head_dim or c.hidden_size // c.num_attention_heads,
                 c.rope_theta, c.norm_eps, self.dtype, mesh=self.mesh,
-                attn_impl=self.attn_impl, rotary=True, qk_norm=c.qk_norm,
-                name="attn")(u)
+                attn_impl=self.attn_impl, rotary=self.rotary,
+                qk_norm=c.qk_norm, window=self.window, name="attn")(u)
         h = h + joins(out, "operator_out_norm")
         u = RMSNorm(c.norm_eps, self.dtype, name="ffn_norm")(h)
         if self.dense:
@@ -356,7 +401,10 @@ class DecoderLayer(nn.Module):
         out, stats = RoutedExperts(
             c.hidden_size, c.moe_intermediate_size, c.num_experts,
             c.num_experts_per_tok, experts_held=c.experts_held,
-            dtype=self.dtype, mesh=self.mesh, name="moe")(u)
+            dtype=self.dtype, mesh=self.mesh,
+            form=EXPERT_FORM_OF_ACT[c.mlp_hidden_act],
+            router="softmax" if c.moe_primary_router_apply_softmax
+            else "sigmoid", name="moe")(u, router_input)
         return h + joins(out, "ffn_out_norm"), stats
 
 
@@ -430,8 +478,10 @@ class HybridLM(nn.Module):
             if kind in MIXER_KINDS:
                 layers.append(block_cls(c, kind, self.dtype, **kw))
             else:
+                rotary, window = c.attention_of(i)
                 layers.append(layer_cls(c, kind, two_part < c.num_dense_layers,
-                                        self.dtype, **kw))
+                                        self.dtype, rotary=rotary,
+                                        window=window, **kw))
                 two_part += 1
         final_norm = RMSNorm(c.norm_eps, self.dtype, name="final_norm")
         gate = ExitGate(name="exit_gate") if looped else None

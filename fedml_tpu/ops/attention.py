@@ -34,7 +34,7 @@ DENSE_SAVED_BYTES_MAX = 512 * 1024**2
 
 
 def auto_attention_impl(B: int, H: int, T: int, Dh: int,
-                        itemsize: int = 2) -> str:
+                        itemsize: int = 2, window: Optional[int] = None) -> str:
     """Pick 'flash' vs 'dense' for (B, T, H, Dh) attention, from the
     platform and the shape alone.
 
@@ -50,27 +50,35 @@ def auto_attention_impl(B: int, H: int, T: int, Dh: int,
 
     Either way the shape has to tile and the kernels' blocks have to fit
     VMEM (``flash_shapes_ok``): ViT's 65 and 197 tokens, a lane-hostile Dh
-    and 25 heads of 64 (one 1600-lane tile) stay dense.
+    and 25 heads of 64 (one 1600-lane tile) stay dense; so does a causal
+    band under T that holds no two of the kernels' smallest blocks.
     """
     from .pallas import flash_shapes_ok
+    from .pallas.flash_attention import MIN_BLOCK
 
     min_t = (FLASH_MIN_T_ON_TPU if jax.default_backend() == "tpu"
              else FLASH_MIN_T_INTERPRETED)
     want_flash = (T >= min_t
                   or B * H * T * T * itemsize > DENSE_SAVED_BYTES_MAX)
-    if want_flash and flash_shapes_ok(T, Dh, itemsize=itemsize, heads=H):
+    band_fits = window is None or window >= min(T, 2 * MIN_BLOCK)
+    if want_flash and band_fits and flash_shapes_ok(T, Dh, itemsize=itemsize,
+                                                     heads=H):
         return "flash"
     return "dense"
 
 
 def multihead_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
-    impl: Optional[str] = None,
+    impl: Optional[str] = None, window: Optional[int] = None,
 ) -> jax.Array:
     """Attention. q: (B, T, H, Dh); k/v: (B, T, Hkv, Dh) -> (B, T, H, Dh).
 
     ``impl``: 'flash' (pallas kernel, ops/pallas/flash_attention.py),
     'dense', or None = auto (flash when shapes tile into whole blocks).
+    ``window`` (causal only): query i sees key j where ``0 <= i - j <
+    window`` (a sliding-window layer); both implementations apply the band,
+    and the dispatch rule sends one too narrow for the kernels' blocks to
+    dense (``auto_attention_impl``).
 
     Grouped KV heads (Hkv < H, Hkv | H): each KV head serves H // Hkv
     consecutive query heads. K and V are repeated to H heads here, before
@@ -79,6 +87,8 @@ def multihead_attention(
     its gradient the sum over the group.
     """
     T, Dh = q.shape[1], q.shape[-1]
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band: causal=True")
     if k.shape[2] != q.shape[2]:
         if q.shape[2] % k.shape[2] or v.shape[2] != k.shape[2]:
             raise ValueError(
@@ -88,7 +98,8 @@ def multihead_attention(
         k, v = jnp.repeat(k, groups, axis=2), jnp.repeat(v, groups, axis=2)
     if impl is None:
         itemsize = jnp.dtype(q.dtype).itemsize
-        impl = auto_attention_impl(q.shape[0], q.shape[2], T, Dh, itemsize)
+        impl = auto_attention_impl(q.shape[0], q.shape[2], T, Dh, itemsize,
+                                   window)
         saved_bytes = q.shape[0] * q.shape[2] * T * T * itemsize
         if impl == "dense" and (T >= 8192
                                 or saved_bytes > DENSE_SAVED_BYTES_MAX):
@@ -115,12 +126,15 @@ def multihead_attention(
     if impl == "flash":
         from .pallas import flash_attention
 
-        return flash_attention(q, k, v, causal)
+        return flash_attention(q, k, v, causal, window=window)
     scale = 1.0 / jnp.sqrt(Dh).astype(q.dtype)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         S = logits.shape[-1]
         mask = jnp.tril(jnp.ones((T, S), dtype=bool))
+        if window is not None and window < T:  # and j > i - window
+            mask = jnp.logical_and(mask, jnp.triu(
+                jnp.ones((T, S), dtype=bool), 1 - window))
         logits = jnp.where(mask, logits, jnp.finfo(logits.dtype).min)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -128,14 +142,14 @@ def multihead_attention(
 
 def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, impl: Optional[str] = None,
-                    mesh=None) -> jax.Array:
+                    mesh=None, window: Optional[int] = None) -> jax.Array:
     """Attention with no sequence axis, under the mesh the enclosing step is
     partitioned over. GSPMD cannot partition a Mosaic kernel (jax refuses to
     lower one inside a sharded jit), so when the flash path engages under a
     data x model mesh the call is wrapped in shard_map: batch and heads are
     independent, each device runs the kernel on its own (B/dp, T, H/tp, Dh)
     shard (and its Hkv/tp KV heads). The dense path stays plain XLA, which
-    GSPMD partitions itself."""
+    GSPMD partitions itself. ``window`` as ``multihead_attention``'s."""
     B, T, H, Dh = q.shape
     if mesh is not None and mesh.size > 1:
         from jax import shard_map
@@ -154,11 +168,12 @@ def local_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             spec = P(b_ax, None, h_ax, None)
             return shard_map(
                 lambda q, k, v: multihead_attention(
-                    q, k, v, causal=causal, impl="flash"),
+                    q, k, v, causal=causal, impl="flash", window=window),
                 mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
                 check_vma=False,
             )(q, k, v)
-    return multihead_attention(q, k, v, causal=causal, impl=impl)
+    return multihead_attention(q, k, v, causal=causal, impl=impl,
+                               window=window)
 
 
 def ulysses_attention(

@@ -329,16 +329,22 @@ def _take_rows_bwd(use, impl, res, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def route_top_k(x, gate, bias, top_k: int):
+def route_top_k(x, gate, bias, top_k: int, router: str = "sigmoid"):
     """(N, D) tokens -> (chosen experts (N, k) int32, their weights (N, k)
-    float32). Float32 throughout: ``s = sigmoid(x gate)``; the choice is
-    ``top_k(s + bias)`` (the bias steers the choice only and takes no
-    gradient; zeros are no bias); the weights are ``s`` at the chosen, over
-    their sum."""
+    float32). Float32 throughout. ``router`` ``"sigmoid"``: ``s =
+    sigmoid(x gate)``; the choice is ``top_k(s + bias)`` (the bias steers
+    the choice only and takes no gradient; zeros are no bias); the weights
+    are ``s`` at the chosen, over their sum + 1e-6. ``"softmax"``: ``p =
+    softmax(x gate)`` over all the experts, the choice ``top_k(p)``, the
+    weights ``p`` at the chosen over their sum; no bias (None)."""
     with jax.named_scope("moe.shuffle.route"):
-        s = jax.nn.sigmoid(jnp.dot(
+        logits = jnp.dot(
             x.astype(jnp.float32), gate.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST)
+        if router == "softmax":
+            w, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            return chosen.astype(jnp.int32), w / w.sum(axis=-1, keepdims=True)
+        s = jax.nn.sigmoid(logits)
         _, chosen = jax.lax.top_k(
             s + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
@@ -361,16 +367,20 @@ def _one_mesh_context(f):
     return in_context
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("top_k", "experts_held", "scale"))
+@functools.partial(jax.jit, static_argnames=(
+    "top_k", "experts_held", "scale", "router", "form"))
 @_one_mesh_context
 def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
-                 experts_held: Tuple[int, int], scale: float = 1.0):
+                 experts_held: Tuple[int, int], scale: float = 1.0,
+                 router: str = "sigmoid", form: str = "swiglu", rx=None):
     """The held experts' part of a routed expert layer. x: (N, D); gate: (D,
-    E) over all E experts; bias: (E,); w1, w3: (held, D, F); w2: (held, F,
-    D), the experts ``offset .. offset + held - 1``. An expert is ``W2
-    (silu(W1 x) * W3 x)``, or with ``w3`` None ``W2 relu(W1 x)^2``; a
-    token's k rows are weighed by its normalised scores times ``scale``.
+    E) over all E experts; bias: (E,), or None for the softmax ``router``
+    (``route_top_k``); w1, w3: (held, D, F); w2: (held, F, D), the experts
+    ``offset .. offset + held - 1``. An expert is of ``form``
+    (``EXPERT_FORMS``): ``W2 (act(W1 x) * W3 x)``, or for ``"relu2"``, with
+    ``w3`` None, ``W2 relu(W1 x)^2``; a token's k rows are weighed by its
+    normalised scores times ``scale``. The router reads ``rx`` (N, D) where
+    given, else x.
     Returns ((N, D), stats (4,) int32 as MOE_STATS names them).
 
     The row buffer has a static size, and gathers and elementwise passes
@@ -382,7 +392,12 @@ def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
     give a row of their own)."""
     N, D = x.shape
     offset, count = experts_held
-    chosen, w = route_top_k(x, gate, bias, top_k)
+    gated = EXPERT_FORMS[form] is not None
+    if gated != (w3 is not None):
+        raise ValueError(f"a {form} expert takes {'a' if gated else 'no'} "
+                         "second product (w3)")
+    chosen, w = route_top_k(x if rx is None else rx, gate, bias, top_k,
+                            router)
     if scale != 1.0:
         w = w * scale
     A = N * top_k
@@ -403,7 +418,7 @@ def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
         order = jnp.pad(order, (0, buffer_of(A) - A))
     held_part = functools.partial(
         _held_rows, order=order, place=place, is_held=local < count,
-        group_sizes=group_sizes, n_held=n_held, top_k=top_k)
+        group_sizes=group_sizes, n_held=n_held, top_k=top_k, form=form)
     if buffer_of(usual) >= buffer_of(A):
         out, placed = held_part(x, w, w1, w3, w2, rows=buffer_of(A))
     else:
@@ -421,7 +436,7 @@ def dropless_moe(x, gate, bias, w1, w3, w2, *, top_k: int,
 
 
 def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
-               n_held, top_k: int, rows: int):
+               n_held, top_k: int, rows: int, form: str = "swiglu"):
     """The held experts over a buffer of ``rows`` rows (at least ``n_held +
     1``): the tokens' rows gathered in expert order, the grouped products
     (three, or two with ``w3`` None), the rows taken back and each token's
@@ -450,16 +465,25 @@ def _held_rows(x, w, w1, w3, w2, *, order, place, is_held, group_sizes,
             a, b, group_sizes, a.dtype,
             _gmm_tiling(min(rows, N * top_k), b.shape[1], b.shape[2]), None,
             None, False, interpret)  # (the row tile the buffer was cut to)
-        up = product(xs, w1.astype(x.dtype))
-        act = (jnp.square(jax.nn.relu(up)) if w3 is None
-               else jax.nn.silu(up) * product(xs, w3.astype(x.dtype)))
+        act = _hidden(form, product(xs, w1.astype(x.dtype)),
+                      lambda: product(xs, w3.astype(x.dtype)))
         ys = product(jnp.where(valid, act, 0), w2.astype(x.dtype))
     with jax.named_scope("moe.shuffle.combine"):
         out = _take_rows("tokens", impl, ys, w, moves)
     return out, placed
 
 
-EXPERT_FORMS = ("swiglu", "relu2")
+# an expert's form -> the activation of its first product, which gates the
+# second; None: ``relu²`` of the first, and no second
+EXPERT_FORMS = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu, "relu2": None}
+ROUTERS = ("sigmoid", "softmax")
+
+
+def _hidden(form: str, up, second):
+    """An expert's hidden rows from its first product ``up``: ``act(up) *
+    second()`` for a gated form, ``relu(up)^2`` for ``"relu2"``."""
+    act = EXPERT_FORMS[form]
+    return jnp.square(jax.nn.relu(up)) if act is None else act(up) * second()
 
 
 class RoutedExperts(nn.Module):
@@ -467,16 +491,20 @@ class RoutedExperts(nn.Module):
     (B, T, D) -> ((B, T, D), stats (4,) int32). ``num_experts`` is the
     router's width; ``experts_held = (offset, count)`` the experts whose
     weights live here (all of them by default). ``form`` is an expert's:
-    ``"swiglu"`` (``w1``, ``w3``, ``w2``) or ``"relu2"`` (``w1``, ``w2``);
+    ``"swiglu"`` or ``"reglu"`` (``w1``, ``w3``, ``w2``: silu or relu of the
+    first product, times the second) or ``"relu2"`` (``w1``, ``w2``);
     ``scale`` multiplies the routed sum; ``shared_width`` > 0 adds a shared
     expert of the same form and that width, which every token passes
     through: computed here whole, whatever the share (scope
-    ``shared_expert``). The selection bias lives in the
-    collection ``buffers``, outside the optimizer: the layer reads it
-    and never moves it (how it is balanced is the training recipe's).
-    Under a mesh with a data axis each device routes its own rows (the
-    grouped product is a Mosaic kernel GSPMD cannot partition): counts are
-    summed over the devices, the largest load is the largest on any."""
+    ``shared_expert``). ``router`` is ``route_top_k``'s. The sigmoid
+    router's selection bias lives in the collection ``buffers``, outside
+    the optimizer: the layer reads it and never moves it (how it is
+    balanced is the training recipe's); the softmax router has none. The
+    router reads ``router_input`` (B, T, D) where the call gives one (a
+    router placed before the layer's attention), else ``x``. Under a mesh
+    with a data axis each device routes its own rows (the grouped product
+    is a Mosaic kernel GSPMD cannot partition): counts are summed over the
+    devices, the largest load is the largest on any."""
 
     dim: int
     width: int
@@ -488,33 +516,37 @@ class RoutedExperts(nn.Module):
     form: str = "swiglu"
     scale: float = 1.0
     shared_width: int = 0
+    router: str = "sigmoid"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         B, T, D = x.shape
         offset, count = self.experts_held or (0, self.num_experts)
         if not 0 <= offset <= offset + count <= self.num_experts:
             raise ValueError(
                 f"experts_held {(offset, count)} lies outside the router's "
                 f"{self.num_experts} experts")
-        if self.form not in EXPERT_FORMS:
-            raise ValueError(f"an expert's form is one of {EXPERT_FORMS}, "
-                             f"not {self.form!r}")
-        gated = self.form == "swiglu"
+        if self.form not in EXPERT_FORMS or self.router not in ROUTERS:
+            raise ValueError(
+                f"an expert's form is one of {sorted(EXPERT_FORMS)} and its "
+                f"router one of {ROUTERS}, not {self.form!r}, {self.router!r}")
+        gated = EXPERT_FORMS[self.form] is not None
         init = nn.initializers.normal(0.02)
         gate = self.param("gate", init, (D, self.num_experts), jnp.float32)
-        bias = self.variable("buffers", "expert_bias", jnp.zeros,
-                             (self.num_experts,), jnp.float32).value
+        bias = None if self.router == "softmax" else self.variable(
+            "buffers", "expert_bias", jnp.zeros, (self.num_experts,),
+            jnp.float32).value
         w1 = self.param("w1", init, (count, D, self.width), jnp.float32)
         w3 = (self.param("w3", init, (count, D, self.width), jnp.float32)
               if gated else None)
         w2 = self.param("w2", init, (count, self.width, D), jnp.float32)
 
-        def held_part(x, gate, bias, w1, w3, w2, data_axis=None):
+        def held_part(x, rx, gate, bias, w1, w3, w2, data_axis=None):
+            kw = {} if rx is None else {"rx": rx.reshape(-1, D)}
             out, stats = dropless_moe(
                 x.reshape(-1, D).astype(self.dtype), gate, bias, w1, w3, w2,
                 top_k=self.top_k, experts_held=(offset, count),
-                scale=self.scale)
+                scale=self.scale, router=self.router, form=self.form, **kw)
             if data_axis:
                 sums = jax.lax.psum(stats, data_axis)
                 stats = sums.at[2].set(jax.lax.pmax(stats[2], data_axis))
@@ -531,10 +563,11 @@ class RoutedExperts(nn.Module):
             rep = P()
             held_part = shard_map(
                 functools.partial(held_part, data_axis=AXIS_DATA), mesh=mesh,
-                in_specs=(P(AXIS_DATA), rep, rep, rep, rep, rep),
+                in_specs=(P(AXIS_DATA), P(AXIS_DATA), rep, rep, rep, rep,
+                          rep),
                 out_specs=(P(AXIS_DATA), rep), check_vma=False)
         if not self.shared_width:
-            return held_part(x, gate, bias, w1, w3, w2)
+            return held_part(x, router_input, gate, bias, w1, w3, w2)
         # (plain matmuls that GSPMD partitions by itself, outside the routed
         # part's ``shard_map``; not a method of the module: flax would put
         # ``moe._shared_expert`` in every op's name, and the routed experts'
@@ -544,16 +577,14 @@ class RoutedExperts(nn.Module):
         D, F = x.shape[-1], self.shared_width
         always = _shared_expert(
             x.astype(self.dtype), shared("w1", D, F),
-            shared("w3", D, F) if gated else None, shared("w2", F, D))
-        out, stats = held_part(x, gate, bias, w1, w3, w2)
+            shared("w3", D, F) if gated else None, shared("w2", F, D),
+            self.form)
+        out, stats = held_part(x, router_input, gate, bias, w1, w3, w2)
         return always + out, stats
 
 
-def _shared_expert(x, w1, w3, w2):
-    """The expert every token passes through, of the routed experts' form
-    (``w3`` None: ``relu²``)."""
+def _shared_expert(x, w1, w3, w2, form):
+    """The expert every token passes through, of the routed experts'
+    ``form``."""
     with jax.named_scope("shared_expert"):
-        up = x @ w1
-        act = (jnp.square(jax.nn.relu(up)) if w3 is None
-               else jax.nn.silu(up) * (x @ w3))
-        return act @ w2
+        return _hidden(form, x @ w1, lambda: x @ w3) @ w2

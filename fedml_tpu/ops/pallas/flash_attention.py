@@ -38,6 +38,25 @@ take each stripe's softmax. Any other block that straddles the diagonal
 (unequal or one-stripe blocks) runs as one square, the row >= col mask
 over all of it.
 
+A sliding window (``window``, a static int: query i sees key j where
+``0 <= i - j < window``, a sliding-window layer's band) is the same rule
+on both sides of the band (``_band_cases``): a key block wholly behind it,
+``q_start - (k_start + block_k - 1) >= window``, is skipped like a block
+above the diagonal, and its index map clamps to the first block that is
+needed as the causal map clamps to the last, so nothing is fetched for it;
+a block wholly inside runs unmasked; the diagonal keeps its stripes; a
+block that straddles the band's lower edge runs as one square masked by
+``i - j < window`` (the mirror of the diagonal: striping it is left for
+later). The blocks are capped so that two fit the band (``block_q +
+block_k <= window``): a pair then crosses one of its edges at most, and a
+band under two blocks of MIN_BLOCK is refused (the dispatch rule sends it
+to dense). A window of T or more is the causal call. ``window`` None
+traces exactly the causal kernels. Each windowed launcher opens the scope
+``attn.window`` itself,
+forward and backward (a custom VJP's backward inherits no scope), and
+``fedml_flash_window_total{pass, seq_len, window}`` counts the band a call
+runs, once per call site per trace.
+
 Gradients: custom VJP, probabilities recomputed blockwise from the saved
 per-row logsumexp (FlashAttention-2). Where it fits, one kernel on a
 (batch, head group, k-block, q-block) grid makes all three: dk/dv accumulate
@@ -47,12 +66,14 @@ the whole-sequence dq output grow with T, W and the dtype: where
 ``_bwd_vmem`` puts them past Mosaic's scoped VMEM (T = 8192 fits at
 W = 128 in bf16, T = 4096 in float32 or at W = 256), dq has its own kernel
 on the forward's grid (seven contractions). That split pair is the only
-backward such lengths have. No cell takes it and nothing has timed it:
-``chip_smoke.py`` runs it against dense on the chip at T = 12288, the tests
-in interpret mode, and it was compiled ahead of time for the v5e over the
-shapes of PERF.md section 6 (PR 27).
+backward such lengths have. The SmallThinker cell takes it (T = 16384,
+W = 128, bf16: every layer, windowed or global), and ``chip_smoke.py``
+runs it against dense on the chip at T = 12288 and, over a band, at the
+cell's length.
 
-What PR 27's sweeps on the v5e found (PERF.md section 6 has the tables):
+What PR 27's sweeps on the v5e found, all of it for the causal kernels
+(``window`` None), whose bodies stay as they were (PERF.md section 6 has the
+tables):
 the kernels are bound by vector loads, stores and lane shuffles, not by the
 MXU (float32 operands ran as fast as bf16 ones), so the softmax state is
 kept lane-replicated, which took a third off the forward; 1024 squares are
@@ -70,6 +91,7 @@ tests validate numerics everywhere.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -156,28 +178,63 @@ def _stripes(causal: bool, block_q: int, block_k: int) -> bool:
     return causal and block_q == block_k and block_q >= 2 * STRIPE
 
 
-def _causal_cases(run, causal, q_start, block_q, k_start, block_k):
+def _causal_cases(run, causal, q_start, block_q, k_start, block_k,
+                  window=None):
     """Run this block pair as ``run(parts)``, each part (rows, keys, mask):
     ``rows`` a slice of the q block, ``keys`` of the k block, ``mask`` None
     or what masks their scores. Not at all above the diagonal, one unmasked
     part under it. A pair that straddles it is one masked part, or, where
     ``_stripes``, a part for each stripe of STRIPE query rows over the
-    prefix of keys it can see, only its last STRIPE columns masked."""
+    prefix of keys it can see, only its last STRIPE columns masked. With a
+    ``window``: ``_band_cases``."""
     whole = slice(None)
     if not causal:
         run([(whole, whole, None)])
+        return
+    if window is not None:
+        _band_cases(run, q_start, block_q, k_start, block_k, window)
         return
     under = k_start + block_k - 1 <= q_start
     pl.when(under)(lambda: run([(whole, whole, None)]))
     straddles = jnp.logical_and(jnp.logical_not(under),
                                 k_start <= q_start + block_q - 1)
-    if not _stripes(causal, block_q, block_k):
+    _diagonal(run, straddles, q_start, block_q, k_start, block_k)
+
+
+def _diagonal(run, straddles, q_start, block_q, k_start, block_k):
+    """A pair that straddles the diagonal, where ``straddles``: one square
+    masked part, or the stripes."""
+    whole = slice(None)
+    if not _stripes(True, block_q, block_k):
         pl.when(straddles)(lambda: run([(whole, whole, lambda s: jnp.where(
             _keep(s.shape, q_start, k_start), s, NEG_INF))]))
         return
     pl.when(straddles)(lambda: run([
         (slice(lo, lo + STRIPE), slice(0, lo + STRIPE), _mask_last)
         for lo in range(0, block_q, STRIPE)]))
+
+
+def _band_cases(run, q_start, block_q, k_start, block_k, window):
+    """The causal band ``0 <= i - j < window``: a pair wholly outside it
+    (above the diagonal, or every key ``window`` or more behind every query
+    of the block) is not run; one wholly inside runs unmasked; the diagonal
+    as ``_diagonal``; one that straddles the band's lower edge runs masked
+    by ``i - j < window``. Two blocks fit the band (``_resolve_blocks``), so
+    no pair crosses both edges."""
+    whole = slice(None)
+    least = q_start - (k_start + block_k - 1)   # the pair's least i - j
+    most = q_start + block_q - 1 - k_start      # and its greatest
+    inside = jnp.logical_and(least >= 0, most < window)
+    pl.when(inside)(lambda: run([(whole, whole, None)]))
+    edge = jnp.logical_and(
+        jnp.logical_and(most >= 0, least < window), jnp.logical_not(inside))
+    # (a diagonal pair's greatest i - j is under block_q + block_k - 2, so
+    # here it never reaches the lower edge, nor a lower-edge pair the diagonal)
+    _diagonal(run, jnp.logical_and(edge, least < 0), q_start, block_q,
+              k_start, block_k)
+    pl.when(jnp.logical_and(edge, least >= 0))(lambda: run([
+        (whole, whole, lambda s: jnp.where(
+            _near(s.shape, q_start, k_start, window), s, NEG_INF))]))
 
 
 def _mask_last(s):
@@ -195,6 +252,13 @@ def _keep(shape, q_start, k_start):
     return rows >= cols
 
 
+def _near(shape, q_start, k_start, window):
+    """The scores whose key is fewer than ``window`` positions behind."""
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return rows - cols < window
+
+
 def _scores(a, b, mask):
     s = _dot(a, b, _NT)
     return s if mask is None else mask(s)
@@ -202,7 +266,7 @@ def _scores(a, b, mask):
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                   q_scr, m_scr, l_scr, acc_scr, *, hp: int, Dh: int,
-                  block_k: int, causal: bool, scale: float):
+                  block_k: int, causal: bool, scale: float, window=None):
     """Grid (B, H//hp, T//block_q, T//block_k), k innermost. Refs:
     q/o (1, block_q, W), k/v (1, block_k, W), lse (1, 1, hp, block_q).
     Scratch: q_scr (hp, block_q, W) the scaled q with one head's lanes
@@ -251,7 +315,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             acc_scr[rows] = (acc_scr[rows] * _by_head(corrs, Dh)
                              + _by_head(pvs, Dh))
 
-    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k, window)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -264,7 +328,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                q_scr, do_scr, dq_scr, *, hp: int, Dh: int, block_k: int,
-               causal: bool, scale: float):
+               causal: bool, scale: float, window=None):
     """The forward's grid: one q block accumulates dq over the streamed key
     blocks; p recomputed from (q, k, lse). Only where the fused backward's
     whole-sequence dq does not fit VMEM (``_bwd_vmem``)."""
@@ -301,7 +365,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 dqs.append(_dot(ds.astype(k_blk.dtype), k_blk, _NN))
             dq_scr[rows] = dq_scr[rows] + _by_head(dqs, Dh)
 
-    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k, window)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -310,7 +374,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 hp: int, Dh: int, block_q: int, causal: bool, scale: float,
-                fused: bool):
+                fused: bool, window=None):
     """Grid (B, H//hp, T//block_k, T//block_q), q innermost: one key block
     accumulates dk/dv over the streamed query blocks. k_scr holds the scaled
     k with one head's lanes each, so ``ds @ k_scr[h]`` is that head's dq
@@ -369,7 +433,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
                 at = pl.ds(pl.multiple_of(q_start + lo, MIN_BLOCK), hi - lo)
                 dq_scr[at, :] = dq_scr[at, :] + dq
 
-    _causal_cases(run, causal, q_start, block_q, k_start, block_k)
+    _causal_cases(run, causal, q_start, block_q, k_start, block_k, window)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -383,19 +447,28 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *refs,
 
 
 def _specs(block_q: int, block_k: int, W: int, hp: int, causal: bool,
-           q_inner: bool):
+           q_inner: bool, window=None):
     """BlockSpecs over the (B, T, G*W) arrays and the (B, G, hp, T) rows,
     for a grid (b, g, i, j) whose inner axis j walks key blocks, or query
     blocks when ``q_inner``. A causal inner index repeats the nearest
-    block that is needed where the step is skipped: no fetch for it."""
+    block that is needed where the step is skipped: no fetch for it; with a
+    ``window`` on both sides of the band."""
     if q_inner:
         def qk(i, j):
             first = (i * block_k) // block_q  # first q block at or past k's
-            return (jnp.maximum(j, first) if causal else j), i
+            j = jnp.maximum(j, first) if causal else j
+            if window is not None:  # the last q block that sees k's
+                j = jnp.minimum(
+                    j, (i * block_k + block_k + window - 2) // block_q)
+            return j, i
     else:
         def qk(i, j):
             last = (i * block_q + block_q - 1) // block_k
-            return i, (jnp.minimum(j, last) if causal else j)
+            j = jnp.minimum(j, last) if causal else j
+            if window is not None:  # the first key block in i's band
+                j = jnp.maximum(
+                    j, jnp.maximum(i * block_q - window + 1, 0) // block_k)
+            return i, j
 
     q_tile = pl.BlockSpec(
         (1, block_q, W), lambda b, g, i, j: (b, qk(i, j)[0], g))
@@ -444,96 +517,115 @@ def _bwd_vmem(T: int, block_q: int, block_k: int, W: int, hp: int,
     return piped + scratch + spilled + whole_dq
 
 
+def _window_scope(window):
+    """``attn.window`` around a windowed launcher's body, nothing around
+    another: a custom VJP's backward inherits no scope from its caller, so
+    the launchers name their own, both ways."""
+    if window is None:
+        return contextlib.nullcontext()
+    return jax.named_scope("attn.window")
+
+
 # The launchers are jitted so that a model's layers share one trace and one
 # Mosaic lowering of each kernel: lowering a pallas_call is Python-side work
 # that no compilation cache skips, and 96 of them added 21 s to every warm
 # start of the 24-layer LM step (my chip runs, PR 27: 66 s against 45 s).
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6))
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
-    block_q: int, block_k: int, interpret: bool,
+    block_q: int, block_k: int, interpret: bool, window=None,
 ):
     """q/k/v: (B, T, H, Dh) -> (out (B, T, H, Dh), lse (B, H//hp, hp, T)
     f32: the head group's rows are a block's last two dims, equal to the
     array's, which is Mosaic's rule for blocks under (8, 128))."""
-    B, T, H, Dh = q.shape
-    hp = heads_per_step(H, Dh)
-    G, W = H // hp, hp * Dh
-    q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, False)
-    out, lse = pl.pallas_call(
-        functools.partial(_flash_kernel, hp=hp, Dh=Dh, block_k=block_k,
-                          causal=causal, scale=1.0 / (Dh ** 0.5)),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
-            jax.ShapeDtypeStruct((B, G, hp, T), jnp.float32),
-        ),
-        grid=(B, G, T // block_q, T // block_k),
-        in_specs=[q_tile, k_tile, k_tile],
-        out_specs=(q_tile, q_rows),
-        scratch_shapes=[
-            pltpu.VMEM((hp, block_q, W), q.dtype),
-            pltpu.VMEM((hp, block_q, 128), jnp.float32),
-            pltpu.VMEM((hp, block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, W), jnp.float32),
-        ],
-        **_params(interpret),
-    )(*(t.reshape(B, T, H * Dh) for t in (q, k, v)))
-    return out.reshape(B, T, H, Dh), lse
-
-
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret):
-    """Blockwise dq/dk/dv; q/k/v/out/g (B, T, H, Dh), lse (B, H//hp, hp, T)."""
-    B, T, H, Dh = q.shape
-    hp = heads_per_step(H, Dh)
-    G, W = H // hp, hp * Dh
-    scale = 1.0 / (Dh ** 0.5)
-    # delta_i = sum_d dO_id * O_id — O(T*Dh), plain XLA (fuses into one pass)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = delta.reshape(B, T, G, hp).transpose(0, 2, 3, 1)  # lse's layout
-    flat = (B, T, H * Dh)
-    args = (*(t.reshape(flat) for t in (q, k, v, g)), lse, delta)
-    fused = _bwd_vmem(T, block_q, block_k, W, hp, q.dtype.itemsize,
-                      fused=True) <= _VMEM_LIMIT
-    like = lambda t: jax.ShapeDtypeStruct(flat, t.dtype)  # noqa: E731
-
-    q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, True)
-    dq_whole = pl.BlockSpec((1, T, W), lambda b, g, i, j: (b, 0, g))
-    grads = pl.pallas_call(
-        functools.partial(_dkv_kernel, hp=hp, Dh=Dh, block_q=block_q,
-                          causal=causal, scale=scale, fused=fused),
-        out_shape=(like(k), like(v)) + ((like(q),) if fused else ()),
-        grid=(B, G, T // block_k, T // block_q),
-        in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
-        out_specs=(k_tile, k_tile) + ((dq_whole,) if fused else ()),
-        scratch_shapes=[
-            pltpu.VMEM((hp, block_k, W), k.dtype),
-            pltpu.VMEM((hp, block_k, W), v.dtype),
-            pltpu.VMEM((block_k, W), jnp.float32),
-            pltpu.VMEM((block_k, W), jnp.float32),
-        ] + ([pltpu.VMEM((T, W), jnp.float32)] if fused else []),
-        **_params(interpret, carried_over_blocks=fused),
-    )(*args)
-    if fused:
-        dk, dv, dq = grads
-    else:
-        dk, dv = grads
-        q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, False)
-        dq = pl.pallas_call(
-            functools.partial(_dq_kernel, hp=hp, Dh=Dh, block_k=block_k,
-                              causal=causal, scale=scale),
-            out_shape=like(q),
+    with _window_scope(window):
+        B, T, H, Dh = q.shape
+        hp = heads_per_step(H, Dh)
+        G, W = H // hp, hp * Dh
+        q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal,
+                                        False, window)
+        out, lse = pl.pallas_call(
+            functools.partial(_flash_kernel, hp=hp, Dh=Dh, block_k=block_k,
+                              causal=causal, scale=1.0 / (Dh ** 0.5),
+                              window=window),
+            out_shape=(
+                jax.ShapeDtypeStruct((B, T, H * Dh), q.dtype),
+                jax.ShapeDtypeStruct((B, G, hp, T), jnp.float32),
+            ),
             grid=(B, G, T // block_q, T // block_k),
-            in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
-            out_specs=q_tile,
+            in_specs=[q_tile, k_tile, k_tile],
+            out_specs=(q_tile, q_rows),
             scratch_shapes=[
                 pltpu.VMEM((hp, block_q, W), q.dtype),
-                pltpu.VMEM((hp, block_q, W), g.dtype),
+                pltpu.VMEM((hp, block_q, 128), jnp.float32),
+                pltpu.VMEM((hp, block_q, 128), jnp.float32),
                 pltpu.VMEM((block_q, W), jnp.float32),
             ],
             **_params(interpret),
+        )(*(t.reshape(B, T, H * Dh) for t in (q, k, v)))
+        return out.reshape(B, T, H, Dh), lse
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
+                    window=None):
+    """Blockwise dq/dk/dv; q/k/v/out/g (B, T, H, Dh), lse (B, H//hp, hp, T)."""
+    with _window_scope(window):
+        B, T, H, Dh = q.shape
+        hp = heads_per_step(H, Dh)
+        G, W = H // hp, hp * Dh
+        scale = 1.0 / (Dh ** 0.5)
+        # delta_i = sum_d dO_id * O_id — O(T*Dh), plain XLA (one fused pass)
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        # in lse's layout
+        delta = delta.reshape(B, T, G, hp).transpose(0, 2, 3, 1)
+        flat = (B, T, H * Dh)
+        args = (*(t.reshape(flat) for t in (q, k, v, g)), lse, delta)
+        fused = _bwd_vmem(T, block_q, block_k, W, hp, q.dtype.itemsize,
+                          fused=True) <= _VMEM_LIMIT
+        like = lambda t: jax.ShapeDtypeStruct(flat, t.dtype)  # noqa: E731
+
+        q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, True,
+                                        window)
+        dq_whole = pl.BlockSpec((1, T, W), lambda b, g, i, j: (b, 0, g))
+        grads = pl.pallas_call(
+            functools.partial(_dkv_kernel, hp=hp, Dh=Dh, block_q=block_q,
+                              causal=causal, scale=scale, fused=fused,
+                              window=window),
+            out_shape=(like(k), like(v)) + ((like(q),) if fused else ()),
+            grid=(B, G, T // block_k, T // block_q),
+            in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
+            out_specs=(k_tile, k_tile) + ((dq_whole,) if fused else ()),
+            scratch_shapes=[
+                pltpu.VMEM((hp, block_k, W), k.dtype),
+                pltpu.VMEM((hp, block_k, W), v.dtype),
+                pltpu.VMEM((block_k, W), jnp.float32),
+                pltpu.VMEM((block_k, W), jnp.float32),
+            ] + ([pltpu.VMEM((T, W), jnp.float32)] if fused else []),
+            **_params(interpret, carried_over_blocks=fused),
         )(*args)
-    return tuple(t.reshape(B, T, H, Dh) for t in (dq, dk, dv))
+        if fused:
+            dk, dv, dq = grads
+        else:
+            dk, dv = grads
+            q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal,
+                                            False, window)
+            dq = pl.pallas_call(
+                functools.partial(_dq_kernel, hp=hp, Dh=Dh, block_k=block_k,
+                                  causal=causal, scale=scale, window=window),
+                out_shape=like(q),
+                grid=(B, G, T // block_q, T // block_k),
+                in_specs=[q_tile, k_tile, k_tile, q_tile, q_rows, q_rows],
+                out_specs=q_tile,
+                scratch_shapes=[
+                    pltpu.VMEM((hp, block_q, W), q.dtype),
+                    pltpu.VMEM((hp, block_q, W), g.dtype),
+                    pltpu.VMEM((block_q, W), jnp.float32),
+                ],
+                **_params(interpret),
+            )(*args)
+        return tuple(t.reshape(B, T, H, Dh) for t in (dq, dk, dv))
 
 
 def _auto_blocks(T: int, H: int, Dh: int, itemsize: int):
@@ -547,11 +639,19 @@ def _auto_blocks(T: int, H: int, Dh: int, itemsize: int):
             T, b, b, hp * Dh, hp, itemsize, fused=False) <= _VMEM_LIMIT))
 
 
-def _resolve_blocks(q, block_q, block_k, backward: bool):
+def _resolve_blocks(q, block_q, block_k, backward: bool, window=None):
+    """A pass's (block_q, block_k): the explicit ones, else the auto block;
+    with a ``window``, no larger than half of it (``_band_cases``)."""
     _, T, H, Dh = q.shape
     auto = _auto_blocks(T, H, Dh, q.dtype.itemsize)[backward]
+    if window is not None and auto is not None:
+        auto = auto_block(T, min(auto, window // 2))
     bq = block_q or auto
     bk = block_k or auto
+    if window is not None and (bq is None or bk is None or bq + bk > window):
+        raise ValueError(
+            f"flash_attention: a band of {window} keys holds no two blocks of "
+            f"at least {MIN_BLOCK} (got {bq}, {bk}): take the dense path")
     if bq is None or bk is None or T % bq or T % bk:
         raise ValueError(
             f"flash_attention: T={T} has no block tiling that fits VMEM "
@@ -564,22 +664,30 @@ def flash_attention(
     causal: bool = False,
     block_q: int | None = None,
     block_k: int | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention with K-blocked pallas forward AND backward.
     q/k/v (B, T, H, Dh); block sizes default to auto_block's tiling for T
     under each pass's measured cap (FWD_BLOCK, BWD_BLOCK), and explicit ones
     hold for both passes; requires T % block == 0 (callers fall back to
-    dense otherwise)."""
+    dense otherwise). ``window`` (causal only): query i sees key j where
+    ``0 <= i - j < window``; one of T or more is the plain causal call."""
+    if window is not None:
+        if not causal:
+            raise ValueError("flash_attention: a window is a causal band")
+        if window >= q.shape[1]:
+            window = None
     # counted here, where a call site is traced once: under remat jax traces
     # the custom VJP's primal and its forward rule both
-    _count_diagonal("fwd", q, causal,
-                    *_resolve_blocks(q, block_q, block_k, backward=False))
-    return _flash_attention(q, k, v, causal, block_q, block_k)
+    blocks = _resolve_blocks(q, block_q, block_k, False, window)
+    _count_diagonal("fwd", q, causal, *blocks)
+    _count_window("fwd", q, window)
+    return _flash_attention(q, k, v, causal, block_q, block_k, window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention(q, k, v, causal, block_q, block_k):
-    return _fwd(q, k, v, causal, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention(q, k, v, causal, block_q, block_k, window):
+    return _fwd(q, k, v, causal, block_q, block_k, window)[0]
 
 
 def _count_diagonal(pass_: str, q, causal, block_q, block_k) -> None:
@@ -593,19 +701,33 @@ def _count_diagonal(pass_: str, q, causal, block_q, block_k) -> None:
                                seq_len=q.shape[1], **{"pass": pass_}).inc()
 
 
-def _fwd(q, k, v, causal, block_q, block_k):
+def _count_window(pass_: str, q, window) -> None:
+    """``fedml_flash_window_total{pass, seq_len, window}``: the band a call
+    runs (``none``: the whole causal triangle, or no mask), counted as
+    ``_count_diagonal`` counts."""
+    if isinstance(q, jax.core.Tracer):
+        get_registry().counter(
+            "fedml_flash_window_total", seq_len=q.shape[1],
+            window="none" if window is None else window,
+            **{"pass": pass_}).inc()
+
+
+def _fwd(q, k, v, causal, block_q, block_k, window):
     interpret = jax.default_backend() != "tpu"
-    block_q, block_k = _resolve_blocks(q, block_q, block_k, backward=False)
-    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret)
+    block_q, block_k = _resolve_blocks(q, block_q, block_k, False, window)
+    out, lse = _flash_forward(q, k, v, causal, block_q, block_k, interpret,
+                              window)
     return out, (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_k, res, g):
+def _bwd(causal, block_q, block_k, window, res, g):
     q, k, v, out, lse = res
     interpret = jax.default_backend() != "tpu"
-    block_q, block_k = _resolve_blocks(q, block_q, block_k, backward=True)
+    block_q, block_k = _resolve_blocks(q, block_q, block_k, True, window)
     _count_diagonal("bwd", q, causal, block_q, block_k)
-    return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret)
+    _count_window("bwd", q, window)
+    return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
+                           interpret, window)
 
 
 _flash_attention.defvjp(_fwd, _bwd)

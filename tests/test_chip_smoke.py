@@ -54,6 +54,15 @@ def test_stage_lm_and_flash_check_tiny():
     assert fvd["mosaic_calls_lowered"] == 0
 
 
+def test_windowed_flash_check_tiny():
+    """The window stage's check at a band of half of T (blocks of 128, a
+    lower-edge block straddling the band, the diagonal apart)."""
+    band = dict(chip_smoke.FLASH_WINDOW, seq=512, heads=2, dh=128, window=256)
+    out = chip_smoke.check_flash_vs_dense(**band)
+    assert out["mosaic_calls_lowered"] == 0
+    assert max(out["rel_err"].values()) <= 2e-2
+
+
 def test_stage_hybrid_lm_tiny():
     tiny = dict(chip_smoke.HYBRID_LM, vocab_size=64, hidden_size=32,
                 intermediate_size=64, moe_intermediate_size=16,

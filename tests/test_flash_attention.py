@@ -1,5 +1,7 @@
 """Pallas flash attention: numerics vs dense, causal masking, gradients."""
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -461,3 +463,159 @@ def test_auto_dispatch_uses_flash_at_long_t(caplog):
         out = multihead_attention(q, q, q)
     assert out.shape == q.shape
     assert "DENSE" not in caplog.text  # no dense fallback = flash engaged
+
+
+# (T, window, block_q, block_k, fused): the default blocks of T = 512 and
+# 1024 capped to half the band (128 under 256 and 384); 128 squares under
+# windows of 256 and 384, whose lower-edge blocks straddle the band and whose
+# diagonal blocks run apart from it; unequal blocks; 256 squares under a band
+# of 512 through the split backward's dq kernel; a window of T or more, the
+# plain causal call
+WINDOWED = [(512, 256, None, None, True), (512, 256, 128, 128, True),
+            (512, 384, 128, 128, True), (1024, 384, None, None, True),
+            (1024, 256, 128, 128, True), (1024, 512, 256, 256, False),
+            (512, 512, None, None, True), (512, 600, 256, 256, True)]
+
+
+@pytest.mark.parametrize("T,window,block_q,block_k,fused", WINDOWED)
+def test_windowed_flash_matches_the_dense_band(T, window, block_q, block_k,
+                                               fused, monkeypatch):
+    """The output and dq, dk, dv of a causal band ``0 <= i - j < window``
+    against the dense path's band, forward, the fused backward and the split
+    pair (the limit patched as ``test_flash_gradients_match_dense`` patches
+    it); a window of T or more is the causal call itself, bit for bit."""
+    mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
+    H, Dh = 2, 64
+    if not fused:  # room for the split pair's tiles, none for the fused dq
+        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+            T, block_q, block_k, 128, H, 4, fused=False))
+    jax.clear_caches()
+    q, k, v = _qkv(B=1, T=T, H=H, Dh=Dh, seed=window)
+    w = jnp.cos(jnp.arange(q.size).reshape(q.shape) * 0.01)
+
+    def run(attn):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out, *vjp(w))
+
+    got = run(lambda q, k, v: flash_attention(q, k, v, True, block_q, block_k,
+                                              window=window))
+    jax.clear_caches()
+    want = run(lambda q, k, v: multihead_attention(
+        q, k, v, causal=True, impl="dense", window=window))
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4)
+    if window >= T:
+        plain = run(lambda q, k, v: flash_attention(q, k, v, True, block_q,
+                                                    block_k))
+        for a, b in zip(got, plain):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    else:  # the band is not the triangle
+        assert not np.allclose(np.asarray(got[0]), np.asarray(
+            multihead_attention(q, k, v, causal=True, impl="dense")),
+            atol=1e-3)
+    grad = jax.grad(lambda q: flash_attention(
+        q, k, v, True, block_q, block_k, window=window).sum())
+    assert str(jax.make_jaxpr(grad)(q)).count("pallas_call") == (
+        2 if fused else 3)
+    jax.clear_caches()
+
+
+def test_a_window_is_a_causal_band():
+    q, k, v = _qkv(B=1, T=256)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, False, window=128)
+
+
+@pytest.mark.parametrize("window,block", [(128, None), (384, 256)])
+def test_a_band_that_holds_no_two_blocks_is_refused(window, block):
+    """The kernels take blocks that two of fit the band, so that no pair of
+    blocks crosses both of its edges: a band of 128 holds no two of the
+    smallest, and explicit 256 squares do not fit one of 384."""
+    q, k, v = _qkv(B=1, T=1024)
+    with pytest.raises(ValueError, match="no two blocks"):
+        flash_attention(q, k, v, True, block, block, window=window)
+
+
+def test_auto_dispatch_sends_a_band_too_narrow_for_the_kernels_to_dense():
+    from fedml_tpu.ops.attention import auto_attention_impl
+
+    assert auto_attention_impl(1, 2, 8192, 128) == "flash"
+    assert auto_attention_impl(1, 2, 8192, 128, window=256) == "flash"
+    assert auto_attention_impl(1, 2, 8192, 128, window=128) == "dense"
+    assert auto_attention_impl(1, 2, 8192, 128, window=8192) == "flash"
+
+
+def test_window_counter_counts_once_per_traced_call_site():
+    """``fedml_flash_window_total{pass, seq_len, window}``: the band each
+    call runs (``none``: the triangle, or no mask at all), where its blocks
+    are resolved, once per call site per trace, as the diagonal's counter;
+    a window of T or more runs, and counts, as ``none``."""
+    from fedml_tpu.core.telemetry import get_registry
+
+    def count(T):
+        return {(p, w): get_registry().counter(
+            "fedml_flash_window_total", seq_len=T, window=w,
+            **{"pass": p}).value
+            for p in ("fwd", "bwd") for w in ("none", "256")}
+
+    def after(T, fn, *args):
+        before = count(T)
+        fn(*args)
+        return {key: n - before[key] for key, n in count(T).items()
+                if n != before[key]}
+
+    q = jnp.zeros((1, 512, 2, 64), jnp.float32)
+    sites = jax.jit(jax.grad(lambda q: (
+        flash_attention(q, q, q, True, window=256)
+        + flash_attention(q, q, q, True) + flash_attention(q, q, q, False)
+        + flash_attention(q, q, q, True, window=512)).sum()))
+    assert after(512, sites, q) == {("fwd", "256"): 1, ("bwd", "256"): 1,
+                                    ("fwd", "none"): 3, ("bwd", "none"): 3}
+    assert after(512, sites, q) == {}                       # compiled
+    assert after(512, flash_attention, q, q, q, True) == {}  # eager
+
+
+@pytest.mark.parametrize("metric,reads", [("flash_window_share.st21", 75.0),
+                                          ("flash_striped_share.st21", 100.0)])
+def test_flash_window_share_reads_the_st21_cell(monkeypatch, metric, reads):
+    """``benchmark/layer_metrics/<metric>.json`` through its reader, at the
+    SmallThinker cell's traffic: nothing before a call at ``seq_len`` is
+    traced (as on the parent, which has no window counter), then, once one
+    period's attention is (the global layer's calls and three windowed
+    layers', forward and backward; traced, never run): 75 windowed, and
+    every diagonal striped, the windowed layers' as the global one's."""
+    import importlib.util
+    import json
+    import os
+
+    from fedml_tpu.core import telemetry
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    reg = telemetry.MetricsRegistry(enabled=True)
+    monkeypatch.setattr(telemetry, "get_registry", lambda: reg)
+    monkeypatch.setattr(sys.modules["fedml_tpu.ops.pallas.flash_attention"],
+                        "get_registry", lambda: reg)
+    spec = importlib.util.spec_from_file_location(
+        "bench_program_counter",
+        os.path.join(bench, "readers", "program_counter.py"))
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    with open(os.path.join(bench, "layer_metrics", metric + ".json")) as f:
+        spec = json.load(f)
+    with open(os.path.join(bench, "traffic", "t16384_b1_pretrain.json")) as f:
+        ctx = {"traffic": json.load(f)}
+    with open(os.path.join(bench, "configs", "smallthinker_21b_a3b.json")) as f:
+        cfg = json.load(f)
+    assert reader.read(ctx, **spec["args"]) is None
+    q = jax.ShapeDtypeStruct((1, ctx["traffic"]["seq_len"], 2, 128),
+                             jnp.bfloat16)
+
+    def period(q):
+        for windowed in cfg["sliding_window_layout"]:
+            q = flash_attention(q, q, q, True, window=(
+                cfg["sliding_window_size"] if windowed else None))
+        return q.astype(jnp.float32).sum()
+
+    jax.jit(jax.grad(period)).trace(q)
+    assert reader.read(ctx, **spec["args"]) == reads

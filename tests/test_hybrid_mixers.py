@@ -116,7 +116,8 @@ def test_relu2_experts_and_the_scaling_factor_match_a_dense_loop(relu2_layer):
 
     def program(x, gate, w1, w2):
         return moe.dropless_moe(x, gate, bias, w1[8:16], None, w2[8:16],
-                                top_k=6, experts_held=held, scale=2.5)[0]
+                                top_k=6, experts_held=held, scale=2.5,
+                                form="relu2")[0]
 
     def plain(x, gate, w1, w2):
         return _plain_relu2(x, gate, bias, w1, w2, sw1, sw2, shape, held,
@@ -130,7 +131,8 @@ def test_relu2_experts_and_the_scaling_factor_match_a_dense_loop(relu2_layer):
             close(g, w)
         # the scaling factor multiplies the routed sum and nothing else
         unscaled = moe.dropless_moe(x, gate, bias, w1[8:16], None, w2[8:16],
-                                    top_k=6, experts_held=held)[0]
+                                    top_k=6, experts_held=held,
+                                    form="relu2")[0]
         close(2.5 * unscaled, program(x, gate, w1, w2))
 
 

@@ -144,7 +144,8 @@ def test_the_relu2_expert_block_compiles_at_widths_that_are_no_tile_multiples(
 
     def loss(x, gate, bias, w1, w2):
         out, stats = dropless_moe(x, gate, bias, w1, None, w2, top_k=k,
-                                  experts_held=(0, held), scale=2.5)
+                                  experts_held=(0, held), scale=2.5,
+                                  form="relu2")
         return out.astype(jnp.float32).sum(), stats
 
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
@@ -321,3 +322,32 @@ def test_the_flash_pair_compiles_with_striped_diagonal_blocks(topo, monkeypatch,
              if "tpu_custom_call" in line and "custom-call(" in line]
     assert len(calls) == 2, calls
     assert sorted("_flash_backward" in n for n in calls) == [False, True]
+
+
+@pytest.mark.parametrize("B,H", [(1, 28), (2, 2)], ids=["cell", "two_groups"])
+def test_the_windowed_flash_pair_compiles_with_its_scope(topo, monkeypatch, B, H):
+    """The SmallThinker cell's windowed attention (T = 16384, head width
+    128, bf16, a band of 4096; and at two head groups and B = 2, where a
+    probe at one group once compiled what two refused) forward and backward
+    compiled for the v5e: Mosaic takes the band's bodies inside scoped VMEM;
+    at this length the backward is the split pair (the fused one's
+    whole-sequence dq does not fit), so three call sites, each under the
+    ``attn.window`` scope the launchers open themselves, which is what
+    ``attn_window_ms.st21`` and the two ``flash_window_*_roofline.st21``
+    read."""
+    from fedml_tpu.ops.pallas import flash_attention
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+    sds = jax.ShapeDtypeStruct((B, 16384, H, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True, window=4096).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(sds, sds, sds).compile().as_text()
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in text.splitlines()
+             if "tpu_custom_call" in line and "custom-call(" in line]
+    assert len(calls) == 3, calls
+    assert all("attn.window" in n for n in calls), calls
+    assert sorted("_flash_backward" in n for n in calls) == [False, True, True]
+    assert [n for n in calls if "transpose(" in n]  # the backward's, named
