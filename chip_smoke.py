@@ -59,7 +59,8 @@ FLAGSHIP = dict(
 FLAGSHIP_ROUNDS = 3           # eval runs at round 0 and at the last round
 LM_MODEL = dict(vocab_size=32000, dim=1024, num_heads=16, num_layers=12)
 LM_SEQ = 4096                 # long context; "flash" on a TPU from T=1024
-FLASH_SPLIT_SEQ = 12288       # past what one backward kernel holds of dq in VMEM
+# past what Mosaic's default scoped VMEM holds of one backward kernel's dq
+FLASH_FUSED_SEQ = 12288
 # the SmallThinker cell's windowed attention: T, head width and band (at two
 # heads: the dense path it is checked against holds the (T, T) scores)
 FLASH_WINDOW = dict(seq=16384, heads=2, dh=128, window=4096)
@@ -127,12 +128,18 @@ def rel_err(got, want) -> float:
                  / max(np.linalg.norm(want), 1e-30))
 
 
-def mosaic_calls(fn, *args) -> int:
+def mosaic_calls(fn, *args, raised: bool = False) -> int:
     """How many compiled Mosaic kernels the lowered program holds. Zero
-    means the Pallas call ran interpreted or took a jnp reference."""
+    means the Pallas call ran interpreted or took a jnp reference.
+    ``raised``: those that ask for more scoped VMEM than Mosaic's default
+    (``scoped_memory_configs`` in the call's backend config)."""
     import jax
 
-    return jax.jit(fn).lower(*args).as_text().count("tpu_custom_call")
+    calls = [line for line in jax.jit(fn).lower(*args).as_text().splitlines()
+             if "tpu_custom_call" in line]
+    if raised:
+        calls = [line for line in calls if "scoped_memory_configs" in line]
+    return len(calls)
 
 
 def memory_stats() -> list:
@@ -511,11 +518,11 @@ def check_flash_vs_dense(seq: int, heads: int, dh: int, batch: int = 1,
     for name, e in errs.items():
         require(np.isfinite(e) and e <= 2e-2,
                 f"flash vs dense {name}: relative error {e:.3e} > 2e-2")
+    grad = jax.grad(lambda q, k, v: loss("flash")(q, k, v)[0], (0, 1, 2))
     return {
         "rel_err": {k_: float(f"{e:.3e}") for k_, e in errs.items()},
-        "mosaic_calls_lowered": mosaic_calls(
-            jax.grad(lambda q, k, v: loss("flash")(q, k, v)[0], (0, 1, 2)),
-            q, k, v),
+        "mosaic_calls_lowered": mosaic_calls(grad, q, k, v),
+        "mosaic_calls_raised_vmem": mosaic_calls(grad, q, k, v, raised=True),
     }
 
 
@@ -952,16 +959,19 @@ def main() -> int:
     fvd = run("flash_vs_dense", check_flash_vs_dense, LM_SEQ,
               LM_MODEL["num_heads"], LM_MODEL["dim"] // LM_MODEL["num_heads"])
     require(fvd["mosaic_calls_lowered"] >= 2, "flash check ran interpreted")
-    # the dk/dv + dq pair, which no model here is long enough to take
-    split = run("flash_vs_dense_split", check_flash_vs_dense,
-                FLASH_SPLIT_SEQ, 2, 64)
-    require(split["mosaic_calls_lowered"] >= 3,
-            "the split backward did not engage compiled")
-    # the same pair over a band, at the window cell's length: the key
-    # blocks outside it skipped, the lower edge's masked
+    # the one backward kernel past Mosaic's default scoped VMEM: forward and
+    # fused backward, the backward asking for the VMEM its dq needs
+    fused = run("flash_vs_dense_fused_raised", check_flash_vs_dense,
+                FLASH_FUSED_SEQ, 2, 64)
+    require((fused["mosaic_calls_lowered"],
+             fused["mosaic_calls_raised_vmem"]) == (2, 1),
+            f"the fused backward did not engage with its VMEM raised: {fused}")
+    # the same over a band, at the window cell's length: the key blocks
+    # outside it skipped, the lower edge's masked
     band = run("flash_vs_dense_window", check_flash_vs_dense, **FLASH_WINDOW)
-    require(band["mosaic_calls_lowered"] >= 3,
-            "the windowed pair did not engage compiled")
+    require((band["mosaic_calls_lowered"],
+             band["mosaic_calls_raised_vmem"]) == (2, 1),
+            f"the windowed pair did not engage compiled: {band}")
 
     run("fused_gram_refuses", check_gram_refuses, GRAM_REFUSED)
     kernels = [run("fused_gram", check_gram, GRAM_SHAPE),

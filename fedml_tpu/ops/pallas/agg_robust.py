@@ -46,16 +46,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .flash_attention import _VMEM_CAP, _VMEM_LIMIT
+
 # rows of the i tile: one f32 sublane group. The j tile takes up to
 # _MAX_BLOCK_J rows (one MXU side) where VMEM allows.
 _BLOCK_I = 8
 _MAX_BLOCK_J = 128
 
-# what the operand and output windows may take of the v5e's 128 MiB of
-# VMEM; Mosaic's default scoped limit (16 MiB) is raised to what a shape
-# needs, never past this. A part with less VMEM refuses at compile time.
-_VMEM_CAP = 112 * 1024 * 1024
-_DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+# the operand and output windows may take up to _VMEM_CAP of the v5e's
+# VMEM: Mosaic's default scoped limit (_VMEM_LIMIT) is raised to what a
+# shape needs, never past the cap.
 
 # interpret mode (non-TPU) unrolls every grid step into the jaxpr — fine
 # for parity-test shapes, catastrophic for a cohort-scale grid (a 10k
@@ -174,7 +174,7 @@ def fused_gram(flat, *, interpret: Optional[bool] = None,
         out_shape=jax.ShapeDtypeStruct(
             (cpad // _BLOCK_I, cpad, _BLOCK_I), jnp.float32),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
-            _DEFAULT_SCOPED_VMEM, _window_bytes(bj, D) + (2 << 20))),
+            _VMEM_LIMIT, _window_bytes(bj, D) + (2 << 20))),
         interpret=interpret,
     )(fp, fp)
     # slabs[i, c, r] = <flat[c], flat[8 i + r]> = gram[c, 8 i + r]

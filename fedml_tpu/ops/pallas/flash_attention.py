@@ -58,18 +58,23 @@ forward and backward (a custom VJP's backward inherits no scope), and
 runs, once per call site per trace.
 
 Gradients: custom VJP, probabilities recomputed blockwise from the saved
-per-row logsumexp (FlashAttention-2). Where it fits, one kernel on a
-(batch, head group, k-block, q-block) grid makes all three: dk/dv accumulate
-over the inner q blocks, dq in a float32 (T, W) scratch that stays in VMEM
-for the whole head group (five contractions a block pair). That scratch and
-the whole-sequence dq output grow with T, W and the dtype: where
-``_bwd_vmem`` puts them past Mosaic's scoped VMEM (T = 8192 fits at
-W = 128 in bf16, T = 4096 in float32 or at W = 256), dq has its own kernel
-on the forward's grid (seven contractions). That split pair is the only
-backward such lengths have. The SmallThinker cell takes it (T = 16384,
-W = 128, bf16: every layer, windowed or global), and ``chip_smoke.py``
-runs it against dense on the chip at T = 12288 and, over a band, at the
-cell's length.
+per-row logsumexp (FlashAttention-2). One kernel on a (batch, head group,
+k-block, q-block) grid makes all three: dk/dv accumulate over the inner q
+blocks, dq in a float32 (T, W) scratch that stays in VMEM for the whole head
+group (five contractions a block pair). That scratch and the whole-sequence
+dq output grow with T, W and the dtype (``_bwd_vmem``). Where they pass
+Mosaic's default scoped VMEM (past T = 8192 at W = 128 in bf16, T = 4096 in
+float32 or at W = 256) the kernel asks for its count and ``_VMEM_MARGIN``,
+up to ``_VMEM_CAP`` of the v5e's 128 MiB; a shorter call asks for nothing
+of its own and runs under the default. The SmallThinker cell's layers
+(T = 16384, W = 128, bf16, windowed or global) take it so, and
+``chip_smoke.py`` runs it against dense on the chip at T = 12288 and, over
+a band, at the cell's length. Past the cap (T of about 109k at W = 128 in
+bf16, 71k in float32, 53k at W = 256) dq has its own kernel on the
+forward's grid (seven contractions): that split pair's tiles fit the
+default at any T, and it is the only backward such lengths have.
+``fedml_flash_backward_total{impl, seq_len}`` counts which backward a call
+runs, once per call site per trace.
 
 What PR 27's sweeps on the v5e found, all of it for the causal kernels
 (``window`` None), whose bodies stay as they were (PERF.md section 6 has the
@@ -112,6 +117,14 @@ FWD_BLOCK, BWD_BLOCK = 1024, 512
 # Mosaic's default scoped-VMEM limit on the v5e: what one kernel instance's
 # buffers and spilled temporaries have to fit (``_fwd_vmem``, ``_bwd_vmem``)
 _VMEM_LIMIT = 16 * 2**20
+# the largest count for which a kernel asks for more than that default
+# (``vmem_limit_bytes``), of the v5e's 128 MiB of VMEM; a part with less
+# refuses at compile time
+_VMEM_CAP = 112 * 2**20
+# asked for above the fused backward's count where that passes _VMEM_LIMIT:
+# Mosaic's own allocation outgrew the count by up to 1.2 MiB in ahead-of-time
+# compiles for the v5e (PERF.md section 6)
+_VMEM_MARGIN = 2 * 2**20
 
 
 def auto_block(T: int, largest: int = FWD_BLOCK,
@@ -479,15 +492,18 @@ def _specs(block_q: int, block_k: int, W: int, hp: int, causal: bool,
     return q_tile, k_tile, q_rows
 
 
-def _params(interpret: bool, carried_over_blocks: bool = False):
+def _params(interpret: bool, carried_over_blocks: bool = False,
+            vmem_limit: int | None = None):
     """The inner grid axis always carries scratch state; the fused backward's
     dq scratch also outlives the key blocks of axis 2, which a chip with two
-    TensorCores must therefore not split between them."""
+    TensorCores must therefore not split between them. ``vmem_limit``: the
+    scoped VMEM asked for, None for Mosaic's default."""
     if interpret:
         return {"interpret": True}
     blocks = "arbitrary" if carried_over_blocks else "parallel"
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", blocks, "arbitrary"))}
+        dimension_semantics=("parallel", "parallel", blocks, "arbitrary"),
+        vmem_limit_bytes=vmem_limit)}
 
 
 def _fwd_vmem(block_q: int, block_k: int, W: int, hp: int, itemsize: int) -> int:
@@ -507,14 +523,33 @@ def _bwd_vmem(T: int, block_q: int, block_k: int, W: int, hp: int,
     """Scoped VMEM of one dk/dv instance, in bytes, as ``_fwd_vmem`` counts:
     q/dO/k/v tiles in, dk/dv tiles out, lse/delta rows, the scratch, and the
     spilled p and dp (float32) and ds (operand dtype). ``fused`` adds dq for
-    the whole sequence: the float32 scratch and the double-buffered output.
-    The dq kernel of the split pair needs less at square blocks."""
+    the whole sequence: the float32 scratch and the double-buffered output,
+    their rows padded to whole 128-lane tiles, as VMEM holds them. The dq
+    kernel of the split pair needs less at square blocks."""
     piped = (2 * (2 * block_q + 2 * block_k) + 4 * block_k) * W * itemsize \
         + 4 * 8 * block_q * 4
     scratch = 2 * block_k * W * (hp * itemsize + 4)
     spilled = block_q * block_k * (8 + itemsize)
-    whole_dq = T * W * (4 + 2 * itemsize) if fused else 0
+    whole_dq = T * -(-W // 128) * 128 * (4 + 2 * itemsize) if fused else 0
     return piped + scratch + spilled + whole_dq
+
+
+def _fused_bwd_vmem(q, block_q: int, block_k: int) -> int:
+    """``_bwd_vmem`` of the fused backward at q's (B, T, H, Dh) and dtype."""
+    _, T, H, Dh = q.shape
+    hp = heads_per_step(H, Dh)
+    return _bwd_vmem(T, block_q, block_k, hp * Dh, hp, q.dtype.itemsize,
+                     fused=True)
+
+
+def _fused_bwd_plan(q, block_q: int, block_k: int):
+    """(fused, vmem_limit) of the backward at q's shape and dtype: the fused
+    kernel where its count is at most ``_VMEM_CAP``, asking for that count
+    and ``_VMEM_MARGIN`` where it passes Mosaic's default, for nothing of
+    its own (None) where it does not."""
+    need = _fused_bwd_vmem(q, block_q, block_k)
+    raised = _VMEM_LIMIT < need <= _VMEM_CAP
+    return need <= _VMEM_CAP, need + _VMEM_MARGIN if raised else None
 
 
 def _window_scope(window):
@@ -582,8 +617,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
         delta = delta.reshape(B, T, G, hp).transpose(0, 2, 3, 1)
         flat = (B, T, H * Dh)
         args = (*(t.reshape(flat) for t in (q, k, v, g)), lse, delta)
-        fused = _bwd_vmem(T, block_q, block_k, W, hp, q.dtype.itemsize,
-                          fused=True) <= _VMEM_LIMIT
+        fused, vmem_limit = _fused_bwd_plan(q, block_q, block_k)
         like = lambda t: jax.ShapeDtypeStruct(flat, t.dtype)  # noqa: E731
 
         q_tile, k_tile, q_rows = _specs(block_q, block_k, W, hp, causal, True,
@@ -603,7 +637,8 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k, interpret,
                 pltpu.VMEM((block_k, W), jnp.float32),
                 pltpu.VMEM((block_k, W), jnp.float32),
             ] + ([pltpu.VMEM((T, W), jnp.float32)] if fused else []),
-            **_params(interpret, carried_over_blocks=fused),
+            **_params(interpret, carried_over_blocks=fused,
+                      vmem_limit=vmem_limit),
         )(*args)
         if fused:
             dk, dv, dq = grads
@@ -712,6 +747,16 @@ def _count_window(pass_: str, q, window) -> None:
             **{"pass": pass_}).inc()
 
 
+def _count_backward(q, block_q, block_k) -> None:
+    """``fedml_flash_backward_total{impl, seq_len}``: which backward a call
+    runs, ``fused`` (one kernel) or ``split`` (the dk/dv and dq pair),
+    counted as ``_count_diagonal`` counts."""
+    if isinstance(q, jax.core.Tracer):
+        impl = "fused" if _fused_bwd_plan(q, block_q, block_k)[0] else "split"
+        get_registry().counter("fedml_flash_backward_total", impl=impl,
+                               seq_len=q.shape[1]).inc()
+
+
 def _fwd(q, k, v, causal, block_q, block_k, window):
     interpret = jax.default_backend() != "tpu"
     block_q, block_k = _resolve_blocks(q, block_q, block_k, False, window)
@@ -726,6 +771,7 @@ def _bwd(causal, block_q, block_k, window, res, g):
     block_q, block_k = _resolve_blocks(q, block_q, block_k, True, window)
     _count_diagonal("bwd", q, causal, block_q, block_k)
     _count_window("bwd", q, window)
+    _count_backward(q, block_q, block_k)
     return _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k,
                            interpret, window)
 
@@ -738,7 +784,7 @@ def flash_vmem_ok(T: int, Dh: int, itemsize: int = 2,
     """Whether the forward and the split backward fit scoped VMEM: at
     ``block`` squares if given, else at some auto block of each pass. Their
     working set is O(block * W), independent of T; the fused backward, which
-    is not, is taken only where it fits (``_flash_backward``). Refuses a
+    is not, is taken only up to ``_VMEM_CAP`` (``_fused_bwd_plan``). Refuses a
     huge Dh, oversized explicit blocks, and a head count whose lane tile is
     the whole H * Dh and wide (an odd H at Dh = 64; without ``heads`` the
     tile is taken to be the fewest whole lane tiles)."""

@@ -39,14 +39,14 @@ def test_flash_matches_dense(causal, T, Dh):
 def test_flash_gradients_match_dense(causal, T, Dh, fused, monkeypatch):
     """dq, dk, dv against dense at blocks of T: one square at T = 128, a
     striped diagonal block beyond, through the fused backward and the split
-    pair (the limit patched as ``test_flash_backward_fused_and_split_agree``
+    pair (the cap patched as ``test_flash_backward_fused_and_split_agree``
     patches it)."""
     import sys
 
     mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
     H = 128 // Dh
-    if not fused:  # room for the split pair's tiles, none for the fused dq
-        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+    if not fused:  # a cap under the fused dq's count: the split pair
+        monkeypatch.setattr(mod, "_VMEM_CAP", mod._bwd_vmem(
             T, T, T, 128, H, 4, fused=False))
     jax.clear_caches()
     q, k, v = _qkv(B=1, T=T, H=H, Dh=Dh)
@@ -195,16 +195,16 @@ def test_flash_keeps_heads_apart(H, Dh):
 
 @pytest.mark.parametrize("fused", [True, False])
 def test_flash_backward_fused_and_split_agree(fused, monkeypatch):
-    """Where the whole sequence's dq does not fit VMEM it has its own
+    """Where the whole sequence's dq passes the VMEM cap it has its own
     kernel: same gradients."""
     import sys
 
     mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
     if not fused:  # room for the 128 x 256 tiles, none for 512 rows of dq
-        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+        monkeypatch.setattr(mod, "_VMEM_CAP", mod._bwd_vmem(
             512, 128, 256, 128, 2, 4, fused=False))
-    # the jitted launcher's cache does not see the limit: trace anew, and
-    # leave no trace made under a patched limit behind
+    # the jitted launcher's cache does not see the cap: trace anew, and
+    # leave no trace made under a patched cap behind
     jax.clear_caches()
     q, k, v = _qkv(B=1, T=512, H=2, Dh=64, seed=4)
     w = jnp.cos(jnp.arange(q.size).reshape(q.shape) * 0.01)
@@ -393,8 +393,9 @@ def test_shapes_gate_rejects_oversized_explicit_blocks():
 
 
 # what Mosaic did with each at its default 16 MiB, compiled ahead of time for
-# the v5e at B = 2 and two head groups (PR 27, PERF.md section 6): the fused
-# backward at 512 squares, (T, H, Dh, itemsize) -> compiled
+# the v5e at B = 2 and two head groups (PERF.md section 6): the fused
+# backward at 512 squares, (T, H, Dh, itemsize) -> compiled; each of them
+# compiled at its count and the margin (PERF.md section 6, the fused backward)
 FUSED_COMPILED_FOR_V5E = [
     (8192, 4, 64, 2, True), (12288, 4, 64, 2, False),
     (4096, 4, 64, 4, True), (8192, 4, 64, 4, False),
@@ -408,16 +409,23 @@ FUSED_COMPILED_FOR_V5E = [
 def test_fused_backward_is_taken_only_where_mosaic_took_it(T, H, Dh, itemsize,
                                                            compiled):
     """The rule is bytes, not a length: dq's whole-sequence scratch and
-    output grow with T, the tile's width and the dtype."""
+    output grow with T, the tile's width and the dtype. Every shape here is
+    under the cap, so the fused kernel is taken; it asks for scoped VMEM
+    past Mosaic's default (its count and the margin) only where Mosaic
+    refused it at that default."""
     from fedml_tpu.ops.pallas.flash_attention import (
         _VMEM_LIMIT,
-        _bwd_vmem,
-        heads_per_step,
+        _VMEM_MARGIN,
+        _fused_bwd_plan,
+        _fused_bwd_vmem,
     )
 
-    hp = heads_per_step(H, Dh)
-    need = _bwd_vmem(T, 512, 512, hp * Dh, hp, itemsize, fused=True)
+    q = jax.ShapeDtypeStruct((2, T, H, Dh), {2: jnp.bfloat16,
+                                             4: jnp.float32}[itemsize])
+    need = _fused_bwd_vmem(q, 512, 512)
     assert (need <= _VMEM_LIMIT) == compiled
+    assert _fused_bwd_plan(q, 512, 512) == (
+        True, None if compiled else need + _VMEM_MARGIN)
 
 
 @pytest.mark.parametrize("H,Dh,itemsize,blocks", [
@@ -482,12 +490,12 @@ def test_windowed_flash_matches_the_dense_band(T, window, block_q, block_k,
                                                fused, monkeypatch):
     """The output and dq, dk, dv of a causal band ``0 <= i - j < window``
     against the dense path's band, forward, the fused backward and the split
-    pair (the limit patched as ``test_flash_gradients_match_dense`` patches
+    pair (the cap patched as ``test_flash_gradients_match_dense`` patches
     it); a window of T or more is the causal call itself, bit for bit."""
     mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
     H, Dh = 2, 64
-    if not fused:  # room for the split pair's tiles, none for the fused dq
-        monkeypatch.setattr(mod, "_VMEM_LIMIT", mod._bwd_vmem(
+    if not fused:  # a cap under the fused dq's count: the split pair
+        monkeypatch.setattr(mod, "_VMEM_CAP", mod._bwd_vmem(
             T, block_q, block_k, 128, H, 4, fused=False))
     jax.clear_caches()
     q, k, v = _qkv(B=1, T=T, H=H, Dh=Dh, seed=window)
@@ -576,14 +584,16 @@ def test_window_counter_counts_once_per_traced_call_site():
 
 
 @pytest.mark.parametrize("metric,reads", [("flash_window_share.st21", 75.0),
-                                          ("flash_striped_share.st21", 100.0)])
+                                          ("flash_striped_share.st21", 100.0),
+                                          ("flash_fused_bwd_share.st21", 100.0)])
 def test_flash_window_share_reads_the_st21_cell(monkeypatch, metric, reads):
     """``benchmark/layer_metrics/<metric>.json`` through its reader, at the
     SmallThinker cell's traffic: nothing before a call at ``seq_len`` is
     traced (as on the parent, which has no window counter), then, once one
     period's attention is (the global layer's calls and three windowed
-    layers', forward and backward; traced, never run): 75 windowed, and
-    every diagonal striped, the windowed layers' as the global one's."""
+    layers', forward and backward; traced, never run): 75 windowed, every
+    diagonal striped, the windowed layers' as the global one's, and every
+    backward the fused kernel."""
     import importlib.util
     import json
     import os
@@ -619,3 +629,85 @@ def test_flash_window_share_reads_the_st21_cell(monkeypatch, metric, reads):
 
     jax.jit(jax.grad(period)).trace(q)
     assert reader.read(ctx, **spec["args"]) == reads
+
+
+def test_backward_counter_counts_fused_and_split(monkeypatch):
+    """``fedml_flash_backward_total{impl, seq_len}``: ``fused`` at the
+    SmallThinker cell's attention, (1, 16384, 28, 128) bf16, whose fused
+    count passes Mosaic's default scoped VMEM and not the cap; ``split``
+    once the cap is under that count (traced, never run). Once per call site
+    per trace, where the backward's blocks are resolved: nothing for a
+    compiled or an eager call, nothing for the forward alone."""
+    from fedml_tpu.core.telemetry import get_registry
+    from fedml_tpu.ops.pallas.flash_attention import (
+        _VMEM_LIMIT,
+        _fused_bwd_vmem,
+    )
+
+    mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
+
+    def count(T):
+        return {impl: get_registry().counter(
+            "fedml_flash_backward_total", impl=impl, seq_len=T).value
+            for impl in ("fused", "split")}
+
+    def after(T, fn, *args):
+        before = count(T)
+        fn(*args)
+        return {key: n - before[key] for key, n in count(T).items()
+                if n != before[key]}
+
+    cell = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16)
+    assert _VMEM_LIMIT < _fused_bwd_vmem(cell, 512, 512) <= mod._VMEM_CAP
+    grad = lambda: jax.jit(jax.grad(lambda q: flash_attention(  # noqa: E731
+        q, q, q, True).astype(jnp.float32).sum())).trace(cell)
+    assert after(16384, grad) == {"fused": 1}
+    monkeypatch.setattr(mod, "_VMEM_CAP", _fused_bwd_vmem(cell, 512, 512) - 1)
+    jax.clear_caches()  # leave no launcher traced under a patched cap behind
+    assert after(16384, grad) == {"split": 1}
+    monkeypatch.undo()
+    jax.clear_caches()
+    q = jnp.zeros((1, 256, 2, 64), jnp.float32)
+    small = jax.jit(jax.grad(lambda q: flash_attention(q, q, q, True).sum()))
+    assert after(256, small, q) == {"fused": 1}
+    assert after(256, small, q) == {}                                # compiled
+    assert after(256, jax.jit(lambda q: flash_attention(q, q, q, True)),
+                 q) == {}                                            # forward
+    assert after(256, jax.grad(lambda q: flash_attention(
+        q, q, q, True).sum()), q) == {}                              # eager
+
+
+@pytest.mark.parametrize("window", [None, 256])
+def test_fused_backward_past_the_default_limit_matches_dense_and_split(
+        window, monkeypatch):
+    """The fused backward where its count passes Mosaic's default scoped
+    VMEM (the limit patched under it, so the kernel would ask for its count
+    and the margin): dq, dk and dv against dense and against the split pair
+    (the cap patched under the count), causal and over a band."""
+    mod = sys.modules["fedml_tpu.ops.pallas.flash_attention"]
+    T, H, Dh, block = 512, 2, 64, 128
+    q, k, v = _qkv(B=1, T=T, H=H, Dh=Dh, seed=11)
+    need = mod._fused_bwd_vmem(q, block, block)
+    monkeypatch.setattr(mod, "_VMEM_LIMIT", need - 1)
+    assert mod._fused_bwd_plan(q, block, block) == (
+        True, need + mod._VMEM_MARGIN)
+    w = jnp.cos(jnp.arange(q.size).reshape(q.shape) * 0.01)
+
+    def grads(attn):
+        jax.clear_caches()  # the launchers' caches do not see a patched cap
+        fn = jax.grad(lambda q, k, v: (attn(q, k, v) * w).sum(), (0, 1, 2))
+        return str(jax.make_jaxpr(fn)(q, k, v)).count("pallas_call"), fn(q, k, v)
+
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, True, block, block, window=window)
+    calls, fused = grads(flash)
+    assert calls == 2
+    monkeypatch.setattr(mod, "_VMEM_CAP", need - 1)
+    calls, split = grads(flash)
+    assert calls == 3
+    _, dense = grads(lambda q, k, v: multihead_attention(
+        q, k, v, causal=True, impl="dense", window=window))
+    for a, b, c in zip(fused, split, dense):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    jax.clear_caches()

@@ -8,6 +8,8 @@ scoped VMEM above all — only ``chip_smoke.py`` on the chip can say.) It
 keeps a kernel PR from spending chip minutes on an error this finds in
 seconds."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -66,13 +68,15 @@ def _flash_loss(q, k, v):
     return flash_attention(q, k, v, True).astype(jnp.float32).sum()
 
 
-# where the whole sequence's dq fits VMEM: forward + the one backward kernel;
-# where not: forward, dk/dv, dq. (8, 1024, 16, 64) is the LM cell's attention.
+# where the whole sequence's dq fits the VMEM cap: forward + the one backward
+# kernel; past it: forward, dk/dv, dq. (8, 1024, 16, 64) is the LM cell's
+# attention, T = 16384 at a 128-lane tile the SmallThinker cell's length.
 @pytest.mark.parametrize("shape,dtype,calls", [
     ((1, 4096, 2, 64), "bfloat16", 2), ((1, 4096, 2, 128), "bfloat16", 2),
     ((8, 1024, 16, 64), "bfloat16", 2), ((1, 8192, 2, 64), "bfloat16", 2),
-    ((1, 8192, 2, 64), "float32", 3), ((1, 16384, 2, 64), "bfloat16", 3),
-    ((1, 16384, 1, 128), "bfloat16", 3)])
+    ((1, 8192, 2, 64), "float32", 2), ((1, 16384, 2, 64), "bfloat16", 2),
+    ((1, 16384, 1, 128), "bfloat16", 2), ((1, 131072, 1, 128), "bfloat16", 3),
+    ((1, 98304, 2, 64), "float32", 3)])
 def test_flash_forward_and_backward_lower(shape, dtype, calls, monkeypatch):
     # the kernel picks interpret mode from the backend it is traced on
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -100,7 +104,8 @@ def _pallas_calls(fn, *shapes):
 def test_fused_backward_keeps_its_key_blocks_on_one_core(monkeypatch):
     """dq's scratch outlives the key blocks of grid axis 2: the fused kernel
     declares that axis ``arbitrary``, so a chip with two TensorCores does
-    not split it; the forward and the split pair keep it ``parallel``."""
+    not split it; the forward and the split pair (past the VMEM cap) keep
+    it ``parallel``."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def semantics(T):
@@ -110,9 +115,10 @@ def test_fused_backward_keeps_its_key_blocks_on_one_core(monkeypatch):
                                           x, x, x)]
 
     free = ("parallel", "parallel", "parallel", "arbitrary")
-    assert semantics(1024) == [
-        free, ("parallel", "parallel", "arbitrary", "arbitrary")]
-    assert semantics(16384) == [free, free, free]
+    fused = [free, ("parallel", "parallel", "arbitrary", "arbitrary")]
+    assert semantics(1024) == fused
+    assert semantics(16384) == fused
+    assert semantics(131072) == [free, free, free]
 
 
 def test_flash_layers_share_one_lowering_of_each_kernel(monkeypatch):
@@ -132,17 +138,30 @@ def test_flash_layers_share_one_lowering_of_each_kernel(monkeypatch):
     assert _mosaic_calls(jax.grad(loss), x) <= 3
 
 
-def test_flash_backward_splits_at_explicit_blocks_over_its_cap(monkeypatch):
-    """1024 blocks asked for by hand: the fused backward would pass scoped
-    VMEM from T = 2048 (AOT for the v5e, PR 27), so dq takes its own kernel."""
+@pytest.mark.parametrize("over_cap", [False, True])
+def test_flash_backward_splits_at_explicit_blocks_over_its_cap(monkeypatch,
+                                                               over_cap):
+    """1024 blocks asked for by hand: the fused backward passes Mosaic's
+    default scoped VMEM from T = 2048, so it asks for its count and the
+    margin (which compiled for the v5e ahead of time: PERF.md section 6);
+    with the cap under that count, dq takes its own kernel."""
+    mod = importlib.import_module("fedml_tpu.ops.pallas.flash_attention")
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     x = _sds((1, 2048, 2, 64), jnp.bfloat16)
+    need = mod._fused_bwd_vmem(x, 1024, 1024)
+    assert mod._VMEM_LIMIT < need <= mod._VMEM_CAP
+    if over_cap:
+        monkeypatch.setattr(mod, "_VMEM_CAP", need - 1)
+    jax.clear_caches()  # the jitted launchers' caches do not see the cap
 
     def loss(q, k, v):
         return flash_attention(q, k, v, True, 1024, 1024).astype(
             jnp.float32).sum()
 
-    assert _mosaic_calls(jax.grad(loss, (0, 1, 2)), x, x, x) == 3
+    assert _mosaic_calls(jax.grad(loss, (0, 1, 2)), x, x, x) == (
+        3 if over_cap else 2)
+    jax.clear_caches()
 
 
 def _kernel_dots(fn, *shapes):
@@ -156,20 +175,62 @@ def _kernel_dots(fn, *shapes):
 
 
 # contractions a head: 2 forward + 5 in the fused backward; with the
-# split pair 2 + 4 (dk/dv) + 3 (dq). Each causal case and each
-# sub-tile of a diagonal block traces its own, so these are floors.
-@pytest.mark.parametrize("T,dots_a_head", [(1024, 2 + 5), (16384, 2 + 4 + 3)])
+# split pair (the cap patched under the fused count) 2 + 4 (dk/dv) + 3
+# (dq). Each causal case and each sub-tile of a diagonal block traces its
+# own, so these are floors.
+@pytest.mark.parametrize("T,split,dots_a_head", [
+    (1024, False, 2 + 5), (16384, False, 2 + 5), (16384, True, 2 + 4 + 3)])
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_flash_feeds_the_mxu_the_dtype_it_was_given(T, dots_a_head, dtype,
-                                                    monkeypatch):
+def test_flash_feeds_the_mxu_the_dtype_it_was_given(T, split, dots_a_head,
+                                                    dtype, monkeypatch):
     """No float32 upcast of an MXU operand at bf16 inputs, in any of the
     kernels: every contraction takes q/k/v/dO, p and ds in the input dtype
     and accumulates float32."""
+    mod = importlib.import_module("fedml_tpu.ops.pallas.flash_attention")
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if split:
+        monkeypatch.setattr(mod, "_VMEM_CAP", mod._VMEM_LIMIT)
+    jax.clear_caches()  # the jitted launchers' caches do not see the cap
     x = _sds((1, T, 2, 64), jnp.dtype(dtype))
     found = _kernel_dots(jax.grad(_flash_loss, (0, 1, 2)), x, x, x)
+    jax.clear_caches()
     assert len(found) >= 2 * dots_a_head
     assert set(found) == {(dtype, dtype, "float32")}
+
+
+# (B, T, H, Dh, window) at each cell's flash shape, bf16: GPT-2, LFM2 (32
+# query heads of 64), Nemotron (32 of 128), Ouro (16 of 128); and the
+# SmallThinker cell's global and windowed layers (28 of 128 at T = 16384),
+# whose fused backward alone passes Mosaic's default
+CELL_FLASH_SHAPES = {
+    "gpt2": (8, 1024, 16, 64, None), "lfm2": (2, 8192, 32, 64, None),
+    "nemo3": (1, 8192, 32, 128, None), "ouro": (1, 4096, 16, 128, None),
+    "st21_global": (1, 16384, 28, 128, None),
+    "st21_window": (1, 16384, 28, 128, 4096)}
+
+
+@pytest.mark.parametrize("cell", list(CELL_FLASH_SHAPES))
+def test_scoped_vmem_is_raised_only_past_mosaics_default(cell, monkeypatch):
+    """No kernel's compiler params ask for scoped VMEM of their own
+    (``vmem_limit_bytes`` None) but the fused backward's where its count
+    passes Mosaic's default: there the count and the margin. So the other
+    cells' kernels run under the default."""
+    mod = importlib.import_module("fedml_tpu.ops.pallas.flash_attention")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    *shape, window = CELL_FLASH_SHAPES[cell]
+    x = _sds(tuple(shape), jnp.bfloat16)
+    calls = _pallas_calls(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True, window=window).astype(jnp.float32).sum(), (0, 1, 2)),
+        x, x, x)
+    limits = [call.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+              for call in calls]
+    need = mod._fused_bwd_vmem(x, 512, 512)
+    raised = [need + mod._VMEM_MARGIN] if need > mod._VMEM_LIMIT else []
+    assert len(calls) == 2
+    assert [n for n in limits if n is not None] == raised
+    assert bool(raised) == cell.startswith("st21")
 
 
 @pytest.mark.parametrize("hw,c", [(32, 16), (16, 32), (8, 64)])

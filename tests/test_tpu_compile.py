@@ -324,30 +324,69 @@ def test_the_flash_pair_compiles_with_striped_diagonal_blocks(topo, monkeypatch,
     assert sorted("_flash_backward" in n for n in calls) == [False, True]
 
 
+def _flash_grad_calls(topo, B, H, window):
+    """The Mosaic calls (op names) of the flash pair's gradient at T = 16384,
+    head width 128, bf16, compiled for one described v5e."""
+    from fedml_tpu.ops.pallas import flash_attention
+
+    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
+    sds = jax.ShapeDtypeStruct((B, 16384, H, 128), jnp.bfloat16,
+                               sharding=one_chip)
+    text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True, window=window).astype(jnp.float32).sum(),
+        (0, 1, 2))).lower(sds, sds, sds).compile().as_text()
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines()
+            if "tpu_custom_call" in line and "custom-call(" in line]
+
+
 @pytest.mark.parametrize("B,H", [(1, 28), (2, 2)], ids=["cell", "two_groups"])
 def test_the_windowed_flash_pair_compiles_with_its_scope(topo, monkeypatch, B, H):
     """The SmallThinker cell's windowed attention (T = 16384, head width
     128, bf16, a band of 4096; and at two head groups and B = 2, where a
     probe at one group once compiled what two refused) forward and backward
-    compiled for the v5e: Mosaic takes the band's bodies inside scoped VMEM;
-    at this length the backward is the split pair (the fused one's
-    whole-sequence dq does not fit), so three call sites, each under the
+    compiled for the v5e: Mosaic takes the band's bodies, the backward as
+    the one fused kernel with its scoped VMEM raised past the default for
+    the whole sequence's dq, so two call sites, each under the
     ``attn.window`` scope the launchers open themselves, which is what
     ``attn_window_ms.st21`` and the two ``flash_window_*_roofline.st21``
     read."""
-    from fedml_tpu.ops.pallas import flash_attention
-
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(Mesh(np.array(topo.devices[:1]), ("x",)), P())
-    sds = jax.ShapeDtypeStruct((B, 16384, H, 128), jnp.bfloat16,
-                               sharding=one_chip)
-    text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
-        q, k, v, True, window=4096).astype(jnp.float32).sum(),
-        (0, 1, 2))).lower(sds, sds, sds).compile().as_text()
-    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
-             for line in text.splitlines()
-             if "tpu_custom_call" in line and "custom-call(" in line]
-    assert len(calls) == 3, calls
+    calls = _flash_grad_calls(topo, B, H, 4096)
+    assert len(calls) == 2, calls
     assert all("attn.window" in n for n in calls), calls
-    assert sorted("_flash_backward" in n for n in calls) == [False, True, True]
+    assert sorted("_flash_backward" in n for n in calls) == [False, True]
     assert [n for n in calls if "transpose(" in n]  # the backward's, named
+
+
+@pytest.mark.parametrize("B,H", [(1, 28), (2, 2)], ids=["cell", "two_groups"])
+def test_the_global_flash_pair_compiles_fused_at_the_cells_length(
+        topo, monkeypatch, B, H):
+    """The SmallThinker cell's global layer (T = 16384, head width 128,
+    bf16, the whole causal triangle) compiled for the v5e: forward and the
+    one fused backward with its scoped VMEM raised, two call sites, no
+    ``attn.window`` scope."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    calls = _flash_grad_calls(topo, B, H, None)
+    assert len(calls) == 2, calls
+    assert not [n for n in calls if "attn.window" in n], calls
+    assert sorted("_flash_backward" in n for n in calls) == [False, True]
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "global"])
+def test_the_split_pair_compiles_past_the_cap(topo, monkeypatch, window):
+    """The split backward, which only lengths past the VMEM cap take (T of
+    about 110k at head width 128 in bf16), at the cell's length with the cap
+    patched under the fused count: dk/dv and dq as two kernels at Mosaic's
+    default scoped VMEM, beside the forward."""
+    import importlib
+
+    mod = importlib.import_module("fedml_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mod, "_VMEM_CAP", mod._VMEM_LIMIT)
+    jax.clear_caches()
+    calls = _flash_grad_calls(topo, 2, 2, window)
+    jax.clear_caches()
+    assert len(calls) == 3, calls
+    assert sorted("_flash_backward" in n for n in calls) == [False, True, True]
+    assert all(("attn.window" in n) == (window is not None) for n in calls)
